@@ -14,7 +14,9 @@ headline metric matches the reference's definition of training throughput
 (records consumed per second while the trainer runs).
 
 Baseline (BASELINE.json): 1M samples/sec on 64 chips => 15625 samples/sec/chip.
-Prints ONE json line: {"metric", "value", "unit", "vs_baseline", ...}.
+Prints ONE json line: {"metric", "value", "unit", "vs_baseline", "device", ...}.
+Needs a TPU: without one it exits non-zero and prints no result (a CPU
+rate is never written under a per-chip name).
 """
 
 from __future__ import annotations
@@ -100,91 +102,11 @@ def write_files(tmpdir: str, rng, reuse_pool=None, prefix="part", pv=False) -> t
     return files, np.concatenate(pool_parts)
 
 
-def apply_legacy_init_env() -> None:
-    """Map the historical PBOX_BENCH_INIT_* env knobs onto the
-    backendguard flags. The probe/retry/fallback logic that grew here now
-    lives in utils/backendguard.py (shared by every entrypoint); older
-    drivers and tools/tpu_capture.py still speak the bench-era env names:
-      PBOX_BENCH_INIT_RETRIES  -> backend_init_retries   (default 6)
-      PBOX_BENCH_INIT_TIMEOUT  -> backend_init_timeout_s (default 120s)
-      PBOX_BENCH_INIT_BACKOFF  -> backend_init_backoff_s (default 30s)
-    """
-    from paddlebox_tpu import config as _config
-
-    for env, flag in (
-        ("PBOX_BENCH_INIT_TIMEOUT", "backend_init_timeout_s"),
-        ("PBOX_BENCH_INIT_RETRIES", "backend_init_retries"),
-        ("PBOX_BENCH_INIT_BACKOFF", "backend_init_backoff_s"),
-    ):
-        if env in os.environ:
-            _config.set_flag(flag, os.environ[env])
-
-
-LAST_GOOD_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "tools", "last_good_tpu_bench.json")
-CAPTURE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "tools", "last_good_tpu_capture.json")
-CAPTURE_LOCK_PATH = CAPTURE_PATH + ".lock"  # shared with tools/tpu_capture.py
-PROBE_LOOP_LOG = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "tools", "tpu_probe_log.jsonl")
-
-
 def pv_mode_enabled() -> bool:
     """PBOX_BENCH_PV=1 benches the JOIN phase: pv-merged batches with
     rank_offset through the rank-attention tower (the two-phase join/update
     pipeline's other half; EnablePvMerge branch, data_feed.cc:2165-2198)."""
     return os.environ.get("PBOX_BENCH_PV", "0") == "1"
-
-
-def bench_config_id() -> str:
-    """Identity of the measured workload: a cached last-good number is only
-    comparable to runs of the SAME bench definition."""
-    return (
-        f"slots={NUM_SLOTS},emb={EMBEDX_DIM},B={BATCH},hid={HIDDEN},"
-        f"files={N_FILES}x{RECORDS_PER_FILE},keys={KEY_SPACE},"
-        f"batches={TRAIN_BATCHES}"
-        + (",pv=1" if pv_mode_enabled() else "")
-    )
-
-
-def read_last_good():
-    """Most recent successful TPU measurement, cached on disk by main()."""
-    try:
-        with open(LAST_GOOD_PATH) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
-def read_last_capture():
-    """Most recent FULL capture artifact (tools/tpu_capture.py): headline +
-    knob sweep + wire/carrier/pv ablations + scatter sweep, taken by the
-    background probe loop on the first healthy chip window. Embedded in the
-    fallback JSON so a wedged driver run still carries the measured TPU
-    evidence."""
-    try:
-        with open(CAPTURE_PATH) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
-def read_probe_loop_tail(n: int = 30):
-    """Tail of the long-running background probe log (tools/tpu_probe_loop.sh),
-    if one was kept during the build session — independent wedge evidence
-    spanning hours, not just this bench invocation."""
-    try:
-        with open(PROBE_LOOP_LOG) as f:
-            lines = f.read().strip().splitlines()
-    except OSError:
-        return None
-    out = []
-    for ln in lines[-n:]:
-        try:
-            out.append(json.loads(ln))
-        except ValueError:
-            pass
-    return out or None
 
 
 def _plan_source() -> str:
@@ -195,87 +117,23 @@ def _plan_source() -> str:
     return get_plan().source
 
 
-def fail_fast(reason: str) -> None:
-    print(
-        json.dumps(
-            {
-                "metric": "deepfm_e2e_train_samples_per_sec_per_chip",
-                "value": 0.0,
-                "unit": "samples/s/chip",
-                "vs_baseline": 0.0,
-                "error": reason,
-            }
-        )
-    )
-    sys.exit(1)
-
-
-def wait_for_capture_lock() -> None:
-    """If a tpu_capture.py run is in flight (lock file with a live pid),
-    wait for it instead of racing it: two benches sharing one chip and one
-    host core degrade BOTH numbers. Skipped inside the capture itself
-    (PBOX_BENCH_NO_LOCK_WAIT) and bounded so a driver-budgeted run is
-    never starved — after the wait the capture artifact is fresh and this
-    run either measures a free chip or embeds the capture."""
-    if os.environ.get("PBOX_BENCH_NO_LOCK_WAIT", "0") == "1":
-        return
-    lock = CAPTURE_LOCK_PATH
-    budget = float(os.environ.get("PBOX_BENCH_CAPTURE_WAIT", "2400"))
-    t0 = time.time()
-    warned = False
-    while time.time() - t0 < budget:
-        try:
-            with open(lock) as f:
-                pid = int(f.read().strip() or "0")
-        except (OSError, ValueError):
-            return
-        if pid <= 0:
-            return  # truncated/garbage lock: os.kill(0, ...) would probe
-            # our own process group and "succeed" forever
-        try:
-            os.kill(pid, 0)  # liveness probe, no signal delivered
-        except ProcessLookupError:
-            return  # stale lock
-        except PermissionError:
-            pass  # pid EXISTS under another uid: the capture is live, wait
-        if not warned:
-            print(
-                f"bench: capture in flight (pid {pid}), waiting up to "
-                f"{budget:.0f}s for it to finish",
-                file=sys.stderr, flush=True,
-            )
-            warned = True
-        time.sleep(15)
-
-
 def main():
     profile = "--profile" in sys.argv
-    wait_for_capture_lock()
-    apply_legacy_init_env()
-    from paddlebox_tpu.utils.backendguard import ensure_backend
+    from paddlebox_tpu.utils import backendguard, compilecache
 
+    # a per-chip metric needs the chip: no accelerator -> typed error,
+    # non-zero exit, no result line
     try:
-        verdict = ensure_backend()
-    except Exception as e:  # even the CPU fallback failed: diagnose fast
-        fail_fast(f"backend bring-up failed: {e!r}")
-    info = {"platform": verdict.platform, "n_devices": verdict.n_devices}
-    probe_log = verdict.probe_log
-    tpu_error = verdict.error if verdict.wedged else None
+        device = backendguard.bring_up(require="tpu")
+    except backendguard.BackendUnavailableError as e:
+        sys.exit(f"bench.py: {e}")
 
     import jax
     import optax
 
-    # persistent XLA compile cache: PBOX_COMPILE_CACHE_DIR (or the
-    # compile_cache_dir flag) points at a durable directory; "auto" stays
-    # off here — bench owns no checkpoint root (the supervisor resolves
-    # "auto" under its own). Enabled before any compilation so warmup_s
-    # becomes a cold-vs-warm pair across consecutive runs.
-    from paddlebox_tpu import config as _cfg
-    from paddlebox_tpu.utils import compilecache
-
-    cache_dir = compilecache.resolve_dir(str(_cfg.get_flag("compile_cache_dir")))
-    if cache_dir is not None:
-        compilecache.enable(cache_dir)
+    # before any compilation, so warmup_s is a cold-vs-warm pair across
+    # consecutive runs (placement: utils/compilecache)
+    compilecache.enable()
 
     from paddlebox_tpu.data import BoxPSDataset, SlotInfo, SlotSchema
     from paddlebox_tpu.models import DeepFM, RankDeepFM
@@ -442,33 +300,6 @@ def main():
 
     sps = timed_samples / train_s
     extra = {}
-    if len(probe_log) > 1:
-        # a recovered-after-retries chip is wedge evidence too — record the
-        # failed probes even when the run ultimately lands on TPU
-        extra["tpu_probe_log"] = probe_log
-    if tpu_error is not None:
-        extra["tpu_error"] = tpu_error
-        extra["tpu_probe_log"] = probe_log
-        loop_tail = read_probe_loop_tail()
-        if loop_tail is not None:
-            extra["tpu_probe_loop_tail"] = loop_tail
-        last_good = read_last_good()
-        if last_good is not None:
-            if last_good.get("bench_config") == bench_config_id():
-                extra["last_good_tpu"] = last_good
-            else:
-                extra["last_good_tpu_stale"] = {
-                    "measured_at": last_good.get("measured_at"),
-                    "bench_config": last_good.get("bench_config"),
-                    "note": "cached TPU measurement predates a bench config "
-                    "change; not comparable",
-                }
-        capture = read_last_capture()
-        if capture is not None:
-            # the probe-loop's full healthy-window capture: headline +
-            # sweep + ablations + scatter decision, with its own
-            # bench_config stamp for comparability
-            extra["tpu_capture"] = capture
     if profile:
         # per-stage attribution (TrainFilesWithProfiler parity) — table to
         # stderr so stdout stays one JSON line for the driver
@@ -527,11 +358,6 @@ def main():
             for name, hist in sorted(_all_histograms().items())
         },
         "warmup_s": round(warmup_s, 3),
-        # backend bring-up verdict (utils/backendguard): "ok" or
-        # "fallback_cpu" — the full probe_log rides in tpu_probe_log above
-        "backend_init": {
-            k: v for k, v in verdict.as_dict().items() if k != "probe_log"
-        },
         # persistent-compile-cache counters: a cold run shows hits == 0,
         # the next identical run shows hits > 0 and a smaller warmup_s
         "compile_cache": compilecache.stats(),
@@ -617,27 +443,9 @@ def main():
         "pass2_keys": pass2_keys,
         "pass_keys": pass1_keys,
         "native_store": native_store,
-        "platform": info["platform"],
+        "device": device.as_dict(),
         "auc": round(out["auc"], 4),
     }
-    no_cache = os.environ.get("PBOX_BENCH_NO_CACHE", "0") == "1"
-    if info["platform"] == "tpu" and not pv and not no_cache:
-        # Cache this healthy-chip measurement; a later wedged run emits it
-        # as "last_good_tpu" alongside its CPU fallback number. (pv-mode
-        # runs live in the capture artifact's ablation slot instead, and
-        # the capture tool sets PBOX_BENCH_NO_CACHE for its ablation/sweep
-        # runs — bench_config_id doesn't encode knobs, so a degraded
-        # non-default run must not shadow the default-knob headline.)
-        try:
-            cached = dict(result)
-            cached["measured_at"] = time.strftime(
-                "%Y-%m-%dT%H:%M:%SZ", time.gmtime()
-            )
-            cached["bench_config"] = bench_config_id()
-            with open(LAST_GOOD_PATH, "w") as f:
-                json.dump(cached, f)
-        except OSError:
-            pass
     print(json.dumps(result))
 
 

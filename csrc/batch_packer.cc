@@ -126,16 +126,17 @@ void pbx_gather_f32_slot(const float* values, const int64_t* base,
 // counter side — one GIL-released native sweep over the whole block
 // matrix replaces a per-(device, batch) Python unique/bincount loop.
 //
-// rows: int32 [total_keys] pass-local row per key occurrence;
-// base/counts: int64 [n_records] flat key span per record;
+// rows: int32 [n_keys] pass-local row per key occurrence;
+// base/counts: int64 [n_records] flat key span per record (every span
+// must lie inside [0, n_keys));
 // indices: int64 [n_blocks * b] record ids, row-major blocks.
 // Dedup is a per-block gather + sort + run walk: work scales with the
 // block's key count, never with the table's row count (an epoch-stamp
 // table over the row id space would memset O(n_rows) per CALL — at a
 // 45M-row pass that is 365 MB of writes before any work). The scratch
 // buffer reuses its high-water allocation across blocks. Returns 0, or
-// -1 on an out-of-range record/row.
-int pbx_block_stats(const int32_t* rows, const int64_t* base,
+// -1 on an out-of-range record/row/key span.
+int pbx_block_stats(const int32_t* rows, int64_t n_keys, const int64_t* base,
                     const int64_t* counts, int64_t n_records,
                     const int64_t* indices, int64_t n_blocks, int64_t b,
                     int64_t cap, int64_t ns, int64_t n_rows,
@@ -146,7 +147,9 @@ int pbx_block_stats(const int32_t* rows, const int64_t* base,
     int64_t L = 0;
     for (int64_t i = 0; i < b; ++i) {
       const int64_t r = idx[i];
-      if (r < 0 || r >= n_records || counts[r] < 0) return -1;
+      if (r < 0 || r >= n_records || counts[r] < 0 || base[r] < 0 ||
+          counts[r] > n_keys - base[r])
+        return -1;
       L += counts[r];
     }
     buf.resize((size_t)L);

@@ -5,7 +5,7 @@ and incident notes — fed unconditionally (tracing enabled or not) by
 ``utils/trace.py`` — plus a one-call ``dump()`` that publishes an atomic
 ``incident-<ts>.json`` bundle (recent spans + incidents + a full stat and
 histogram snapshot) when something fatal happens: DataPoisonedError,
-PeerDeadError, CoordinatedAbort, a wedged backend init. Postmortems no
+PeerDeadError, CoordinatedAbort. Postmortems no
 longer depend on having had tracing enabled in advance: the last N spans
 before the death are always there.
 

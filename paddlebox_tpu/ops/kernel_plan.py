@@ -61,15 +61,10 @@ def log2_bucket(n: int) -> int:
 
 
 def current_backend() -> str:
-    """The default jax backend name, or "none" before/without one."""
-    try:
-        import jax
+    """The default jax backend name."""
+    import jax
 
-        return jax.default_backend()
-    # absence probe: "none" IS the answer (dispatch falls back to XLA ops)
-    # pbox-lint: disable=EXC007
-    except Exception:  # pragma: no cover - no backend at all
-        return "none"
+    return jax.default_backend()
 
 
 # lookup probe order per (op, backend): exact bucket first, then wildcard

@@ -47,19 +47,6 @@ from paddlebox_tpu.ops.kernel_plan import PALLAS_LANE as LANE  # Mosaic lane wid
 # multiple to DMA-slice. The constants live in kernel_plan.py so the plan's
 # eligibility clamp and these kernels can never disagree.)
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams and grew a
-# has_side_effects field along the way; accept both and drop unknown fields
-# (on older jax the input_output_aliases on the call already keep the
-# effectful write live — has_side_effects is belt-and-braces there)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
-def _compiler_params(**kw):
-    import dataclasses
-
-    known = {f.name for f in dataclasses.fields(_CompilerParams)}
-    return _CompilerParams(**{k: v for k, v in kw.items() if k in known})
-
 
 def _gather_kernel(rows_ref, table_ref, out_ref, sems):
     i = pl.program_id(0)
@@ -143,15 +130,6 @@ def write_rows_pallas(
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         grid_spec=grid_spec,
         input_output_aliases={1: 0},  # table (first arg after scalars) -> out
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(rows.astype(jnp.int32), table, new_rows)
-
-
-def backend_is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    # absence probe: False IS the answer (callers take the XLA path)
-    # pbox-lint: disable=EXC007
-    except Exception:  # pragma: no cover
-        return False

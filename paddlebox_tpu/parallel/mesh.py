@@ -28,37 +28,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def axis_size(axis_name: str) -> int:
-    """``lax.axis_size`` across jax versions: 0.4.x has no lax.axis_size,
-    but ``jax.core.axis_frame(name)`` returns the same static size inside
-    a shard_map/pmap body."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    import jax.core
-
-    return jax.core.axis_frame(axis_name)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes shard_map at top level with the replication check
-    named ``check_vma``; 0.4.x only has jax.experimental.shard_map with the
-    same knob named ``check_rep``. Every mesh-step maker routes through
-    this wrapper so the supported jax range is decided in one place."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
+# the mesh-step makers and tests import these two names from here
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 @dataclass(frozen=True)

@@ -122,7 +122,7 @@ class DeviceScoringTier:
             fn = jax.jit(
                 shard_map(
                     body,
-                    plan.mesh,
+                    mesh=plan.mesh,
                     in_specs=(P(axis), P(axis)),
                     out_specs=P(axis),
                 )

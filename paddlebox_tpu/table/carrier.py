@@ -126,13 +126,13 @@ class TableCarrier:
         )
 
     def push_departures_async(self, table, keys: np.ndarray, positions) -> None:
-        """Push the departing slice on a background thread: the D2H (the
-        expensive part on a tunneled transport) overlaps the next pass's
-        load/train instead of stalling the boundary. The device gather
-        dispatches NOW (so it reads this table's values, not anything
-        later); only the host fetch + push run on the worker. Joined by
-        flush(), and by the next end_pass before host decay (a late push
-        landing after a decay would un-decay those rows)."""
+        """Push the departing slice on a background thread: the D2H
+        overlaps the next pass's load/train instead of stalling the
+        boundary. The device gather dispatches NOW (so it reads this
+        table's values, not anything later); only the host fetch + push
+        run on the worker. Joined by flush(), and by the next end_pass
+        before host decay (a late push landing after a decay would
+        un-decay those rows)."""
         from concurrent.futures import Future
 
         from paddlebox_tpu import config

@@ -70,7 +70,7 @@ class ResidentPass:
         dense_dim: int = 0,
         label_slot: Optional[str] = None,
         bucket: Optional[int] = None,
-        plan=None,  # MeshPlan; needed only multi-host
+        plan=None,  # MeshPlan of the mesh tier (None = single device)
         transport=None,  # host plane; multi-host placement + lockstep
     ):
         self.store = store
@@ -114,6 +114,13 @@ class ResidentPass:
                 from paddlebox_tpu.parallel.mesh import put_per_device_copies
 
                 return put_per_device_copies(plan, a)
+            if plan is not None:
+                # single-host mesh: replicate ONCE here — left on the
+                # default device, every superstep dispatch would re-copy
+                # the pass arrays to the other chips for its P() argument
+                from paddlebox_tpu.parallel.mesh import put_replicated
+
+                return put_replicated(plan, a)
             return jnp.asarray(a)
 
         self.rows = place(_pad(rows.astype(np.int32), L_max))

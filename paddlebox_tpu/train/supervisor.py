@@ -404,20 +404,14 @@ class PassSupervisor:
             and getattr(dataset, "quarantine_dir", "absent") is None
         ):
             dataset.quarantine_dir = os.path.join(checkpoint.root, "quarantine")
-        # backend bring-up through the watchdog (no-op when jax is already
-        # initialized — i.e. in every in-process test — but a cold trainer
-        # entrypoint on a wedged TPU falls back to CPU instead of hanging),
-        # then the persistent compile cache: "auto" resolves under the
-        # durable checkpoint root, next to the checkpoints it warms
+        # a supervisor without a device fails here, at construction, with
+        # the typed error — not mid-pass; then the persistent compile
+        # cache, placed by utils/compilecache's rule (never under the
+        # checkpoint root: a root that moves is a cache that never hits)
         from paddlebox_tpu.utils import backendguard, compilecache
 
-        self.backend_verdict = backendguard.ensure_backend()
-        cache_dir = compilecache.resolve_dir(
-            str(config.get_flag("compile_cache_dir")),
-            ckpt_root=checkpoint.root if checkpoint is not None else None,
-        )
-        if cache_dir is not None:
-            compilecache.enable(cache_dir)
+        self.backend = backendguard.bring_up()
+        compilecache.enable()
         # telemetry plane: metric series + incident bundles live under the
         # durable checkpoint root (obs/) so postmortems travel with the
         # artifacts they explain; without a checkpoint both stay off

@@ -1,265 +1,63 @@
-"""Watchdogged jax backend bring-up with a clean CPU fallback.
+"""In-process jax backend bring-up: report the device, or raise.
 
-The TPU runtime in this environment can wedge FOREVER inside backend init
-(``make_c_api_client``; every bench round since r03 recorded it). A hung
-import in-process is unkillable — so the first touch of the backend happens
-in a SUBPROCESS with a hard watchdog timeout, and only after the probe
-reports a live platform does the calling process initialize jax itself.
-This generalizes the probe logic that grew inside bench.py /
-tools/tpu_capture.py into the one implementation every entrypoint shares
-(bench.py, tools/*, and the trainer supervisor).
-
-Contract:
-
-- ``probe_backend``   one subprocess probe under ``backend_init_timeout_s``;
-                      the ``backend.init`` fault site lets chaos tests
-                      simulate a wedged runtime deterministically.
-- ``ensure_backend``  retry loop + decision: returns a :class:`BackendVerdict`
-                      whose ``verdict`` is ``"ok"`` (requested backend up) or
-                      ``"fallback_cpu"`` (requested backend wedged/absent —
-                      the process was switched to the CPU backend so work
-                      CONTINUES, labeled, instead of hanging a driver for
-                      900s). It never writes any artifact — in particular it
-                      can never clobber ``tools/last_good_tpu_capture.json``;
-                      recording the verdict is the caller's job.
-
-Probing is skipped (``probe="auto"``) when the backend is already
-initialized in-process or the environment pins a non-TPU platform — a CPU
-CI run pays zero subprocesses.
+A run is one process on one machine that either holds the accelerator or
+does not. There is nothing to outlast and nobody to hand the chip to: a
+probe child would be a second process asking for a chip the parent may
+already hold. So bring-up is ``jax.devices()`` in this process, and a
+platform that is not the one the caller needs is a typed error — never a
+switch to another platform. A measurement entry point (``chip_smoke.py``,
+``bench.py``) passes ``require="tpu"``; code that is correct on any
+platform (the trainer supervisor, the CPU test suite) passes nothing and
+gets the device stamp for its records.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import time
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
-
-from paddlebox_tpu import config
-from paddlebox_tpu.utils.faultinject import InjectedFault, fire
-from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
-
-config.define_flag(
-    "backend_init_timeout_s",
-    120.0,
-    "watchdog on each subprocess backend-init probe: a TPU runtime that "
-    "doesn't come up within this is declared wedged (the probe child is "
-    "killed; a hung in-process init would be unkillable)",
-)
-config.define_flag(
-    "backend_init_retries",
-    6,
-    "backend-init probes before giving up on the requested backend and "
-    "falling back to CPU (wedges observed to last hours-but-not-forever; "
-    "retrying maximizes the chance of a real measurement)",
-)
-config.define_flag(
-    "backend_init_backoff_s",
-    30.0,
-    "first sleep between backend-init probes, doubled each retry and "
-    "capped at 120s",
-)
+from dataclasses import asdict, dataclass
+from typing import Dict, Optional
 
 
-@dataclass
-class BackendVerdict:
-    """Outcome of backend bring-up, recorded into bench/capture artifacts."""
+class BackendUnavailableError(RuntimeError):
+    """jax has no backend, or not the platform the caller requires."""
+
+
+@dataclass(frozen=True)
+class BackendInfo:
+    """The device as jax reports it (``jax.devices()[0]``)."""
 
     platform: str
+    device_kind: str
     n_devices: int
-    verdict: str  # "ok" | "fallback_cpu"
-    wedged: bool = False  # the REQUESTED backend never came up
-    probed: bool = False  # at least one subprocess probe ran
-    error: Optional[str] = None  # last probe failure when wedged
-    probe_log: List[Dict] = field(default_factory=list)
 
     def as_dict(self) -> Dict:
-        d = {
-            "platform": self.platform,
-            "n_devices": self.n_devices,
-            "verdict": self.verdict,
-            "wedged": self.wedged,
-            "probed": self.probed,
-        }
-        if self.error is not None:
-            d["error"] = self.error
-        if self.probe_log:
-            d["probe_log"] = self.probe_log
-        return d
+        return asdict(self)
 
 
-def _initialized_platform() -> Optional[str]:
-    """Platform of an already-initialized in-process backend, else None."""
-    try:
-        from jax._src import xla_bridge
+def bring_up(require: Optional[str] = None) -> BackendInfo:
+    """Initialize the jax backend in this process and describe it.
 
-        if xla_bridge.backends_are_initialized():
-            import jax
-
-            return jax.default_backend()
-    # probe child: None IS the answer (the parent counts/alarms on it)
-    # pbox-lint: disable=EXC007
-    except Exception:
-        return None
-    return None
-
-
-def probe_backend(timeout_s: Optional[float] = None) -> Tuple[Optional[dict], Optional[str]]:
-    """Initialize the jax backend in a SUBPROCESS with a hard timeout.
-
-    Returns ``(info, None)`` on success (``info`` = {"platform",
-    "n_devices"}) or ``(None, reason)`` on failure — a hung child is killed
-    at the watchdog deadline; a hung import in this process would not be.
-    The ``backend.init`` fault site fires first so chaos schedules can
-    simulate a wedged runtime without owning a wedgeable chip.
+    ``require`` names the platform the caller cannot do without
+    ("tpu"); anything else found raises :class:`BackendUnavailableError`
+    naming both. A backend that fails to initialize at all raises the
+    same type with jax's reason attached.
     """
-    if timeout_s is None:
-        timeout_s = float(config.get_flag("backend_init_timeout_s"))
-    STAT_ADD("backend.init_probes")
-    try:
-        fire("backend.init")
-    except InjectedFault as e:
-        # simulated wedge: the probe "consumed" its slice and saw nothing
-        return None, f"backend init wedged (injected: {e})"
-    code = (
-        "import jax, json; d = jax.devices(); "
-        "print(json.dumps({'platform': d[0].platform, 'n_devices': len(d)}))"
-    )
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", code],
-            capture_output=True,
-            text=True,
-            timeout=timeout_s,
-        )
-    except subprocess.TimeoutExpired:
-        return None, f"backend init timed out after {timeout_s:.0f}s (wedged TPU init?)"
-    if proc.returncode != 0:
-        tail = (proc.stderr or "").strip().splitlines()[-3:]
-        return None, f"backend init failed rc={proc.returncode}: " + " | ".join(tail)
-    try:
-        return json.loads(proc.stdout.strip().splitlines()[-1]), None
-    except (ValueError, IndexError):
-        return None, f"backend probe produced no JSON: {proc.stdout[-200:]!r}"
-
-
-def probe_backend_with_retries(
-    timeout_s: Optional[float] = None,
-    retries: Optional[int] = None,
-    backoff_s: Optional[float] = None,
-    sleep=time.sleep,
-) -> Tuple[Optional[dict], List[Dict]]:
-    """Probe repeatedly with doubling backoff before giving up.
-
-    Returns ``(info, probe_log)``; ``info`` is None if every probe failed.
-    Each log entry is {"ts", "elapsed_s", "ok", "detail"} — the multi-probe
-    wedge evidence callers record when the backend never comes up.
-    """
-    if retries is None:
-        retries = max(1, int(config.get_flag("backend_init_retries")))
-    if backoff_s is None:
-        backoff_s = float(config.get_flag("backend_init_backoff_s"))
-    probe_log: List[Dict] = []
-    for attempt in range(retries):
-        t0 = time.time()
-        info, err = probe_backend(timeout_s)
-        entry = {
-            "ts": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime(t0)),
-            "elapsed_s": round(time.time() - t0, 1),
-            "ok": err is None,
-            "detail": "ok" if err is None else err,
-        }
-        probe_log.append(entry)
-        # progress to stderr as it happens: a driver with a wall-clock
-        # watchdog must see life during the retry budget, or it kills the
-        # run before the JSON evidence is ever emitted
-        print(
-            f"[backendguard] probe {attempt + 1}/{retries}: {entry['detail']}",
-            file=sys.stderr,
-            flush=True,
-        )
-        if err is None:
-            return info, probe_log
-        if attempt + 1 < retries:
-            sleep(min(backoff_s, 120.0))
-            backoff_s *= 2
-    return None, probe_log
-
-
-def ensure_backend(
-    timeout_s: Optional[float] = None,
-    retries: Optional[int] = None,
-    backoff_s: Optional[float] = None,
-    probe: str = "auto",
-    sleep=time.sleep,
-) -> BackendVerdict:
-    """Bring up a usable jax backend, falling back to CPU on a wedge.
-
-    ``probe`` is "auto" (skip the subprocess when the backend is already
-    initialized in-process or JAX_PLATFORMS pins a non-TPU platform),
-    "always", or "never" (trust in-process init; only for tests).
-    Raises only if even the CPU fallback cannot initialize.
-    """
-    if probe not in ("auto", "always", "never"):
-        raise ValueError(f"probe={probe!r} not in ('auto', 'always', 'never')")
-    if probe != "always":
-        live = _initialized_platform()
-        if live is not None:
-            import jax
-
-            return BackendVerdict(
-                platform=live, n_devices=jax.device_count(), verdict="ok"
-            )
-        plats = os.environ.get("JAX_PLATFORMS", "")
-        if probe == "never" or (plats and "tpu" not in plats.lower()):
-            # a pinned non-TPU platform can't wedge the way the TPU
-            # runtime does; init in-process without a subprocess
-            import jax
-
-            d = jax.devices()
-            return BackendVerdict(
-                platform=d[0].platform, n_devices=len(d), verdict="ok"
-            )
-
-    info, probe_log = probe_backend_with_retries(
-        timeout_s, retries, backoff_s, sleep=sleep
-    )
-    if info is not None:
-        return BackendVerdict(
-            platform=str(info["platform"]),
-            n_devices=int(info["n_devices"]),
-            verdict="ok",
-            probed=True,
-            probe_log=probe_log,
-        )
-
-    # Wedged/absent accelerator after the full retry budget: switch THIS
-    # process to the CPU backend so the caller still runs end to end —
-    # clearly labeled instead of silently degraded or hung.
-    STAT_SET("backend.init_wedged", 1)
-    err = probe_log[-1]["detail"] if probe_log else "no probe ran"
-    # a wedge is a flight-recorder incident: the bundle (when a dump dir
-    # is configured) captures the probe log and every stat leading up to
-    # the fallback, which is the whole postmortem for "why was this run
-    # on CPU"
-    from paddlebox_tpu.obs.flight_recorder import FLIGHT_RECORDER
-
-    FLIGHT_RECORDER.note_incident(
-        "backend_wedge", {"error": err, "probes": len(probe_log)})
-    FLIGHT_RECORDER.dump("backend_wedge", detail=err)
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-    d = jax.devices()  # raises only if even CPU cannot come up
-    return BackendVerdict(
-        platform=d[0].platform,
-        n_devices=len(d),
-        verdict="fallback_cpu",
-        wedged=True,
-        probed=True,
-        error=err,
-        probe_log=probe_log,
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:
+        raise BackendUnavailableError(
+            f"jax could not initialize a backend: {e}"
+        ) from e
+    info = BackendInfo(
+        platform=devices[0].platform,
+        device_kind=devices[0].device_kind,
+        n_devices=len(devices),
     )
+    if require is not None and info.platform != require:
+        raise BackendUnavailableError(
+            f"this run needs a {require} device and jax found "
+            f"{info.n_devices} x {info.platform} ({info.device_kind}); "
+            "there is no CPU fallback — run it where the chip is"
+        )
+    return info

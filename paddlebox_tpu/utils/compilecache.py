@@ -1,29 +1,25 @@
-"""Persistent XLA compile cache: kill warmup variance across runs.
+"""Persistent XLA compile cache: one place decides where it lives.
 
-``warmup_s`` swung 8-33s across bench rounds because every process paid
-full XLA compilation of the same programs (same shapes — the pad-bucket
-discipline exists precisely so shapes repeat). jax ships a persistent
-compilation cache keyed on the HLO; pointing it at a durable directory
-turns warmup into a cold-vs-warm PAIR: the first run compiles and
-populates, every later run (or process) with identical programs loads the
-compiled executable from disk.
+Every process pays full XLA compilation of the same programs (same shapes
+— the pad-bucket discipline exists precisely so shapes repeat). jax ships
+a persistent compilation cache keyed on the HLO *and the cache path*, so
+the directory must not move between runs:
 
-This module is the one place that enables it and counts it:
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax already read it at import; this
+  module uses it and sets NO directory in code, so whoever launched the
+  process (a chip machine that keeps a cache between calls) owns the
+  placement.
+- otherwise ``<checkout>/.jax_cache`` (gitignored) — fixed, never derived
+  from a temp name, pid, time or checkpoint root.
+- the ``compile_cache_dir`` flag only turns the cache off (``off``; the
+  test suite runs that way so tests never write into the checkout).
 
-- :func:`enable` wires ``jax_compilation_cache_dir`` (plus the thresholds
-  that would otherwise skip small/fast CPU programs — the tier-1 suite and
-  the CPU-fallback bench must be able to verify the machinery without a
-  TPU) and registers a ``jax.monitoring`` listener ONCE per process.
-- hit/miss counters surface as ``compile_cache.*`` stats and through
-  :func:`stats`, which bench.py embeds in its JSON so a cold run
-  (hits == 0) and a warm run (hits > 0, lower ``warmup_s``) are
-  distinguishable in the artifact record.
-
-Resolution policy (``compile_cache_dir`` flag): "auto" means "under the
-durable checkpoint root" — the trainer supervisor resolves it to
-``<ckpt_root>/compile_cache`` next to the checkpoints whose job it warms;
-entrypoints without a checkpoint root (bench.py) treat "auto" as off
-unless an explicit directory is given. "off"/"" disables.
+:func:`enable` applies that rule, drops the size/time thresholds so every
+program is cached (the pass boundary's small eager scatters included), and
+registers a ``jax.monitoring`` listener ONCE per process; hit/miss/request
+counters surface as ``compile_cache.*`` stats and through :func:`stats`,
+which ``chip_smoke.py`` and ``bench.py`` embed in their JSON so a cold run
+(hits == 0) and a warm run (hits > 0, shorter warm-up) are distinguishable.
 """
 
 from __future__ import annotations
@@ -35,17 +31,32 @@ from typing import Dict, Optional
 from paddlebox_tpu import config
 from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_GET
 
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def _check_flag(v: str) -> None:
+    if v not in ("auto", "off"):
+        raise ValueError(
+            f"compile_cache_dir={v!r}: 'auto' or 'off' (place the cache "
+            f"with the {_ENV} environment variable)"
+        )
+
+
 config.define_flag(
     "compile_cache_dir",
     "auto",
-    "persistent XLA compile cache directory: 'auto' resolves to "
-    "<checkpoint_root>/compile_cache when a supervisor owns a checkpoint "
-    "root (and stays off for root-less entrypoints unless set explicitly); "
-    "'off' disables; any other value is the cache directory itself",
+    f"persistent XLA compile cache: 'auto' = ${_ENV} when set, else the "
+    "fixed <checkout>/.jax_cache; 'off' disables",
+    validator=_check_flag,
 )
 
 _lock = threading.Lock()
 _state = {"dir": None, "listener": False}  # guarded-by: _lock
+
 
 def _listener(event: str, **kwargs) -> None:
     # jax.monitoring event -> our stat, one literal per branch
@@ -57,66 +68,32 @@ def _listener(event: str, **kwargs) -> None:
         STAT_ADD("compile_cache.requests")
 
 
-def resolve_dir(flag_value: str, ckpt_root: Optional[str] = None) -> Optional[str]:
-    """compile_cache_dir flag -> concrete directory or None (disabled)."""
-    v = (flag_value or "").strip()
-    if v in ("", "off", "none"):
-        return None
-    if v == "auto":
-        if ckpt_root:
-            return os.path.join(ckpt_root, "compile_cache")
-        return None
-    return v
-
-
-def enable(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir``.
-
-    Idempotent; re-pointing at a different directory is allowed (the cache
-    is process-global, so the last enable wins — jax reads the config at
-    each compile). Returns the directory. Thresholds are dropped to zero so
-    CPU-sized programs cache too — without that, the machinery is
-    unverifiable anywhere but on a real accelerator.
-    """
+def enable() -> Optional[str]:
+    """Turn the persistent cache on where the policy says; returns the
+    directory, or None when the flag says ``off``. Idempotent."""
     import jax
+    from jax._src import compilation_cache
 
-    cache_dir = os.path.abspath(cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if str(config.get_flag("compile_cache_dir")) == "off":
+        return None
+    cache_dir = os.environ.get(_ENV)
+    if not cache_dir:
+        cache_dir = DEFAULT_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    # version-drift probe: the option simply not existing is fine
-    # pbox-lint: disable=EXC007
-    except Exception:  # pragma: no cover - option absent on older jax
-        pass
-    try:
-        # jax LATCHES cache-unused at the first compile that ran without a
-        # cache dir (is_cache_used checks once per task); any entrypoint
-        # that compiled anything before calling enable() would silently get
-        # no caching at all. reset_cache() clears the latch so the next
-        # compile re-evaluates against the directory just configured.
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    # jax-internals drift probe: a missing reset only re-latches the old
-    # behavior, which stats() makes visible as zero hits
-    # pbox-lint: disable=EXC007
-    except Exception:  # pragma: no cover - internal API drift
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax LATCHES cache-unused at the first compile that ran without a
+    # cache dir (is_cache_used checks once per task); an entrypoint that
+    # compiled anything before calling enable() would silently get no
+    # caching at all. reset_cache() clears the latch so the next compile
+    # re-evaluates against the directory now configured.
+    compilation_cache.reset_cache()
     with _lock:
         _state["dir"] = cache_dir
         if not _state["listener"]:
-            try:
-                from jax._src import monitoring
-
-                monitoring.register_event_listener(_listener)
-                _state["listener"] = True
-            except Exception:  # pragma: no cover - counters degrade to 0
-                # caching still works without the listener, but every
-                # hit/miss counter silently reads 0 — record the
-                # degradation once so stats() consumers can tell
-                STAT_ADD("compile_cache.listener_errors")
+            jax.monitoring.register_event_listener(_listener)
+            _state["listener"] = True
     return cache_dir
 
 
@@ -126,36 +103,26 @@ def enabled_dir() -> Optional[str]:
 
 
 def disable() -> None:
-    """Undo :func:`enable`: detach jax from the cache directory and clear
-    the cache-used latch. The cache is process-global state — tests that
-    build a supervisor (which enables it under the checkpoint root) use
-    this to keep the setting from leaking into every later test."""
+    """Undo :func:`enable`. The cache is process-global state — tests that
+    enable it use this to keep the setting from leaking into every later
+    test. A directory placed by the environment is not ours to unset."""
     import jax
+    from jax._src import compilation_cache
 
-    jax.config.update("jax_compilation_cache_dir", None)
+    if not os.environ.get(_ENV):
+        jax.config.update("jax_compilation_cache_dir", None)
     with _lock:
         _state["dir"] = None
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    # jax-internals drift probe, as in enable()
-    # pbox-lint: disable=EXC007
-    except Exception:  # pragma: no cover - internal API drift
-        pass
+    compilation_cache.reset_cache()
 
 
 def stats() -> Dict:
-    """Counters + entry census for artifact embedding (bench JSON,
-    tpu_capture artifacts). ``hits``/``misses`` are process-lifetime."""
+    """Counters + entry census for artifact embedding (smoke and bench
+    JSON). ``hits``/``misses``/``requests`` are process-lifetime."""
     d = enabled_dir()
     entries = 0
-    if d is not None:
-        try:
-            entries = sum(1 for n in os.listdir(d) if n.endswith("-cache"))
-        # pbox-lint: disable=EXC007 — the -1 label IS the record
-        except OSError:
-            entries = -1  # dir vanished under us; label, don't crash
+    if d is not None and os.path.isdir(d):
+        entries = sum(1 for n in os.listdir(d) if n.endswith("-cache"))
     return {
         "enabled": d is not None,
         "dir": d,

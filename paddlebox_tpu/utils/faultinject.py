@@ -60,11 +60,6 @@ Catalog of wired sites (see docs/ROBUSTNESS.md for the recovery matrix):
                             file is opened/read — an injected failure is a
                             synthetic unreadable file (quarantined whole in
                             data_quarantine mode)
-    backend.init            utils/backendguard.py  before each subprocess
-                            backend-init probe — an injected failure is a
-                            simulated wedged TPU runtime, exercising the
-                            watchdog + CPU-fallback path without owning a
-                            wedgeable chip
     serve.apply_delta       serve/scoring_table.py  commit(): after the next
                             scoring-table version is fully built, before the
                             atomic swap — a failure is a follower crash
@@ -234,7 +229,6 @@ KNOWN_SITES = (
     "boundary.writeback",
     "parser.parse_line",
     "data.file_read",
-    "backend.init",
     "serve.apply_delta",
     "spill.io",
     "spill.stage_flush",

@@ -3,7 +3,9 @@
 The reference's data loader is C++ worker threads parsing sample text
 (data_feed.cc:2951-3061); this module is that native tier here. The library
 is built on demand with g++ (no pybind11 in the image — plain C ABI +
-ctypes, per the runtime's binding policy) and cached under csrc/build/.
+ctypes, per the runtime's binding policy) and cached under csrc/build/,
+named by a hash of its sources: a copied checkout can carry a stale .so
+with a fresh mtime, but never one whose name matches other sources.
 
 ``parse_buffer(data, schema)`` parses a whole file's bytes in one native
 call and wraps the columnar result in per-record numpy VIEWS over two big
@@ -15,6 +17,8 @@ remains both the fallback and the semantics oracle (tests assert equality).
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -31,7 +35,8 @@ _SRCS = [
     os.path.join(_REPO, "csrc", "batch_packer.cc"),
     os.path.join(_REPO, "csrc", "host_table.cc"),
 ]
-_LIB = os.path.join(_REPO, "csrc", "build", "libpbx_parser.so")
+_CXXFLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17"]
+_BUILD_DIR = os.path.join(_REPO, "csrc", "build")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -62,22 +67,28 @@ IO_STAT_FIELDS = (
 )
 
 
-def _build() -> bool:
-    os.makedirs(os.path.dirname(_LIB), exist_ok=True)
-    # compile to a tmp path, then atomic-rename: overwriting the .so in
-    # place would scribble on pages another live process has dlopen-mapped
-    # (and a concurrent builder/loader would see a half-written file);
-    # os.replace gives every reader either the old inode or the new one
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+def _lib_path() -> str:
+    """csrc/build/libpbx_parser-<hash of sources + compile flags>.so"""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_BUILD_DIR, f"libpbx_parser-{h.hexdigest()[:16]}.so")
+
+
+def _build(lib_path: str) -> bool:
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    # compile to a tmp path, then atomic-rename: a concurrent builder or
+    # loader sees either no file or the whole one, never a half-written .so
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp] + _SRCS,
+            ["g++"] + _CXXFLAGS + ["-o", tmp] + _SRCS,
             check=True,
             capture_output=True,
             timeout=120,
         )
-        os.replace(tmp, _LIB)
-        return True
+        os.replace(tmp, lib_path)
     except Exception:
         # every caller silently falls back to the pure-Python paths on
         # False — a 10x parse/pull slowdown nobody asked for must at
@@ -92,17 +103,12 @@ def _build() -> bool:
         except OSError:
             pass
         return False
-
-
-def _stale() -> bool:
-    """Rebuild when any source is newer than the cached .so."""
-    try:
-        t = os.path.getmtime(_LIB)
-        return any(os.path.getmtime(s) > t for s in _SRCS)
-    # staleness probe: a vanished .so or source answers "rebuild"
-    # pbox-lint: disable=EXC007
-    except OSError:
-        return True
+    # libraries built from other sources are dead weight in every copy of
+    # the checkout (an unlinked .so stays mapped where it is loaded)
+    for old in glob.glob(os.path.join(_BUILD_DIR, "libpbx_parser-*.so")):
+        if old != lib_path:
+            os.unlink(old)
+    return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -114,10 +120,13 @@ def _load() -> Optional[ctypes.CDLL]:
         # PBOX_NATIVE_LIB points the whole native tier at a prebuilt .so
         # (tools/native_sanitize.py replays the test suite against an
         # ASan+UBSan-instrumented build this way); the override is never
-        # rebuilt or staleness-checked — the caller owns its lifecycle
-        lib_path = os.environ.get("PBOX_NATIVE_LIB") or _LIB
-        if lib_path == _LIB and (not os.path.exists(_LIB) or _stale()):
-            if not (all(os.path.exists(s) for s in _SRCS) and _build()):
+        # rebuilt — the caller owns its lifecycle
+        lib_path = os.environ.get("PBOX_NATIVE_LIB")
+        if not lib_path:
+            if not all(os.path.exists(s) for s in _SRCS):
+                return None
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path) and not _build(lib_path):
                 return None
         try:
             lib = ctypes.CDLL(lib_path)
@@ -169,7 +178,8 @@ def _load() -> Optional[ctypes.CDLL]:
         ]
         lib.pbx_block_stats.restype = ctypes.c_int
         lib.pbx_block_stats.argtypes = [
-            _i32p, _i64p, _i64p, ctypes.c_int64, _i64p, ctypes.c_int64,
+            _i32p, ctypes.c_int64, _i64p, _i64p, ctypes.c_int64, _i64p,
+            ctypes.c_int64,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
             _i64p, _i64p,
         ]
@@ -303,6 +313,7 @@ def block_stats(
     bmax_out = np.empty(n_blocks, np.int64)
     rc = lib.pbx_block_stats(
         _as_ptr(rows, ctypes.c_int32),
+        len(rows),
         _as_ptr(rec_base, ctypes.c_int64),
         _as_ptr(key_counts, ctypes.c_int64),
         len(rec_base),
@@ -312,7 +323,8 @@ def block_stats(
         _as_ptr(bmax_out, ctypes.c_int64),
     )
     if rc != 0:
-        raise ValueError("block_stats: record index or row out of range")
+        raise ValueError(
+            "block_stats: record index, row or key span out of range")
     return L_out, bmax_out
 
 

@@ -1,9 +1,12 @@
-"""Backend-init watchdog (utils/backendguard.py) and the persistent XLA
-compile cache (utils/compilecache.py): wedged init must fall back to CPU
-inside the configured deadline, and a warm cache must report hits."""
+"""In-process backend bring-up (utils/backendguard.py) and the persistent
+XLA compile cache's placement rule (utils/compilecache.py): a missing
+platform is a typed error, never a switch to the CPU; the cache goes where
+JAX_COMPILATION_CACHE_DIR says, else to one fixed path in the checkout."""
 
 import os
-import time
+import shutil
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -13,166 +16,163 @@ import pytest
 from paddlebox_tpu import config
 from paddlebox_tpu.utils import compilecache
 from paddlebox_tpu.utils.backendguard import (
-    BackendVerdict,
-    ensure_backend,
-    probe_backend,
-    probe_backend_with_retries,
+    BackendInfo,
+    BackendUnavailableError,
+    bring_up,
 )
-from paddlebox_tpu.utils.faultinject import fail_always, fail_once, inject
 from paddlebox_tpu.utils.monitor import STAT_GET
 
-
-def test_wedged_init_falls_back_to_cpu_within_deadline():
-    """The acceptance scenario: every probe wedges (injected at the
-    backend.init site), and ensure_backend must return a labeled
-    fallback_cpu verdict within retries x timeout — not hang."""
-    timeout_s, retries = 2.0, 3
-    deadline = retries * timeout_s + 5.0
-    slept = []
-    t0 = time.monotonic()
-    with inject(fail_always("backend.init")) as plan:
-        v = ensure_backend(
-            timeout_s=timeout_s,
-            retries=retries,
-            backoff_s=0.0,
-            probe="always",
-            sleep=slept.append,  # no real sleeping between probes
-        )
-        assert plan.failures("backend.init") == retries
-    elapsed = time.monotonic() - t0
-    assert elapsed <= deadline
-    assert v.verdict == "fallback_cpu"
-    assert v.wedged and v.probed
-    assert v.platform == "cpu" and v.n_devices >= 1
-    assert "wedged" in (v.error or "")
-    assert len(v.probe_log) == retries
-    assert all(not e["ok"] for e in v.probe_log)
-    assert len(slept) == retries - 1  # backoff between probes, not after last
-    assert STAT_GET("backend.init_wedged") == 1
-    # work continues on the fallback: the process has a live CPU backend
-    assert float(jnp.sum(jnp.ones(4))) == 4.0
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_wedged_verdict_serializes_for_artifacts():
-    with inject(fail_always("backend.init")):
-        v = ensure_backend(
-            timeout_s=1.0, retries=1, probe="always", sleep=lambda s: None
-        )
-    d = v.as_dict()
-    assert d["verdict"] == "fallback_cpu"
-    assert d["wedged"] is True
-    assert d["error"] and d["probe_log"]
-    # ok verdicts omit the failure fields entirely
-    ok = BackendVerdict(platform="cpu", n_devices=1, verdict="ok").as_dict()
-    assert "error" not in ok and "probe_log" not in ok
+def test_missing_platform_raises_typed_error_and_never_switches():
+    """The acceptance scenario: a TPU is required, jax has the CPU. The
+    error names both, and the process is left on the platform it had."""
+    with pytest.raises(BackendUnavailableError) as ei:
+        bring_up(require="tpu")
+    assert "tpu" in str(ei.value) and "cpu" in str(ei.value)
+    assert isinstance(ei.value, RuntimeError)
+    assert jax.default_backend() == "cpu"
+    assert float(jnp.sum(jnp.ones(4))) == 4.0  # still usable, still CPU
 
 
-def test_initialized_backend_short_circuits():
-    """probe='auto' with a live in-process backend: no subprocess, verdict
-    ok immediately (the zero-cost CI path)."""
-    jnp.zeros(1).block_until_ready()  # force backend init
-    before = STAT_GET("backend.init_probes")
-    v = ensure_backend()
-    assert v.verdict == "ok" and not v.probed and not v.wedged
-    assert v.platform == jax.default_backend()
-    assert STAT_GET("backend.init_probes") == before  # no probe ran
+def test_backend_info_serializes_for_artifacts():
+    d = bring_up().as_dict()
+    assert d == {
+        "platform": "cpu",
+        "device_kind": jax.devices()[0].device_kind,
+        "n_devices": jax.device_count(),
+    }
+    assert BackendInfo(**d) == bring_up()
 
 
-@pytest.mark.slow
-def test_real_subprocess_probe_succeeds_on_cpu():
-    """The actual watchdog path: a child python initializes jax and
-    reports its platform (CPU here; TPU on hardware)."""
-    info, err = probe_backend(timeout_s=180.0)
-    assert err is None, err
-    assert info["platform"] in ("cpu", "tpu", "gpu")
-    assert info["n_devices"] >= 1
+def test_bring_up_is_in_process(monkeypatch):
+    """No probe child: a second process would ask for a chip this one may
+    already hold."""
+
+    def no_children(*a, **k):
+        raise AssertionError("backend bring-up spawned a process")
+
+    monkeypatch.setattr(subprocess, "run", no_children)
+    monkeypatch.setattr(subprocess, "Popen", no_children)
+    info = bring_up(require=jax.default_backend())
+    assert info.platform == jax.default_backend()
+    assert info.n_devices == jax.device_count()
 
 
-@pytest.mark.slow
-def test_retry_recovers_from_transient_wedge():
-    """fail_once wedges the first probe only; the second real probe
-    succeeds and the log records one failure then one success."""
-    with inject(fail_once("backend.init")) as plan:
-        info, log = probe_backend_with_retries(
-            timeout_s=180.0, retries=2, backoff_s=0.0, sleep=lambda s: None
-        )
-        assert plan.failures("backend.init") == 1
-    assert info is not None
-    assert [e["ok"] for e in log] == [False, True]
-
-
-def test_ensure_backend_rejects_bad_probe_mode():
-    with pytest.raises(ValueError):
-        ensure_backend(probe="sometimes")
-
-
-def test_resolve_dir_policy(tmp_path):
-    for off in ("", "off", "none", None):
-        assert compilecache.resolve_dir(off) is None
-    # "auto" only engages under a durable checkpoint root
-    assert compilecache.resolve_dir("auto") is None
-    assert compilecache.resolve_dir("auto", ckpt_root=str(tmp_path)) == str(
-        tmp_path / "compile_cache"
+def _run_without_chip(cmd, cwd):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    return subprocess.run(
+        cmd, cwd=cwd, env=env, capture_output=True, text=True, timeout=300
     )
-    explicit = str(tmp_path / "cc")
-    assert compilecache.resolve_dir(explicit) == explicit
 
 
-def test_compile_cache_counts_hits(tmp_path):
-    """Enable the persistent cache, compile the same program twice from
-    distinct function objects: the second compile must be served from disk
-    and counted as a hit — the mechanism behind the cold/warm warmup_s
-    acceptance check in bench.py."""
-    cache_dir = str(tmp_path / "compile_cache")
-    old_dir = jax.config.jax_compilation_cache_dir
+@pytest.mark.slow
+def test_bench_exits_nonzero_without_a_chip():
+    proc = _run_without_chip([sys.executable, "bench.py"], REPO)
+    assert proc.returncode != 0
+    assert "tpu" in proc.stderr and "cpu" in proc.stderr
+    assert proc.stdout.strip() == ""  # no CPU number under a per-chip name
+
+
+@pytest.mark.slow
+def test_chip_smoke_fails_alone_in_a_directory(tmp_path):
+    """The driver runs the script alone, without the program: it must fail
+    there, and without a chip, and print no result either way."""
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    alone = _run_without_chip([sys.executable, "chip_smoke.py"], str(tmp_path))
+    assert alone.returncode != 0 and '"ok"' not in alone.stdout
+    here = _run_without_chip([sys.executable, "chip_smoke.py"], REPO)
+    assert here.returncode != 0 and '"ok"' not in here.stdout
+    assert "platform=cpu" in here.stdout
+
+
+def test_backend_init_failure_is_the_typed_error(monkeypatch):
+    def boom():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(BackendUnavailableError, match="Unable to initialize"):
+        bring_up()
+
+
+@pytest.fixture
+def cache_on(tmp_path, monkeypatch):
+    """compile_cache_dir=auto (the suite runs "off") with the fixed
+    in-checkout path pointed into tmp_path."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compilecache, "DEFAULT_DIR", str(tmp_path / ".jax_cache"))
     old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    config.set_flag("compile_cache_dir", "auto")
+    yield tmp_path
+    config.set_flag("compile_cache_dir", "off")
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+
+
+def test_compile_cache_flag_is_auto_or_off(cache_on):
+    assert os.path.basename(compilecache.DEFAULT_DIR) == ".jax_cache"
+    config.set_flag("compile_cache_dir", "off")
+    assert compilecache.enable() is None
+    assert compilecache.enabled_dir() is None
+    assert jax.config.jax_compilation_cache_dir is None
+    assert not os.path.exists(compilecache.DEFAULT_DIR)
+    # a path is not a value: placement belongs to the environment variable
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        config.set_flag("compile_cache_dir", "/tmp/some/dir")
+
+
+def test_compile_cache_default_path_counts_hits(cache_on):
+    """Unset environment: entries land in the one fixed directory, and the
+    same program compiled twice from distinct function objects is served
+    from disk the second time and counted as a hit."""
+    got = compilecache.enable()
+    assert got == compilecache.DEFAULT_DIR == str(cache_on / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == got
+    assert compilecache.enabled_dir() == got
+
+    hits0 = STAT_GET("compile_cache.hits")
+    misses0 = STAT_GET("compile_cache.misses")
+    x = jnp.arange(64, dtype=jnp.float32)
+    cold = np.asarray(jax.jit(lambda v: v * 3.0 + 1.0)(x))
+    assert STAT_GET("compile_cache.misses") > misses0  # populated disk
+    # a DISTINCT function object with an identical jaxpr: jax's in-memory
+    # jit cache can't serve it, the persistent cache must
+    warm = np.asarray(jax.jit(lambda v: v * 3.0 + 1.0)(x))
+    assert STAT_GET("compile_cache.hits") > hits0
+    np.testing.assert_array_equal(cold, warm)
+
+    s = compilecache.stats()
+    assert s["enabled"] and s["dir"] == got and s["entries"] >= 1
+    assert s["hits"] >= 1 and s["misses"] >= 1
+    assert s["requests"] >= s["hits"] + s["misses"] - 1
+
+
+def test_environment_places_the_cache_and_code_sets_no_directory(
+    cache_on, monkeypatch
+):
+    """JAX_COMPILATION_CACHE_DIR=/x: jax read it at import, so the module
+    must use it, count in it, and never call
+    jax.config.update("jax_compilation_cache_dir", ...) — enabling or
+    disabling."""
+    env_dir = str(cache_on / "x")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    jax.config.update("jax_compilation_cache_dir", env_dir)  # jax's import
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (updates.append(name), real_update(name, val)),
+    )
     try:
-        got = compilecache.enable(cache_dir)
-        assert got == cache_dir and os.path.isdir(cache_dir)
-        assert compilecache.enabled_dir() == cache_dir
-
-        hits0 = STAT_GET("compile_cache.hits")
-        misses0 = STAT_GET("compile_cache.misses")
-        x = jnp.arange(64, dtype=jnp.float32)
-
-        f_cold = jax.jit(lambda v: v * 3.0 + 1.0)
-        cold = np.asarray(f_cold(x))
-        assert STAT_GET("compile_cache.misses") > misses0  # populated disk
-        assert len(os.listdir(cache_dir)) > 0
-
-        # a DISTINCT function object with an identical jaxpr: jax's
-        # in-memory jit cache can't serve it, the persistent cache must
-        f_warm = jax.jit(lambda v: v * 3.0 + 1.0)
-        warm = np.asarray(f_warm(x))
-        assert STAT_GET("compile_cache.hits") > hits0
-        np.testing.assert_array_equal(cold, warm)
-
-        s = compilecache.stats()
-        assert s["enabled"] and s["dir"] == cache_dir
-        assert s["hits"] >= 1 and s["misses"] >= 1
-        assert s["requests"] >= s["hits"] + s["misses"] - 1
+        assert compilecache.enable() == env_dir
+        np.asarray(jax.jit(lambda v: v * 5.0 - 2.0)(jnp.arange(32.0)))
+        assert any(n.endswith("-cache") for n in os.listdir(env_dir))
+        assert not os.path.exists(compilecache.DEFAULT_DIR)
+        assert compilecache.stats()["dir"] == env_dir
+        compilecache.disable()
+        assert "jax_compilation_cache_dir" not in updates
+        assert "jax_persistent_cache_min_compile_time_secs" in updates
+        assert jax.config.jax_compilation_cache_dir == env_dir
     finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
-
-
-def test_legacy_env_maps_to_flags(monkeypatch):
-    """PBOX_BENCH_INIT_* env (the pre-flag interface tpu_probe_loop and
-    operators already use) must keep working by mapping onto the
-    backend_init_* flags."""
-    import bench
-
-    old = {k: config.get_flag(k) for k in
-           ("backend_init_timeout_s", "backend_init_retries",
-            "backend_init_backoff_s")}
-    monkeypatch.setenv("PBOX_BENCH_INIT_TIMEOUT", "7.5")
-    monkeypatch.setenv("PBOX_BENCH_INIT_RETRIES", "2")
-    monkeypatch.setenv("PBOX_BENCH_INIT_BACKOFF", "0.25")
-    try:
-        bench.apply_legacy_init_env()
-        assert float(config.get_flag("backend_init_timeout_s")) == 7.5
-        assert int(config.get_flag("backend_init_retries")) == 2
-        assert float(config.get_flag("backend_init_backoff_s")) == 0.25
-    finally:
-        for k, v in old.items():
-            config.set_flag(k, v)
+        real_update("jax_compilation_cache_dir", None)

@@ -13,12 +13,7 @@ pytest.importorskip("jax")
 
 def test_all_five_configs_run(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        PBOX_BENCH_INIT_RETRIES="1",
-        PBOX_BENCH_INIT_TIMEOUT="5",
-    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [
             sys.executable,
@@ -62,12 +57,7 @@ def test_all_five_configs_run_real_format(tmp_path):
         os.path.join(repo, "tests", "fixtures", "criteo_train_sample.txt"),
         data_dir / "train.txt",
     )
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        PBOX_BENCH_INIT_RETRIES="1",
-        PBOX_BENCH_INIT_TIMEOUT="5",
-    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [
             sys.executable,

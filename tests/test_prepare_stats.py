@@ -68,6 +68,19 @@ def test_block_stats_rejects_out_of_range():
         native.block_stats(rows, base, counts, bad, cap, ns)
 
 
+def test_block_stats_rejects_key_span_past_rows():
+    """A record whose (base, count) span runs past the row array must be
+    refused before the gather memcpy reads it (ADVICE r5)."""
+    rng = np.random.default_rng(5)
+    rows, base, counts, ns, cap = _synthetic_pass(rng)
+    blocks = np.arange(16, dtype=np.int64)[None]
+    for bad_base, bad_count in ((len(rows) - 1, 2), (-1, 1), (0, len(rows) + 1)):
+        b, c = base.copy(), counts.copy()
+        b[3], c[3] = bad_base, bad_count
+        with pytest.raises(ValueError, match="key span"):
+            native.block_stats(rows, b, c, blocks, cap, ns)
+
+
 def _mk_rp(rng, ns, cap):
     rows, base, counts, _, _ = _synthetic_pass(rng, ns=ns, cap=cap)
     rp = types.SimpleNamespace(

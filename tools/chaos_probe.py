@@ -272,83 +272,6 @@ def run_corrupt(args):
         return 0 if (equal and counts_match) else 1
 
 
-def run_wedge_backend(args):
-    """Wedged-backend smoke: arm the ``backend.init`` fault site so every
-    watchdog probe sees a wedged runtime, then prove the triad contract —
-    (a) ``ensure_backend`` lands on the labeled CPU fallback within the
-    retry x timeout deadline, (b) a supervised mini-day still trains end to
-    end on the fallback, and (c) ``tools/last_good_tpu_capture.json`` is
-    byte-for-byte untouched (the watchdog must never clobber the last
-    healthy chip's evidence). Exit 0 iff all three hold.
-
-      JAX_PLATFORMS=cpu python tools/chaos_probe.py --wedge-backend [--json]
-    """
-    from paddlebox_tpu import config
-    from paddlebox_tpu.utils.backendguard import ensure_backend
-    from paddlebox_tpu.utils.faultinject import fail_always, inject
-    from paddlebox_tpu.utils.monitor import STAT_GET
-
-    capture_path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tools", "last_good_tpu_capture.json",
-    )
-
-    def capture_sig():
-        try:
-            st = os.stat(capture_path)
-            return (st.st_mtime_ns, st.st_size)
-        # absence probe: None (no capture yet) IS the answer
-        # pbox-lint: disable=EXC007
-        except OSError:
-            return None
-
-    sig_before = capture_sig()
-    timeout_s, retries = 2.0, 2
-    config.set_flag("fs_open_backoff_s", 0.0)
-    deadline_s = retries * timeout_s + 5.0  # probes fail instantly when
-    # injected; the slack covers CPU backend bring-up, not probe time
-    t0 = time.perf_counter()
-    with inject(fail_always("backend.init")) as plan:
-        verdict = ensure_backend(
-            timeout_s=timeout_s, retries=retries, backoff_s=0.0,
-            probe="always", sleep=lambda s: None,
-        )
-    fallback_s = time.perf_counter() - t0
-
-    with tempfile.TemporaryDirectory() as tmpdir:
-        date = "20260101"
-        files = write_day_files(tmpdir, date, args.passes, args.rows, args.seed)
-        table, tr, sup = build_supervisor(os.path.join(tmpdir, "ckpt-wedge"))
-        t0 = time.perf_counter()
-        sup.run_day(date, [[f] for f in files])
-        day_s = time.perf_counter() - t0
-        n_keys = len(table.keys())
-
-    ok = (
-        verdict.verdict == "fallback_cpu"
-        and verdict.wedged
-        and verdict.platform == "cpu"
-        and fallback_s <= deadline_s
-        and plan.failures("backend.init") == retries
-        and capture_sig() == sig_before
-        and n_keys > 0
-    )
-    report = {
-        "mode": "wedge-backend",
-        "verdict": verdict.as_dict(),
-        "fallback_s": round(fallback_s, 2),
-        "deadline_s": deadline_s,
-        "probes_wedged": plan.failures("backend.init"),
-        "stat_init_wedged": int(STAT_GET("backend.init_wedged")),
-        "capture_untouched": capture_sig() == sig_before,
-        "fallback_day_trained_keys": n_keys,
-        "fallback_day_s": round(day_s, 2),
-        "ok": bool(ok),
-    }
-    print(json.dumps(report, indent=None if args.json else 2))
-    return 0 if ok else 1
-
-
 def run_serve(args):
     """Serving-chain corruption smoke (``--serve``): a follower tailing a
     live publish stream must SKIP a corrupted delta with an alarm — same
@@ -1963,12 +1886,6 @@ def main(argv=None):
                     help="iid per-line data corruption probability; "
                          "switches to the quarantine/degrade soak "
                          "(single-rank only)")
-    ap.add_argument("--wedge-backend", action="store_true",
-                    help="simulate a wedged TPU runtime at the backend.init "
-                         "fault site: ensure_backend must fall back to CPU "
-                         "within the watchdog deadline, a mini supervised "
-                         "day must still train, and the last-good TPU "
-                         "capture must remain untouched")
     ap.add_argument("--native-sanitize", action="store_true",
                     help="memory-safety soak instead: rebuild the native "
                          "tier under ASan+UBSan and replay the native test "
@@ -2034,8 +1951,6 @@ def main(argv=None):
         return run_stream(args)
     if args.serve:
         return run_serve(args)
-    if args.wedge_backend:
-        return run_wedge_backend(args)
     if args.join_rank is not None:
         return run_join_rank(args)
     if args.kill_rank is not None:

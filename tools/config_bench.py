@@ -27,10 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from bench import apply_legacy_init_env  # noqa: E402
-from paddlebox_tpu.utils.backendguard import (  # noqa: E402
-    probe_backend_with_retries,
-)
+from paddlebox_tpu.utils.backendguard import bring_up  # noqa: E402
 
 
 def write_files(tmpdir, rng, n_rows, n_slots, key_space):
@@ -147,13 +144,7 @@ def main():
             batches = int(sys.argv[i + 1])
         if a == "--data-dir":
             data_dir = sys.argv[i + 1]
-    apply_legacy_init_env()
-    info, _ = probe_backend_with_retries()
-    import jax
-
-    if info is None:
-        jax.config.update("jax_platforms", "cpu")
-    platform = jax.devices()[0].platform
+    device = bring_up()  # whatever jax brings up; every line is stamped
 
     from paddlebox_tpu.models import (
         DCN,
@@ -232,7 +223,8 @@ def main():
                     name, fn, n_slots, batch, embedx, rows, n_batches,
                     key_space=1 << 20, data_files=data_files,
                 )
-                r["platform"] = platform
+                r["platform"] = device.platform
+                r["device_kind"] = device.device_kind
                 if data_dir:
                     r["real_format"] = True
                     r["rejected_lines"] = n_rej

@@ -6,9 +6,9 @@
    must surface at the next pass boundary, the carrier must stay owed,
    and a retried drain must land the carried values in the checkpoint
 3. error probes: zero-count slot line, unknown ws key
-4. round-6 triad: committed kernel plan routes pull/push (native on CPU),
-   persistent compile cache reports misses cold and hits warm in one
-   process, and a wedged backend init falls back to CPU within deadline
+4. committed kernel plan routes pull/push (native on CPU), and the
+   persistent compile cache (fixed .jax_cache/ in the checkout, or
+   $JAX_COMPILATION_CACHE_DIR) serves a repeated program from disk
 5. static gates: the full three-root pbox-lint scan must exit 0 with the
    empty baseline, and the native tier must replay clean under ASan+UBSan
    (quick set; skips green on images without g++)
@@ -148,39 +148,23 @@ assert _impl_for("pull", aligned, 64) == "native"
 assert _impl_for("push", aligned, 64, unique_rows=True) == "native"
 print(f"[5] kernel plan ok: source={plan.source}, CPU clamps to native")
 
-# --- 6. persistent compile cache: cold miss -> warm hit ----------------
+# --- 6. persistent compile cache: a repeated program is a disk hit -------
+# (placed by utils/compilecache's rule; the directory persists, so the
+# first compile is a miss only on a cold checkout — the gate is the hit)
 from paddlebox_tpu.utils import compilecache
 
-cc_dir = compilecache.enable(os.path.join(tmp, "compile_cache"))
-h0, m0 = compilecache.stats()["hits"], compilecache.stats()["misses"]
+cc_dir = compilecache.enable()
+assert cc_dir is not None, "compile_cache_dir=off in the environment?"
+h0 = compilecache.stats()["hits"]
 x = jnp.arange(512.0)
-float(jax.jit(lambda v: (v * 3.0 + 1.0).sum())(x))  # cold: compiles, populates
-s_cold = compilecache.stats()
-assert s_cold["misses"] > m0, s_cold
+float(jax.jit(lambda v: (v * 3.0 + 1.0).sum())(x))  # compiles or loads
 float(jax.jit(lambda v: (v * 3.0 + 1.0).sum())(x))  # same HLO, new fn: disk hit
 s_warm = compilecache.stats()
 assert s_warm["hits"] > h0, s_warm
 assert s_warm["entries"] > 0, s_warm
 compilecache.disable()
-print(f"[6] compile cache ok: {s_cold['misses'] - m0} cold miss(es) -> "
-      f"{s_warm['hits'] - h0} warm hit(s), {s_warm['entries']} entr(ies) in {cc_dir}")
-
-# --- 7. backend-init watchdog: wedge falls back to CPU -----------------
-import time as _time
-from paddlebox_tpu.utils import backendguard
-from paddlebox_tpu.utils.faultinject import fail_always, inject
-
-with inject(fail_always("backend.init")) as fplan:
-    t0 = _time.monotonic()
-    v = backendguard.ensure_backend(
-        timeout_s=2.0, retries=2, backoff_s=0.0, probe="always", sleep=lambda s: None
-    )
-    took = _time.monotonic() - t0
-assert v.verdict == "fallback_cpu" and v.wedged and v.platform == "cpu", v.as_dict()
-assert fplan.failures("backend.init") == 2, fplan.failures("backend.init")
-assert took <= 2.0 * 2 + 2.0, f"fallback blew the deadline: {took:.1f}s"
-float(jnp.arange(8.0).sum())  # backend still usable after the verdict
-print(f"[7] backend watchdog ok: wedged init -> {v.verdict} in {took:.2f}s")
+print(f"[6] compile cache ok: {s_warm['hits'] - h0} warm hit(s), "
+      f"{s_warm['entries']} entr(ies) in {cc_dir}")
 
 # --- 8. publish-while-serve soak (the serving tentpole, short) ----------
 # Trains a 3-pass day publishing base+deltas while a follower tails and
