@@ -34,10 +34,12 @@ is no CPU mode — without a TPU the script says what it found and exits 2
 before any phase. The phases are functions of their sizes so that
 tests/test_chip_smoke.py can run them tiny on the virtual CPU mesh.
 
-The last line of standard output is one JSON object, ``{"ok": true,
-"device": {"platform", "kind", "count"}, ...}`` with per-phase seconds,
-warm-up, compile-cache counters, compilations across each pass boundary and
-peak device memory. It claims no speed: ``"claim": null``.
+The last line of standard output is one JSON object with exactly these keys,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``, the
+device as JAX reports it. The line before it, ``chip_smoke: summary {json}``,
+holds what the run found: per-phase seconds, warm-up, compile-cache counters,
+compilations across each pass boundary and peak device memory. It claims no
+speed: ``"claim": null``. A failed run prints neither.
 """
 
 from __future__ import annotations
@@ -493,8 +495,18 @@ def compare_days(one: dict, mesh: dict, rows_one, rows_mesh) -> dict:
 
 def progress(name: str, record) -> None:
     """A failed run keeps what the finished phases found (never the last
-    line of a successful run: that is the result object)."""
+    line of a successful run: that is the bare result object)."""
     print(f"chip_smoke: {name} {json.dumps(record)}", flush=True)
+
+
+def result_line(device) -> str:
+    """The last line of a successful run: exactly these keys, the device as
+    JAX reports it. Everything else the run found goes in the summary line."""
+    return json.dumps({"ok": True, "device": {
+        "platform": device.platform,
+        "kind": device.device_kind,
+        "count": device.n_devices,
+    }})
 
 
 def main() -> int:
@@ -566,13 +578,8 @@ def main() -> int:
 
     first = one_rec["passes"][0]
     plan_doc = kernel_plan.get_plan()
-    result = {
-        "ok": True,
-        "device": {
-            "platform": device.platform,
-            "kind": device.device_kind,
-            "count": device.n_devices,
-        },
+    summary = {
+        **json.loads(result_line(device)),
         "jax": jax.__version__,
         "n_devices": device.n_devices,
         "total_s": round(time.perf_counter() - t_start, 3),
@@ -601,7 +608,8 @@ def main() -> int:
         "mesh_vs_one_chip": agreement,
         "claim": None,
     }
-    print(json.dumps(result))
+    progress("summary", summary)
+    print(result_line(device), flush=True)
     return 0
 
 

@@ -49,6 +49,17 @@ def test_main_refuses_to_start_without_a_tpu(capsys):
         json.loads(out[-1])
 
 
+def test_result_line_has_the_contract_keys_and_no_others():
+    from paddlebox_tpu.utils import backendguard
+
+    doc = json.loads(chip_smoke.result_line(backendguard.bring_up()))
+    assert doc["ok"] is True and set(doc) == {"ok", "device"}
+    first = jax.devices()[0]
+    assert doc["device"] == {
+        "platform": first.platform, "kind": first.device_kind,
+        "count": len(jax.devices())}
+
+
 def test_pallas_kernels_match_xla_ops_in_interpret_mode():
     rec = chip_smoke.check_pallas_kernels(rows=256, width=128, uniq=64, interpret=True)
     assert rec["pull_compiled"] and rec["write_compiled"]
