@@ -730,12 +730,11 @@ class BoxPSDataset:
             return
         self._boundary_prefetch = None
         fire("boundary.premerge")
-        t0 = time.perf_counter()
-        with record_event("boundary.premerge", "boundary"):
+        with record_event("boundary.premerge", "boundary") as span:
             merged = ws.premerge(
                 int(config.get_flag("boundary_merge_threads"))
             )
-        premerge_s = time.perf_counter() - t0
+        premerge_s = span.seconds
         STAT_SET("boundary.premerge_s", premerge_s)
         STAT_OBSERVE("boundary.premerge_s", premerge_s)
         if self._in_pass:
@@ -775,10 +774,9 @@ class BoxPSDataset:
         if carrier is not None and not carrier.flushed:
             carrier.wait_push()
         fire("boundary.stage_pull")
-        t0 = time.perf_counter()
-        with record_event("boundary.stage_pull", "boundary"):
+        with record_event("boundary.stage_pull", "boundary") as span:
             rows, epoch = table.prefetch_rows(need)
-        pull_s = time.perf_counter() - t0
+        pull_s = span.seconds
         STAT_SET("boundary.prefetch_pull_s", pull_s)
         STAT_OBSERVE("boundary.prefetch_pull_s", pull_s)
         with self._stage_lock:
@@ -1213,12 +1211,11 @@ class BoxPSDataset:
         kick = _WritebackKick(ws)
 
         def run_kick():
-            t0 = time.perf_counter()
             try:
-                with record_event("boundary.writeback_kick", "boundary"):
+                with record_event("boundary.writeback_kick", "boundary") as span:
                     arr = _trained_to_host(trained_table, table.layout)
                     ws.writeback(arr, cancel=kick.cancel)
-                kick.fut.set_result(time.perf_counter() - t0)
+                kick.fut.set_result(span.seconds)
             except BaseException as e:
                 kick.fut.set_exception(e)
 

@@ -1,12 +1,15 @@
 """paddlebox_tpu.obs — the unified telemetry plane.
 
-Four pieces, one registry:
+Five pieces, one registry:
 
 - ``histogram``      log2-bucketed distributions behind ``STAT_OBSERVE``
 - ``metrics_writer`` rank-tagged JSONL series of registry snapshots
 - ``trace_context``  (trace_id, span_id) propagation across PBTX frames
 - ``flight_recorder`` always-on ring of recent spans/stats/incidents,
                       dumped as ``incident-<ts>.json`` on fatal errors
+- ``program_scopes`` which ``jax.named_scope`` each instruction of a
+                      compiled program belongs to, kept per program for
+                      whoever reads a device trace of this process
 
 Exports are lazy (PEP 562): ``utils/monitor.py`` imports
 ``obs.histogram`` at import time, and ``metrics_writer``/

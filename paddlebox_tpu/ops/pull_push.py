@@ -68,6 +68,7 @@ def embedx_active_mask(
     return (show >= embedx_threshold)[:, None]
 
 
+@jax.named_scope("table_gather")  # with its gating: XLA may fuse the two
 def pull_sparse_rows(
     table: jnp.ndarray,  # [rows, width]
     rows: jnp.ndarray,  # int32 [U] (deduped, padded with the padding row)
@@ -91,6 +92,7 @@ def pull_sparse_rows(
     return jnp.concatenate([cvm_block, embedx], axis=1)
 
 
+@jax.named_scope("table_gather")
 def pull_sparse_rows_extended(
     table: jnp.ndarray,  # [rows, width]
     rows: jnp.ndarray,  # int32 [U]
@@ -137,7 +139,9 @@ def push_sparse_rows(
     grads; box_wrapper.cu PushCopy fills show/clk from the batch) with the
     optimizer semantics documented in table/optimizers.py.
     """
-    old = _gather_rows(table, rows)  # [U, width]
+    # the same gather as the pull's: XLA keeps one of the two
+    with jax.named_scope("table_gather"):
+        old = _gather_rows(table, rows)  # [U, width]
     new_rows = sparse_update_rows(
         old, grads, show_counts, clk_counts, layout, opt, lr_scale
     )
@@ -145,17 +149,20 @@ def push_sparse_rows(
     # the pallas per-row SET == scatter-add of deltas; without dedup the
     # plan clamps to native (unique_rows=False makes pallas ineligible)
     unique_rows = bool(config.get_flag("enable_pullpush_dedup_keys"))
-    if _impl_for("push", table, rows.shape[0], unique_rows=unique_rows) == "pallas":
-        from paddlebox_tpu.ops.pallas_kernels import write_rows_pallas
+    with jax.named_scope("table_scatter"):
+        if _impl_for("push", table, rows.shape[0], unique_rows=unique_rows) == "pallas":
+            from paddlebox_tpu.ops.pallas_kernels import write_rows_pallas
 
-        return write_rows_pallas(table, rows, new_rows)
-    # Scatter the *delta* with add-semantics: with host dedup rows are unique
-    # and this equals a set; without dedup (enable_pullpush_dedup_keys=0) a
-    # key occurring in several slots contributes each occurrence's update
-    # deterministically (sequential-push semantics) instead of last-write-wins.
-    return table.at[rows].add(new_rows - old)
+            return write_rows_pallas(table, rows, new_rows)
+        # Scatter the *delta* with add-semantics: with host dedup rows are
+        # unique and this equals a set; without dedup
+        # (enable_pullpush_dedup_keys=0) a key occurring in several slots
+        # contributes each occurrence's update deterministically
+        # (sequential-push semantics) instead of last-write-wins.
+        return table.at[rows].add(new_rows - old)
 
 
+@jax.named_scope("sparse_opt")
 def sparse_update_rows(
     old: jnp.ndarray,  # [U, width] current rows
     grads: jnp.ndarray,  # [U, pull_width] d(loss)/d(pull record)

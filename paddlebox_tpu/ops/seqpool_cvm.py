@@ -146,6 +146,7 @@ def _seqpool(
     return pooled
 
 
+@jax.named_scope("seqpool_cvm")
 def fused_seqpool_cvm(
     records: jnp.ndarray,  # [L, width] pulled per-key records (flat, padded)
     segments: jnp.ndarray,  # int32 [L] = slot * batch + ins; pads -> num_segments
@@ -200,6 +201,7 @@ def fused_seqpool_cvm_with_diff_thres(
     )
 
 
+@jax.named_scope("seqpool_cvm")
 def fused_seqpool_cvm_with_conv(
     records: jnp.ndarray,  # [L, width] CONV layout: [show, clk, conv, embedx...]
     segments: jnp.ndarray,
@@ -219,6 +221,7 @@ def fused_seqpool_cvm_with_conv(
     return jnp.transpose(out, (1, 0, 2))
 
 
+@jax.named_scope("seqpool_cvm")
 def fused_seqpool_cvm_with_pcoc(
     records: jnp.ndarray,  # [L, width] PCOC layout (cvm_offset 4 + pclk_num)
     segments: jnp.ndarray,

@@ -1085,14 +1085,13 @@ class PassWorkingSet:
         boundary feed stage ({src, keys, rows, epoch}); it is consumed
         only if its ``src`` is the very array this finalize merges
         (identity check), else silently dropped."""
-        t0 = time.perf_counter()
-        with self._lock, record_event("boundary.dedup", "boundary"):
+        with self._lock, record_event("boundary.dedup", "boundary") as span:
             all_keys = merge_unique_keys(
                 self._key_chunks,
                 int(config.get_flag("boundary_merge_threads")),
             )
             self._key_chunks = []
-        STAT_SET("boundary.dedup_s", time.perf_counter() - t0)
+        STAT_SET("boundary.dedup_s", span.seconds)
         if prefetch is not None and prefetch.get("src") is not all_keys:
             prefetch = None  # keys landed after the staged premerge: stale
         self.n_keys = len(all_keys)
@@ -1126,20 +1125,20 @@ class PassWorkingSet:
             return self._finalize_spliced(
                 table, carrier, all_keys, global_rows, ns, cap, prefetch
             )
-        t0 = time.perf_counter()
-        with record_event("boundary.pull", "boundary"):
+        with record_event("boundary.pull", "boundary") as span:
             rows = (
                 _rows_with_prefetch(table, all_keys, prefetch)
                 if len(all_keys)
                 else np.zeros((0, table.layout.width), dtype=np.float32)
             )
-        STAT_SET("boundary.pull_s", time.perf_counter() - t0)
+        STAT_SET("boundary.pull_s", span.seconds)
         if self._ici_adaptive() and len(all_keys):
             # the classic pull already materialized every row: its decayed
             # show column is the exact, free hotness source
             self._set_hot_rows(global_rows, rows[:, table.layout.SHOW])
-        dev = np.zeros((ns, cap, table.layout.width), dtype=np.float32)
-        dev.reshape(ns * cap, -1)[global_rows] = rows
+        with record_event("boundary.layout", "boundary"):
+            dev = np.zeros((ns, cap, table.layout.width), dtype=np.float32)
+            dev.reshape(ns * cap, -1)[global_rows] = rows
         return dev
 
     @staticmethod
@@ -1192,15 +1191,14 @@ class PassWorkingSet:
         pull = {"rows": None, "err": None, "secs": 0.0}
 
         def _pull_new():
-            t0 = time.perf_counter()
             try:
-                with record_event("boundary.pull", "boundary"):
+                with record_event("boundary.pull", "boundary") as span:
                     pull["rows"] = _rows_with_prefetch(
                         table, new_keys, prefetch
                     )
             except BaseException as e:  # joined + re-raised below
                 pull["err"] = e
-            pull["secs"] = time.perf_counter() - t0
+            pull["secs"] = span.seconds
 
         puller = None
         if len(new_keys):
@@ -1215,14 +1213,13 @@ class PassWorkingSet:
         # unsharded on the default device — an HBM spike of full-table
         # size at exactly the boundary the carrier exists to slim down.
         # On a single device this degenerates to a plain allocation.
-        t0 = time.perf_counter()
-        with record_event("boundary.splice", "boundary"):
+        with record_event("boundary.splice", "boundary") as span:
             dev = _sharded_zeros(ns * cap, W, carrier.dev_flat.sharding)
             if common.any():
                 dev = dev.at[jnp.asarray(global_rows[common])].set(
                     carrier.rows_for(common_old)
                 )
-        STAT_SET("boundary.splice_s", time.perf_counter() - t0)
+        STAT_SET("boundary.splice_s", span.seconds)
         if puller is not None:
             puller.join()
             if pull["err"] is not None:

@@ -37,6 +37,7 @@ import numpy as np
 from paddlebox_tpu import config
 from paddlebox_tpu.data.device_pack import _round_bucket
 from paddlebox_tpu.train.train_step import TrainStepConfig, make_train_step
+from paddlebox_tpu.utils.trace import record_event
 
 config.define_flag(
     "enable_resident_feed",
@@ -79,7 +80,8 @@ class ResidentPass:
         self.bucket = bucket or config.get_flag("batch_bucket_rounding")
         self.n_table_rows = ws.n_mesh_shards * ws.capacity
         self.pad_row = self.n_table_rows - 1
-        rows = store.resolve_rows(ws)
+        with record_event("resident.resolve_rows", "pass"):
+            rows = store.resolve_rows(ws)
         if len(store.u64_values) >= (1 << 31):  # int32 src indexing
             raise ValueError("pass too large for resident feed (>=2^31 keys)")
         self._host_rows = rows
@@ -109,6 +111,7 @@ class ResidentPass:
         else:
             L_max, N_max = len(rows), len(store)
 
+        @record_event("resident.upload", "pass")
         def place(a):
             if self.per_device:
                 from paddlebox_tpu.parallel.mesh import put_per_device_copies
@@ -169,6 +172,7 @@ class ResidentPass:
         self._uniq_cache: Dict[bytes, int] = {}
         self._mesh_cache: Dict = {}  # (device, idx bytes) -> (L, bucket max)
 
+    @record_event("resident.ensure", "pass")
     def ensure(self, batch_indices) -> None:
         """Freeze/grow L_pad and U_pad to cover every batch in the partition
         (exact per-batch max key and unique-row counts; results cached per
@@ -212,6 +216,7 @@ class ResidentPass:
 
 
 
+@jax.named_scope("offsets")
 def _batch_offsets(arrs: Dict[str, jnp.ndarray], idx: jnp.ndarray) -> jnp.ndarray:
     """[B, S+1] absolute flat-stream offsets for a batch, from whichever
     resident representation was uploaded (full matrix, or base+uint8
@@ -224,6 +229,7 @@ def _batch_offsets(arrs: Dict[str, jnp.ndarray], idx: jnp.ndarray) -> jnp.ndarra
     return arrs["base"][idx][:, None] + jnp.concatenate([zero, cum], axis=1)
 
 
+@jax.named_scope("ragged_rows")
 def _ragged_rows(
     rows_res: jnp.ndarray,
     off_b: jnp.ndarray,  # [B, S+1] this batch's absolute offsets
@@ -254,6 +260,7 @@ def _ragged_rows(
     return rows_flat, segments, valid
 
 
+@jax.named_scope("build_batch")
 def build_device_batch(
     rp: ResidentPass, cfg: TrainStepConfig, idx: jnp.ndarray
 ) -> Dict[str, jnp.ndarray]:
@@ -273,24 +280,27 @@ def build_device_batch(
     )
     # cross-slot dedup on device: sort rows, first-occurrence scan
     INF = jnp.int32(rp.n_table_rows)
-    sort_keys = jnp.where(valid, rows_flat, INF)
-    sorted_rows, perm = jax.lax.sort_key_val(
-        sort_keys, jnp.arange(L_pad, dtype=jnp.int32)
-    )
-    real = sorted_rows < INF
-    first = (
-        jnp.concatenate(
-            [jnp.ones((1,), jnp.bool_), sorted_rows[1:] != sorted_rows[:-1]]
+    with jax.named_scope("dedup_sort"):
+        sort_keys = jnp.where(valid, rows_flat, INF)
+        sorted_rows, perm = jax.lax.sort_key_val(
+            sort_keys, jnp.arange(L_pad, dtype=jnp.int32)
         )
-        & real
-    )
-    segid = jnp.minimum(jnp.cumsum(first.astype(jnp.int32)) - 1, U_pad - 1)
-    segid = jnp.where(real, segid, U_pad - 1)
-    uniq = jax.ops.segment_max(
-        jnp.where(real, sorted_rows, -1), segid, num_segments=U_pad
-    )
-    uniq_rows = jnp.where(uniq >= 0, uniq, rp.pad_row).astype(jnp.int32)
-    inverse = jnp.zeros((L_pad,), jnp.int32).at[perm].set(segid)
+    with jax.named_scope("dedup_scan"):
+        real = sorted_rows < INF
+        first = (
+            jnp.concatenate(
+                [jnp.ones((1,), jnp.bool_), sorted_rows[1:] != sorted_rows[:-1]]
+            )
+            & real
+        )
+        segid = jnp.minimum(jnp.cumsum(first.astype(jnp.int32)) - 1, U_pad - 1)
+        segid = jnp.where(real, segid, U_pad - 1)
+        uniq = jax.ops.segment_max(
+            jnp.where(real, sorted_rows, -1), segid, num_segments=U_pad
+        )
+        uniq_rows = jnp.where(uniq >= 0, uniq, rp.pad_row).astype(jnp.int32)
+    with jax.named_scope("inverse_scatter"):
+        inverse = jnp.zeros((L_pad,), jnp.int32).at[perm].set(segid)
     batch = {
         "uniq_rows": uniq_rows,
         "inverse": inverse,
@@ -492,6 +502,9 @@ def make_resident_pv_mesh_superstep(
         # multi-host arrays must be jit ARGUMENTS, not closure constants
         return jitted(state, pos_block, rp_arrays, feed.idx, feed.ro, feed.w)
 
+    call.lower = lambda state, pos_block: jitted.lower(
+        state, pos_block, rp_arrays, feed.idx, feed.ro, feed.w
+    )
     return call
 
 
@@ -582,6 +595,7 @@ def ensure_sharded(rp: ResidentPass, batch_indices, n_devices: int) -> None:
     rp.K_pad = max(rp.K_pad, K)
 
 
+@jax.named_scope("build_batch")
 def build_mesh_device_batch(
     rp_arrays: Dict[str, jnp.ndarray],
     cfg: TrainStepConfig,
@@ -731,6 +745,7 @@ def make_resident_mesh_superstep(
         # the jit as ARGUMENTS, not closure constants
         return jitted(state, idx_block, rp_arrays)
 
+    call.lower = lambda state, idx_block: jitted.lower(state, idx_block, rp_arrays)
     return call
 
 

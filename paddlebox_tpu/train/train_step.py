@@ -159,24 +159,27 @@ def local_forward_backward(
             extra.append(rank_offset)
         if cfg.use_expand:
             # sum-pool expand per (slot, ins): [B, S, E] (pad segments drop)
-            pooled = jax.ops.segment_sum(
-                expand_flat,
-                segments,
-                num_segments=cfg.num_slots * cfg.batch_size,
-            ).reshape(cfg.num_slots, cfg.batch_size, E)
-            extra.append(jnp.transpose(pooled, (1, 0, 2)))
-        logits = model_apply(p, slot_feats, dense, *extra)
-        loss_vec = optax.sigmoid_binary_cross_entropy(logits, labels)
-        if ins_weight is not None:
-            denom = (
-                loss_denom
-                if loss_denom is not None
-                else jnp.maximum(jnp.sum(ins_weight), 1.0)
-            )
-            loss = jnp.sum(loss_vec * ins_weight) / denom
-        else:
-            loss = jnp.mean(loss_vec)
-        return loss, jax.nn.sigmoid(logits)
+            with jax.named_scope("seqpool_cvm"):
+                pooled = jax.ops.segment_sum(
+                    expand_flat,
+                    segments,
+                    num_segments=cfg.num_slots * cfg.batch_size,
+                ).reshape(cfg.num_slots, cfg.batch_size, E)
+                extra.append(jnp.transpose(pooled, (1, 0, 2)))
+        with jax.named_scope("model"):
+            logits = model_apply(p, slot_feats, dense, *extra)
+        with jax.named_scope("loss"):
+            loss_vec = optax.sigmoid_binary_cross_entropy(logits, labels)
+            if ins_weight is not None:
+                denom = (
+                    loss_denom
+                    if loss_denom is not None
+                    else jnp.maximum(jnp.sum(ins_weight), 1.0)
+                )
+                loss = jnp.sum(loss_vec * ins_weight) / denom
+            else:
+                loss = jnp.mean(loss_vec)
+            return loss, jax.nn.sigmoid(logits)
 
     if eval_mode:
         loss, preds = loss_fn(params, flat)
@@ -187,6 +190,7 @@ def local_forward_backward(
     return loss, preds, gparams, gflat
 
 
+@jax.named_scope("merge")
 def scale_and_merge_grads(
     cfg: TrainStepConfig,
     gflat: jnp.ndarray,  # [L, PW]
@@ -227,6 +231,7 @@ def scale_and_merge_grads(
     return summed[:, :-2], summed[:, -2], summed[:, -1]
 
 
+@jax.named_scope("loss")
 def adjusted_loss_weight(
     cfg: TrainStepConfig,
     flat: jnp.ndarray,  # [L, PW(+E)] pulled records (col 0 = show)
@@ -294,16 +299,18 @@ def make_train_step(
         rank_offset = batch.get("rank_offset")
         U = uniq_rows.shape[0]
 
-        if cfg.use_expand:
-            rec_u, exp_u = pull_sparse_rows_extended(
-                state.table, uniq_rows, lay, opt.embedx_threshold, cfg.pull_scale
-            )
-            pulled_u = jnp.concatenate([rec_u, exp_u], axis=1)  # [U, PW+E]
-        else:
-            pulled_u = pull_sparse_rows(
-                state.table, uniq_rows, lay, opt.embedx_threshold, cfg.pull_scale
-            )  # [U, PW]
-        flat = jnp.take(pulled_u, inverse, axis=0)  # [L, PW(+E)]
+        with jax.named_scope("pull"):
+            if cfg.use_expand:
+                rec_u, exp_u = pull_sparse_rows_extended(
+                    state.table, uniq_rows, lay, opt.embedx_threshold, cfg.pull_scale
+                )
+                pulled_u = jnp.concatenate([rec_u, exp_u], axis=1)  # [U, PW+E]
+            else:
+                pulled_u = pull_sparse_rows(
+                    state.table, uniq_rows, lay, opt.embedx_threshold, cfg.pull_scale
+                )  # [U, PW]
+            with jax.named_scope("expand"):
+                flat = jnp.take(pulled_u, inverse, axis=0)  # [L, PW(+E)]
 
         loss_w, loss_denom = ins_weight, None
         if cfg.adjust_ins_weight is not None and not eval_mode:
@@ -317,17 +324,18 @@ def make_train_step(
         )
         finite = None
         if cfg.check_nan and not eval_mode:
-            gsum = loss + jnp.sum(gflat)
-            for leaf in jax.tree.leaves(gparams):
-                gsum = gsum + jnp.sum(leaf)
-            finite = jnp.isfinite(gsum)
-            if cfg.axis_name is not None:
-                # all devices share the table: one bad device skips everywhere
-                finite = (
-                    jax.lax.psum((~finite).astype(jnp.int32), cfg.axis_name) == 0
-                )
-            # where, not multiply: NaN * 0 is still NaN
-            gflat = jnp.where(finite, gflat, 0.0)
+            with jax.named_scope("nan_guard"):
+                gsum = loss + jnp.sum(gflat)
+                for leaf in jax.tree.leaves(gparams):
+                    gsum = gsum + jnp.sum(leaf)
+                finite = jnp.isfinite(gsum)
+                if cfg.axis_name is not None:
+                    # all devices share the table: one bad device skips everywhere
+                    finite = (
+                        jax.lax.psum((~finite).astype(jnp.int32), cfg.axis_name) == 0
+                    )
+                # where, not multiply: NaN * 0 is still NaN
+                gflat = jnp.where(finite, gflat, 0.0)
         if eval_mode:
             new_table = state.table
             new_params, new_opt_state = state.params, state.opt_state
@@ -338,21 +346,24 @@ def make_train_step(
             # resolution (a key deduped across slots gets each slot's
             # scaled contribution), then grads merge per unique row —
             # PushMergeCopy parity.
-            guniq, show_counts, clk_counts = scale_and_merge_grads(
-                cfg, gflat, segments, inverse, labels, num_segments=U,
-                ins_weight=ins_weight,
-            )
+            with jax.named_scope("push"):
+                guniq, show_counts, clk_counts = scale_and_merge_grads(
+                    cfg, gflat, segments, inverse, labels, num_segments=U,
+                    ins_weight=ins_weight,
+                )
             if finite is not None:
                 # a zeroed push is an exact identity on the table (adagrad
                 # g2 += 0, step 0, show/clk += 0) — the skipped batch never
                 # happened as far as the sparse model is concerned. where,
                 # not multiply: a NaN label rides into clk via segment_sum
-                show_counts = jnp.where(finite, show_counts, 0.0)
-                clk_counts = jnp.where(finite, clk_counts, 0.0)
+                with jax.named_scope("nan_guard"):
+                    show_counts = jnp.where(finite, show_counts, 0.0)
+                    clk_counts = jnp.where(finite, clk_counts, 0.0)
 
-            new_table = push_sparse_rows(
-                state.table, uniq_rows, guniq, show_counts, clk_counts, lay, opt
-            )
+            with jax.named_scope("push"):
+                new_table = push_sparse_rows(
+                    state.table, uniq_rows, guniq, show_counts, clk_counts, lay, opt
+                )
 
             # --- dense sync: psum over the DP axis (K-step/NCCL allreduce
             # parity)
@@ -364,26 +375,29 @@ def make_train_step(
                 # back
                 new_params, new_opt_state = state.params, state.opt_state
             else:
-                updates, new_opt_state = dense_opt.update(
-                    gparams, state.opt_state, state.params
-                )
-                new_params = optax.apply_updates(state.params, updates)
+                with jax.named_scope("dense_opt"):
+                    updates, new_opt_state = dense_opt.update(
+                        gparams, state.opt_state, state.params
+                    )
+                    new_params = optax.apply_updates(state.params, updates)
             if finite is not None:
                 # skipped batch: dense params + optimizer moments stay put
-                new_params = jax.tree.map(
-                    lambda new, old: jnp.where(finite, new, old),
-                    new_params, state.params,
-                )
-                new_opt_state = jax.tree.map(
-                    lambda new, old: jnp.where(finite, new, old),
-                    new_opt_state, state.opt_state,
-                )
+                with jax.named_scope("nan_guard"):
+                    new_params = jax.tree.map(
+                        lambda new, old: jnp.where(finite, new, old),
+                        new_params, state.params,
+                    )
+                    new_opt_state = jax.tree.map(
+                        lambda new, old: jnp.where(finite, new, old),
+                        new_opt_state, state.opt_state,
+                    )
 
-        auc_mask = None if ins_weight is None else (ins_weight > 0)
-        if finite is not None:
-            fin_mask = jnp.broadcast_to(finite, labels.shape)
-            auc_mask = fin_mask if auc_mask is None else (auc_mask & fin_mask)
-        new_auc = auc_update(state.auc, preds, labels, auc_mask)
+        with jax.named_scope("auc"):
+            auc_mask = None if ins_weight is None else (ins_weight > 0)
+            if finite is not None:
+                fin_mask = jnp.broadcast_to(finite, labels.shape)
+                auc_mask = fin_mask if auc_mask is None else (auc_mask & fin_mask)
+            new_auc = auc_update(state.auc, preds, labels, auc_mask)
         # a skipped batch never happened: the step counter (which paces
         # kstep param syncs and dump sampling) must not advance either
         step_inc = (

@@ -32,6 +32,7 @@ from paddlebox_tpu.data.device_pack import BatchPacker, pack_batch, pack_batch_s
 from paddlebox_tpu.data.pipeline import prefetch
 from paddlebox_tpu.metrics.auc import auc_compute, auc_init
 from paddlebox_tpu.metrics.registry import MetricRegistry
+from paddlebox_tpu.obs.program_scopes import REGISTRY as PROGRAMS
 from paddlebox_tpu.parallel.mesh import (
     MeshPlan,
     local_slice,
@@ -67,6 +68,14 @@ config.define_flag(
     "round-trip latency behind compute, shallow enough that transfers and "
     "executions don't pile up on the transport",
 )
+
+
+def _aval_of(x) -> jax.ShapeDtypeStruct:
+    """Shape, dtype and (where the array is committed to one) sharding: what
+    jit keys its executable on."""
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding if x.committed else None
+    )
 
 
 class CTRTrainer:
@@ -232,7 +241,8 @@ class CTRTrainer:
         if self.params is None:
             self.init_params()
         if self.plan is None:
-            flat = jnp.asarray(dev_table.reshape(-1, dev_table.shape[-1]))
+            with PROFILER.record_event("state.upload", "pass"):
+                flat = jnp.asarray(dev_table.reshape(-1, dev_table.shape[-1]))
             # device COPIES of params/opt_state: the step donates its state,
             # so handing self.params's own buffers over would delete them —
             # a mid-pass save_dense or an aborted pass would then read dead
@@ -678,6 +688,7 @@ class CTRTrainer:
         c = None  # the local ref would keep the old arrays alive too
         self._resident_cache = None
         self._sstep_cache = {}
+        self._sstep_recorded = set()
         self._pv_feed_cache = None  # old pass's pv stacks must release too
         rp = ResidentPass(
             dataset.store,
@@ -747,6 +758,7 @@ class CTRTrainer:
         key = (id(rp), id(pv_feed), eval_mode, rp.L_pad, rp.U_pad, rp.K_pad)
         ss = cache.get(key)
         if ss is None:
+            PROGRAMS.watch("superstep")  # its build seconds, by jax's own events
             if pv_feed is not None:
                 from paddlebox_tpu.train.resident_step import (
                     make_resident_pv_mesh_superstep,
@@ -779,6 +791,17 @@ class CTRTrainer:
                 )
             cache[key] = ss
         return ss
+
+    def _record_superstep(self, sstep, avals, eval_mode: bool) -> None:
+        """Once a superstep program has run: its instruction -> scope map
+        into the process's program registry (obs/program_scopes.py).
+        ``lower`` with the call's own avals hands back the executable the
+        call just built — nothing compiles a second time."""
+        shape = "x".join(str(d) for d in avals[1].shape)
+        name = f"superstep/{'eval' if eval_mode else 'train'}/{shape}"
+        with PROFILER.record_event("superstep_scope_map", "pass"):
+            text = sstep.lower(*avals).compile().as_text()
+            PROGRAMS.record(name, "superstep", text)
 
     def _resident_stepper(
         self, dataset, n_batches, holder, eval_mode, profile, t_feed, t_disp, t_dev,
@@ -868,10 +891,17 @@ class CTRTrainer:
                 else:
                     idx_dev = jnp.asarray(np.stack(chunk))
                 _fault_fire("step.device")  # chaos seam (see classic stepper)
+                avals = None
+                if (id(sstep), idx_dev.shape) not in self._sstep_recorded:
+                    # the state is donated: keep its shapes, not its arrays
+                    avals = jax.tree.map(_aval_of, (holder["state"], idx_dev))
                 t_disp.start()
                 with PROFILER.record_event("superstep_dispatch", "pass"):
                     holder["state"], mstack = sstep(holder["state"], idx_dev)
                 t_disp.pause()
+                if avals is not None:
+                    self._sstep_recorded.add((id(sstep), idx_dev.shape))
+                    self._record_superstep(sstep, avals, eval_mode)
                 if profile:
                     t_dev.start()
                     with PROFILER.record_event("device_superstep", "device"):
@@ -881,21 +911,24 @@ class CTRTrainer:
                     inflight.append(mstack["loss"])
                     if len(inflight) > 1:  # double-buffer supersteps
                         t_dev.start()
-                        jax.block_until_ready(inflight.popleft())
+                        with PROFILER.record_event("superstep_wait", "device"):
+                            jax.block_until_ready(inflight.popleft())
                         t_dev.pause()
                 chunk_ids = ids_fut.result() if ids_fut is not None else None
-                for j, idx in enumerate(chunk):
-                    m = {k: v[j] for k, v in mstack.items()}
-                    aux = {}
-                    if has_meta:
-                        aux["cmatch"] = store.cmatch[idx]
-                        aux["rank"] = store.rank[idx]
-                    if pv_w is not None:
-                        aux["ins_weight"] = pv_w[c0 + j]
-                    if chunk_ids is not None:
-                        aux["ins_ids"] = chunk_ids[j]
-                    yield i, m, aux
-                    i += 1
+                # the consumer's work on each batch runs inside this span
+                with PROFILER.record_event("superstep_consume", "pass"):
+                    for j, idx in enumerate(chunk):
+                        m = {k: v[j] for k, v in mstack.items()}
+                        aux = {}
+                        if has_meta:
+                            aux["cmatch"] = store.cmatch[idx]
+                            aux["rank"] = store.rank[idx]
+                        if pv_w is not None:
+                            aux["ins_weight"] = pv_w[c0 + j]
+                        if chunk_ids is not None:
+                            aux["ins_ids"] = chunk_ids[j]
+                        yield i, m, aux
+                        i += 1
         finally:
             if ids_ex is not None:
                 ids_ex.shutdown(wait=False)
@@ -1028,7 +1061,15 @@ class CTRTrainer:
         self._schema = dataset.schema
         # the ws OBJECT is the cache key (an id() could be recycled across
         # passes and silently serve the previous pass's state)
-        state = self._make_state(dataset.device_table, ws_key=dataset.ws)
+        with PROFILER.record_event("train_pass.open", "pass"):
+            state = self._make_state(dataset.device_table, ws_key=dataset.ws)
+            # AUC buckets accumulate in device state across train_pass calls
+            # within one pass (warmup epochs, join/update phases, sequential
+            # slot-shuffle evals); snapshot them so THIS call's metrics are a
+            # bucket delta, not the running total. The read waits for the
+            # previous call's last program
+            auc_pos0 = self._host_np(state.auc.pos).copy()
+            auc_neg0 = self._host_np(state.auc.neg).copy()
         losses = []
         # join phase serves pv-merged batches with rank_offset + ghost
         # weights; update phase serves flat batches (EnablePvMerge branch,
@@ -1051,12 +1092,6 @@ class CTRTrainer:
         else:
             iterator = self._slow_feed_iter(dataset, n_batches)
             step_fn = self._eval_step() if eval_mode else self._step
-        # AUC buckets accumulate in device state across train_pass calls
-        # within one pass (warmup epochs, join/update phases, sequential
-        # slot-shuffle evals); snapshot them so THIS call's metrics are a
-        # bucket delta, not the running total
-        auc_pos0 = self._host_np(state.auc.pos).copy()
-        auc_neg0 = self._host_np(state.auc.neg).copy()
         if self.plan is not None and jax.process_count() > 1:
             if dataset.store is None:
                 raise RuntimeError(
@@ -1163,37 +1198,38 @@ class CTRTrainer:
                 dump_param(self.dump_pool, name, np.asarray(leaf))
         from paddlebox_tpu.metrics.auc import AucState
 
-        cum = AucState(
-            pos=self._host_np(state.auc.pos), neg=self._host_np(state.auc.neg)
-        )
-        delta = AucState(pos=cum.pos - auc_pos0, neg=cum.neg - auc_neg0)
-        out = auc_compute(delta)
-        cum_out = auc_compute(cum)
-        out["auc_cumulative"] = cum_out["auc"]
-        # saturation is a property of the CUMULATIVE buckets — the delta is
-        # small by construction and would always read unsaturated
-        out["saturated"] = cum_out["saturated"]
-        if losses and skip_flags:
-            lv = jnp.stack(losses)
-            bad = jnp.stack(skip_flags) > 0
-            kept = jnp.maximum(jnp.sum(~bad), 1)
-            out["loss"] = float(jnp.sum(jnp.where(bad, 0.0, lv)) / kept)
-            out["nan_batches"] = float(jnp.sum(bad))
-        else:
-            out["loss"] = float(jnp.mean(jnp.stack(losses))) if losses else float("nan")
-            out["nan_batches"] = 0.0
-        out["batches"] = float(len(losses))
-        if not eval_mode:
-            # monitor parity: training-lifecycle counters (an eval pass
-            # trains nothing, so it bumps nothing). ins_num counts REAL
-            # instances (AUC-masked: no ghosts, no skipped batches);
-            # samples_processed is device throughput incl. wraparound pads.
-            from paddlebox_tpu.utils.monitor import STAT_ADD
+        with PROFILER.record_event("train_pass.tail", "pass"):
+            cum = AucState(
+                pos=self._host_np(state.auc.pos), neg=self._host_np(state.auc.neg)
+            )
+            delta = AucState(pos=cum.pos - auc_pos0, neg=cum.neg - auc_neg0)
+            out = auc_compute(delta)
+            cum_out = auc_compute(cum)
+            out["auc_cumulative"] = cum_out["auc"]
+            # saturation is a property of the CUMULATIVE buckets — the delta is
+            # small by construction and would always read unsaturated
+            out["saturated"] = cum_out["saturated"]
+            if losses and skip_flags:
+                lv = jnp.stack(losses)
+                bad = jnp.stack(skip_flags) > 0
+                kept = jnp.maximum(jnp.sum(~bad), 1)
+                out["loss"] = float(jnp.sum(jnp.where(bad, 0.0, lv)) / kept)
+                out["nan_batches"] = float(jnp.sum(bad))
+            else:
+                out["loss"] = float(jnp.mean(jnp.stack(losses))) if losses else float("nan")
+                out["nan_batches"] = 0.0
+            out["batches"] = float(len(losses))
+            if not eval_mode:
+                # monitor parity: training-lifecycle counters (an eval pass
+                # trains nothing, so it bumps nothing). ins_num counts REAL
+                # instances (AUC-masked: no ghosts, no skipped batches);
+                # samples_processed is device throughput incl. wraparound pads.
+                from paddlebox_tpu.utils.monitor import STAT_ADD
 
-            STAT_ADD("train_batches", len(losses))
-            STAT_ADD("train_samples_processed", len(losses) * self.cfg.batch_size)
-            STAT_ADD("train_ins_num", out.get("ins_num", 0))
-            STAT_ADD("nan_skipped_batches", out["nan_batches"])
+                STAT_ADD("train_batches", len(losses))
+                STAT_ADD("train_samples_processed", len(losses) * self.cfg.batch_size)
+                STAT_ADD("train_ins_num", out.get("ins_num", 0))
+                STAT_ADD("nan_skipped_batches", out["nan_batches"])
         if profile:
             out["profile"] = {
                 "feed_wait_s": round(t_feed.elapsed_sec(), 4),

@@ -21,7 +21,15 @@ Telemetry-plane upgrades (docs/OBSERVABILITY.md):
   incident bundle can show the last N spans before a death;
 - spans recorded inside an ``obs.trace_span`` context carry
   trace_id/span_id args for cross-rank correlation
-  (``tools/obs_report.py --merge-traces``).
+  (``tools/obs_report.py --merge-traces``);
+- every span is also a ``jax.profiler.TraceAnnotation`` named
+  ``pbx:<name>``: nothing while no profiler session runs, a host event on
+  the device trace's own clock while one does, so a device idle gap can
+  be read under the span that covered it;
+- a per-name count and summed seconds are kept always (``totals()``), and
+  ``record_event`` yields an object whose ``seconds`` is the span's length
+  once it has closed, so a stage that publishes its seconds as a stat
+  times itself once.
 """
 
 from __future__ import annotations
@@ -32,6 +40,8 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from paddlebox_tpu import config
 from paddlebox_tpu.obs.flight_recorder import FLIGHT_RECORDER
@@ -50,6 +60,15 @@ def _trace_args() -> Optional[Dict[str, str]]:
     return ctx.as_args() if ctx is not None else None
 
 
+class Span:
+    """What ``record_event`` yields; ``seconds`` is set when the span closes."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self):
+        self.seconds = 0.0
+
+
 class Profiler:
     def __init__(self, max_events: Optional[int] = None):
         self._lock = threading.Lock()
@@ -60,6 +79,7 @@ class Profiler:
         self._thread_meta: List[Dict] = []  # synchronized-by: _lock (held by *_locked callers)
         self._tids: Dict[int, int] = {}  # synchronized-by: _lock (held by *_locked callers)
         self._dropped = 0  # synchronized-by: _lock (held by *_locked callers)
+        self._totals: Dict[str, List] = {}  # guarded-by: _lock; name -> [count, seconds]
         self._pid = 0  # guarded-by: _lock
         self._process_name = "rank0"  # guarded-by: _lock
         self.enabled = False
@@ -114,12 +134,21 @@ class Profiler:
     @contextmanager
     def record_event(self, name: str, category: str = "host"):
         """Scoped annotation (platform::RecordEvent parity). Always feeds
-        the flight recorder; appends to the trace only when enabled."""
+        the flight recorder, the per-name totals and a running jax profiler
+        session (as ``pbx:<name>``); appends to the chrome-trace ring only
+        when enabled. Yields a ``Span``."""
+        span = Span()
         t0 = time.perf_counter_ns()
         try:
-            yield
+            with TraceAnnotation("pbx:" + name):
+                yield span
         finally:
             t1 = time.perf_counter_ns()
+            span.seconds = (t1 - t0) * 1e-9
+            with self._lock:
+                tot = self._totals.setdefault(name, [0, 0.0])
+                tot[0] += 1
+                tot[1] += span.seconds
             args = _trace_args()
             FLIGHT_RECORDER.note_span(
                 name, category, t0 / 1e3, (t1 - t0) / 1e3, args)
@@ -168,6 +197,12 @@ class Profiler:
             event["tid"] = self._tid_locked()
             self._append_locked(event)
 
+    def totals(self) -> Dict[str, Dict[str, float]]:
+        """{span name: {"count", "seconds"}} over every span closed since
+        the last ``reset``, tracing enabled or not."""
+        with self._lock:
+            return {n: {"count": c, "seconds": s} for n, (c, s) in self._totals.items()}
+
     # -- export -----------------------------------------------------------
     def export_chrome_trace(self, path: str) -> int:
         """Write chrome://tracing JSON (timeline.py parity). Returns the
@@ -205,6 +240,7 @@ class Profiler:
             self._thread_meta.clear()
             self._tids.clear()
             self._dropped = 0
+            self._totals.clear()
 
 
 # process-global profiler, like the reference's g_state
@@ -213,19 +249,3 @@ PROFILER = Profiler()
 
 def record_event(name: str, category: str = "host"):
     return PROFILER.record_event(name, category)
-
-
-@contextmanager
-def device_trace(log_dir: Optional[str] = None):
-    """Wrap a region with jax.profiler device tracing when available
-    (nvprof-hook analog, platform/cuda_profiler.h)."""
-    import jax
-
-    if log_dir is None:
-        yield
-        return
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -1,0 +1,198 @@
+"""The step named from inside: ``jax.named_scope`` in the compiled
+superstep, the process's instruction -> scope registry
+(obs/program_scopes.py), and the trainer's and boundary's spans as
+``pbx:`` annotations and always-on totals (utils/trace.py)."""
+
+from __future__ import annotations
+
+import gc
+import glob
+import os
+import re
+
+import numpy as np
+import optax
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from paddlebox_tpu.data import BoxPSDataset, SlotInfo, SlotSchema  # noqa: E402
+from paddlebox_tpu.models import DeepFM  # noqa: E402
+from paddlebox_tpu.obs.program_scopes import (  # noqa: E402
+    REGISTRY,
+    ProgramRegistry,
+    scope_map,
+    scope_of,
+)
+from paddlebox_tpu.table import (  # noqa: E402
+    HostSparseTable,
+    SparseOptimizerConfig,
+    ValueLayout,
+)
+from paddlebox_tpu.train import CTRTrainer, TrainStepConfig  # noqa: E402
+from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
+from paddlebox_tpu.utils.trace import PROFILER  # noqa: E402
+
+S, D, B, N = 6, 8, 64, 1024  # the benchmark's toy: 6 slots x embedx 8
+PROGRAM = f"superstep/train/8x{B}"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+
+STEP_SCOPES = {
+    "build_batch/offsets", "build_batch/ragged_rows", "build_batch/dedup_sort",
+    "build_batch/dedup_scan", "build_batch/inverse_scatter",
+    "pull/expand", "seqpool_cvm", "model", "loss", "nan_guard",
+    "push/merge", "push/sparse_opt", "push/table_scatter", "dense_opt", "auc",
+}
+
+
+def _dataset(tmp_path):
+    rng = np.random.default_rng(26)
+    path = tmp_path / "part-000.txt"
+    with open(path, "w") as f:
+        for _ in range(N):
+            keys = rng.integers(1, 5000, S)
+            f.write(f"1 {float(rng.integers(0, 2))} " + " ".join(f"1 {k}" for k in keys) + "\n")
+    schema = SlotSchema(
+        [SlotInfo("label", type="float", dense=True, dim=1)]
+        + [SlotInfo(f"s{i}") for i in range(S)],
+        label_slot="label",
+    )
+    layout = ValueLayout(embedx_dim=D)
+    opt = SparseOptimizerConfig(embedx_threshold=0.0)
+    ds = BoxPSDataset(
+        schema, HostSparseTable(layout, opt, n_shards=2, seed=0),
+        batch_size=B, shuffle_mode="none",
+    )
+    ds.set_filelist([str(path)])
+    ds.load_into_memory()
+    return ds, layout, opt
+
+
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """One pass opened and 16 batches (two supersteps) trained, with every
+    backend compile of the process listened to from before the build."""
+    from jax._src import monitoring
+
+    compiles, texts = [], {}
+
+    def on_event(event, duration, **kw):
+        if event == COMPILE:
+            compiles.append(kw.get("fun_name"))
+
+    def record(name, fun_name, hlo_text):  # keep the text the map was read from
+        texts[name] = hlo_text
+        return ProgramRegistry.record(REGISTRY, name, fun_name, hlo_text)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    REGISTRY.record = record
+    try:
+        ds, layout, opt = _dataset(tmp_path_factory.mktemp("scopes"))
+        ds.begin_pass(round_to=8)
+        cfg = TrainStepConfig(
+            num_slots=S, batch_size=B, layout=layout, sparse_opt=opt,
+            auc_buckets=1000, check_nan=True,
+        )
+        tr = CTRTrainer(
+            DeepFM(num_slots=S, feat_width=layout.pull_width, embedx_dim=D, hidden=(32, 16)),
+            cfg, dense_opt=optax.adam(1e-3),
+        )
+        tr.init_params(jax.random.PRNGKey(0))
+        out = tr.train_pass(ds, n_batches=16)
+        assert out["batches"] == 16
+        yield {"ds": ds, "tr": tr, "compiles": compiles, "text": texts[PROGRAM]}
+    finally:
+        del REGISTRY.record
+        monitoring.unregister_event_duration_listener(on_event)
+
+
+def test_scope_of_strips_what_jax_adds_and_folds_the_backward_pass():
+    body = "jit(superstep)/while/body/closed_call/"
+    assert scope_of(body + "build_batch/inverse_scatter/scatter") == "build_batch/inverse_scatter"
+    assert scope_of(body + "transpose(jvp(seqpool_cvm))/gather") == "seqpool_cvm"
+    assert scope_of(body + "jvp(model)/tower/dot_general") == "model/tower"
+    assert scope_of(body + "build_batch/ragged_rows/jit(searchsorted)/vmap()/while/body/gather") \
+        == "build_batch/ragged_rows"
+    # a constant's name ends in the scope or in a jit(...) token, not in a primitive
+    assert scope_of(body + "jvp(loss)/jit(log_sigmoid)/jit(softplus)") == "loss"
+    assert scope_of(body + "push/merge/mul;" + body + "add") == "push/merge"  # merged: the first
+    assert scope_of("jit(superstep)/while/body/dynamic_update_slice") == ""
+    assert scope_of("jit(superstep)/while") == "" and scope_of("") == ""
+    text = (
+        'ENTRY %main {\n'
+        '  %p = f32[4]{0} parameter(0)\n'
+        '  %fusion.7 = s32[8]{0:T(1024)} fusion(%p), kind=kLoop, calls=%fc, '
+        'metadata={op_name="jit(superstep)/while/body/closed_call/pull/expand/jit(_take)/gather" '
+        'source_file="x.py" source_line=3}\n'
+        '  ROOT tuple.1 = (s32[8]) tuple(%fusion.7)\n}\n'
+    )
+    assert scope_map(text) == {"p": "", "fusion.7": "pull/expand", "tuple.1": ""}
+
+
+def test_superstep_map_holds_every_scope_and_names_the_sparse_instructions(trained):
+    entry = REGISTRY.get(PROGRAM)
+    assert entry is not None, REGISTRY.names()
+    scopes = entry["scopes"]
+    assert entry["instructions"] == len(scopes) > 100
+    found = set(scopes.values())
+    assert STEP_SCOPES <= found, STEP_SCOPES - found
+    # the push reads again the rows the pull gathered: XLA keeps one gather, under either name
+    assert {"pull/table_gather", "push/table_gather"} & found
+    # every sort, scatter (a segment sum is one) and gather of the step body is in a scope
+    sparse = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?\s(sort|scatter|gather)\(",
+                        trained["text"], re.M)
+    assert len(sparse) >= 10 and {op for _, op in sparse} == {"sort", "scatter", "gather"}
+    assert not [(n, op) for n, op in sparse if not scopes[n]]
+    # jax's own build seconds ride along, by the jitted function's name
+    assert entry["fun_name"] == "superstep"
+    assert entry["lower_s"] > 0 and entry["compile_s"] > 0 and entry["trace_s"] > 0
+
+
+def test_a_profiler_trace_holds_the_trainers_spans_as_pbx_annotations(trained, tmp_path):
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        trained["tr"].train_pass(trained["ds"], n_batches=16)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    host = {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for ln in plane.lines for e in ln.events
+            if e.name.startswith("pbx:")}
+    assert {"pbx:train_pass.open", "pbx:resident_prepare", "pbx:superstep_dispatch",
+            "pbx:superstep_wait", "pbx:superstep_consume", "pbx:train_pass.tail"} <= host, host
+
+
+def test_totals_count_every_span_with_the_profiler_disabled(tmp_path):
+    assert not PROFILER.enabled
+    PROFILER.reset()
+    with PROFILER.record_event("stage") as span:
+        pass
+    with PROFILER.record_event("stage"):
+        pass
+    assert span.seconds > 0
+    tot = PROFILER.totals()["stage"]
+    assert tot["count"] == 2 and tot["seconds"] >= span.seconds
+    assert PROFILER.export_chrome_trace(str(tmp_path / "t.json")) == 0  # the ring stayed empty
+    # a boundary stage publishes its span's own length under its literal stat name
+    ds, _, _ = _dataset(tmp_path)
+    ds.begin_pass(round_to=8)
+    tot = PROFILER.totals()
+    for stage in ("dedup", "pull"):
+        assert tot[f"boundary.{stage}"]["count"] == 1
+        assert STAT_GET(f"boundary.{stage}_s") == tot[f"boundary.{stage}"]["seconds"] > 0
+    assert tot["boundary.layout"]["count"] == 1
+
+
+def test_recording_the_map_compiles_nothing_and_outlives_the_trainer(trained):
+    # two supersteps ran and the map was recorded: the program compiled once
+    assert trained["compiles"].count("jit(superstep)") == 1, trained["compiles"]
+    n = REGISTRY.get(PROGRAM)["instructions"]
+    trained.pop("tr")
+    trained.pop("ds")
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+    entry = REGISTRY.get(PROGRAM)
+    assert entry["instructions"] == n and "build_batch/dedup_sort" in entry["scopes"].values()

@@ -15,6 +15,11 @@ This CLI is the read side for all three:
   # rank; cross-rank sends share a trace_id via the PBTX frame extension)
   python tools/obs_report.py --merge-traces out.json rank0.json rank1.json ...
 
+  # a jax.profiler device trace under the program's own names: device time
+  # by named scope, and the trainer's span over each of the longest idle
+  # gaps (SCOPES.json is REGISTRY.dump()'s file; default <trace>.scopes.json)
+  python tools/obs_report.py --device-trace TRACE.xplane.pb [SCOPES.json]
+
   # self-contained smoke of histogram/series/recorder/merge (verify drive)
   python tools/obs_report.py --selfcheck
 
@@ -250,6 +255,72 @@ def merge_traces(paths: Sequence[str], out_path: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# device trace under the program's names
+# ---------------------------------------------------------------------------
+
+
+def read_device_trace(path: str) -> dict:
+    """Events of an ``.xplane.pb`` in the shape ``benchmark/trace_reduce.py``
+    reduces: device operations and program executions per TPU plane, and
+    the host's spans — the program's ``pbx:<name>`` annotations
+    (utils/trace.py) and a harness's ``bench:<name>`` — each a
+    (name, start_s, end_s)."""
+    from jax.profiler import ProfileData
+
+    def ev(e, name=None):
+        return (name or e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
+
+    devices: Dict[str, dict] = {}
+    spans: List[tuple] = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:TPU:"):
+            lines = {ln.name: ln for ln in plane.lines}
+            devices[plane.name] = {
+                key: [ev(e) for e in lines[name].events] if name in lines else []
+                for key, name in (("ops", "XLA Ops"), ("modules", "XLA Modules"))
+            }
+        elif plane.name.startswith("/host:"):
+            spans += [ev(e, e.name.split(":", 1)[1]) for ln in plane.lines for e in ln.events
+                      if e.name.startswith(("pbx:", "bench:"))]
+    return {"devices": devices, "spans": spans}
+
+
+def device_trace_report(trace_path: str, scopes_path: Optional[str] = None) -> dict:
+    """Device seconds by the program's named scopes over the whole trace,
+    and its ten longest idle gaps under the innermost span that covered
+    each. The reduction is the benchmark's own (``trace_reduce.reduce``,
+    ``scope_times.scope_seconds``); this only reads the events and joins
+    them with the program registry's dump."""
+    from benchmark import scope_times, trace_reduce
+
+    if scopes_path is None:
+        scopes_path = trace_path.removesuffix(".xplane.pb") + ".scopes.json"
+    with open(scopes_path) as f:
+        programs = json.load(f)
+    trace = read_device_trace(trace_path)
+    if not trace["devices"]:
+        raise ValueError(f"{trace_path} has no /device:TPU plane")
+    ops = [o for dev in trace["devices"].values() for o in dev["ops"]]
+    window = (min(o[1] for o in ops), max(o[2] for o in ops))
+    red = trace_reduce.reduce(trace, window=window)
+    by_scope: Dict[str, float] = {}
+    for prog in programs.values():
+        for dev in trace["devices"].values():
+            mods = sorted((s, e) for n, s, e in dev["modules"] if prog["fun_name"] in n)
+            for scope, sec in scope_times.scope_seconds(
+                    dev["ops"], mods, *window, prog["scopes"]).items():
+                if scope != scope_times.OTHER:  # what ran outside this program's executions
+                    by_scope[scope] = by_scope.get(scope, 0.0) + sec / len(trace["devices"])
+    by_scope[scope_times.OTHER] = max(red["busy_s"] - sum(by_scope.values()), 0.0)
+    return {
+        "window_s": red["window_s"], "busy_s": red["busy_s"],
+        "programs": {n: p["instructions"] for n, p in programs.items()},
+        "scope_s": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])),
+        "idle_gaps": red["idle_gaps"],
+    }
+
+
+# ---------------------------------------------------------------------------
 # selfcheck: exercised by tools/verify_drive.py
 # ---------------------------------------------------------------------------
 
@@ -331,6 +402,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--merge-traces", nargs="+", metavar="JSON",
                     help="OUT.json IN0.json IN1.json ... — fuse per-rank "
                          "chrome traces into one timeline")
+    ap.add_argument("--device-trace", nargs="+", metavar="FILE",
+                    help="TRACE.xplane.pb [SCOPES.json] — device time by the "
+                         "program's named scopes, idle gaps by its spans")
     ap.add_argument("--selfcheck", action="store_true",
                     help="run the obs-plane smoke (verify drive gate)")
     ap.add_argument("--json", action="store_true", help="machine output")
@@ -346,8 +420,24 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print(json.dumps(rep, indent=None if args.json else 2))
         return 0
 
+    if args.device_trace:
+        if len(args.device_trace) > 2:
+            ap.error("--device-trace takes TRACE.xplane.pb and at most SCOPES.json")
+        rep = device_trace_report(*args.device_trace)
+        if args.json:
+            print(json.dumps(rep))
+            return 0
+        print(f"device busy {rep['busy_s']:.6f} s of {rep['window_s']:.6f} s; "
+              f"programs: {rep['programs']}")
+        for scope, sec in rep["scope_s"].items():
+            print(f"  {100 * sec / rep['busy_s']:6.2f}%  {sec:.6f} s  {scope or '(no scope)'}")
+        print("longest idle gaps, by the span that covered each:")
+        for span, sec in rep["idle_gaps"]:
+            print(f"  {1e3 * sec:9.3f} ms  {span}")
+        return 0
+
     if not args.obs_dir:
-        ap.error("give an obs_dir, --merge-traces, or --selfcheck")
+        ap.error("give an obs_dir, --merge-traces, --device-trace, or --selfcheck")
     records = load_series(args.obs_dir, rank=args.rank)
     if not records:
         print(f"no metric series under {args.obs_dir}", file=sys.stderr)
