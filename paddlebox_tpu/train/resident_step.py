@@ -12,10 +12,10 @@ row-resolved key stream can live in device HBM for the pass:
   (`rows`), per-record per-slot absolute offsets (`off`), labels, optional
   dense features. ~8 bytes/key, once.
 - **Per batch**: feed is ONE [B] int32 record-index vector (~16 KB). The
-  jitted step rebuilds the batch on device: ragged gather via
-  cumsum+searchsorted, then cross-slot dedup via sort + segment scan
-  (DedupKeysAndFillIdx parity, box_wrapper_impl.h:103 — the reference runs
-  the same dedup as a device kernel, not on the host).
+  jitted step rebuilds the batch on device: ragged gather via a scatter
+  of segment starts + prefix sum, then cross-slot dedup via sort +
+  segment scan (DedupKeysAndFillIdx parity, box_wrapper_impl.h:103 — the
+  reference runs the same dedup as a device kernel, not on the host).
 - **Superstep**: `lax.scan` over K batches per dispatch amortizes the
   host->device dispatch round-trip (BoxPSWorker's batch loop
   boxps_worker.cc:420-466 collapses into one XLA program per K batches).
@@ -241,7 +241,16 @@ def _ragged_rows(
     """Shared ragged gather: batch offsets -> (rows_flat, segments, valid)
     in slot-major flat order. ``pad_value`` fills invalid tail rows (the
     single-device tier pads with the real padding row; the mesh tier with
-    an out-of-range sentinel its sort treats as +inf)."""
+    an out-of-range sentinel its sort treats as +inf).
+
+    Flat positions and segment starts are both sorted, so nothing is
+    searched: every segment marks the flat position it begins at, and a
+    prefix sum over the positions carries the marks forward. Segment s
+    begins at ``begin[s]`` and its keys lie ``delta[s]`` further on in the
+    pass's row stream; marks of 1 sum to the segment id, marks of
+    ``delta[s] - delta[s-1]`` telescope to ``delta`` of the segment a
+    position is in. Zero-length segments share a begin with the next one
+    and need no case of their own."""
     lens_b = off_b[:, 1:] - off_b[:, :-1]
     starts_b = off_b[:, :-1]
     lens_flat = lens_b.T.reshape(-1)  # [S*B] slot-major
@@ -249,13 +258,24 @@ def _ragged_rows(
     cum = jnp.cumsum(lens_flat)
     L_real = cum[-1]
     pos = jnp.arange(L_pad, dtype=jnp.int32)
-    seg_c = jnp.minimum(
-        jnp.searchsorted(cum, pos, side="right").astype(jnp.int32), S * B - 1
-    )
-    within = pos - (cum[seg_c] - lens_flat[seg_c])
-    src = jnp.clip(starts_flat[seg_c] + within, 0, rows_res.shape[0] - 1)
-    valid = pos < L_real
-    rows_flat = jnp.where(valid, rows_res[src], pad_value)
+    with jax.named_scope("segment_scan"):
+        begin = cum - lens_flat  # non-decreasing; begin[0] == 0
+        delta = starts_flat - begin
+        zero = jnp.zeros((1,), jnp.int32)
+
+        def carried(marks):
+            # segments that begin at or past L_pad hold no position: dropped
+            at_begin = jnp.zeros((L_pad,), jnp.int32).at[begin]
+            return jnp.cumsum(
+                at_begin.add(marks, mode="drop", indices_are_sorted=True)
+            )
+
+        seg_c = carried(jnp.concatenate([zero, jnp.ones((S * B - 1,), jnp.int32)]))
+        ahead = carried(delta - jnp.concatenate([zero, delta[:-1]]))
+    with jax.named_scope("row_gather"):
+        src = jnp.clip(pos + ahead, 0, rows_res.shape[0] - 1)
+        valid = pos < L_real
+        rows_flat = jnp.where(valid, rows_res[src], pad_value)
     segments = jnp.where(valid, seg_c, S * B)  # seg_c IS slot*B + ins
     return rows_flat, segments, valid
 

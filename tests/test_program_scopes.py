@@ -148,6 +148,24 @@ def test_superstep_map_holds_every_scope_and_names_the_sparse_instructions(train
     assert entry["lower_s"] > 0 and entry["compile_s"] > 0 and entry["trace_s"] > 0
 
 
+def test_ragged_rows_searches_nothing_in_the_compiled_superstep(trained):
+    """A batch's segments come from a scatter of segment starts and a prefix
+    sum: no loop of its own (a binary search is a ``while``), nothing born of
+    ``searchsorted``, and one per-id gather, that of the row ids."""
+    scopes = REGISTRY.get(PROGRAM)["scopes"]
+    ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?[\s)]([a-z][\w\-]*)\(", trained["text"], re.M)
+    assert len(ops) == len(scopes)
+    ragged = [(n, op) for n, op in ops if scopes[n].startswith("build_batch/ragged_rows")]
+    kinds = {op for _, op in ragged}
+    assert len(ragged) > 10 and "scatter" in kinds, kinds
+    # the scan over the batches is the program's only loop, outside every scope
+    loops = [n for n, op in ops if op == "while"]
+    assert loops and not [n for n in loops if scopes[n]], [(n, scopes[n]) for n in loops]
+    assert "searchsorted" not in trained["text"]
+    assert {scopes[n] for n, op in ragged if op == "gather"} == {"build_batch/ragged_rows/row_gather"}
+    assert {"build_batch/ragged_rows/segment_scan", "build_batch/ragged_rows/row_gather"} <= set(scopes.values())
+
+
 def test_a_profiler_trace_holds_the_trainers_spans_as_pbx_annotations(trained, tmp_path):
     from jax.profiler import ProfileData
 

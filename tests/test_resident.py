@@ -413,3 +413,87 @@ def test_prepare_pass_prefreezes_shapes(tmp_path):
     tr.train_pass(ds, n_batches=8)
     assert (rp.L_pad, rp.U_pad) == pads_before
     assert len(tr._sstep_cache) == 1  # one train superstep, no regrowth
+
+
+# ---- _ragged_rows alone, against a plain loop over the segments ------------
+
+
+def _ragged_case(name):
+    """(rows, off [N, S+1], idx [B], L_pad) of one batch shape."""
+    rng = np.random.default_rng(27)
+    n_rec, s, b = 40, 4, 6
+    counts = rng.integers(0, 4, (n_rec, s))
+    idx = rng.integers(0, n_rec, b)
+    if name.startswith("ones"):
+        counts[:] = 1
+    elif name == "empty_batch":
+        counts[idx] = 0
+    elif name.startswith("zero_head_mid_tail"):
+        counts[idx[0], 0] = 0  # segment 0
+        counts[idx[2], 1] = counts[idx[3], 1] = 0  # two in a row, mid-stream
+        counts[idx[-1], s - 1] = 0  # segment S*B-1
+        counts[idx[-2], s - 1] = 0
+    elif name == "repeated_records":
+        idx = np.array([7, 7, 3, 7, 3, 11])
+        counts[7] = [2, 0, 3, 1]
+    elif name == "one_slot_one_record":
+        counts, idx, s, b = counts[:, :1] + 1, idx[:1], 1, 1
+    per = counts.sum(1)
+    base = np.concatenate([[0], np.cumsum(per)[:-1]])
+    off = base[:, None] + np.concatenate(
+        [np.zeros((n_rec, 1), np.int64), np.cumsum(counts, 1)], axis=1
+    )
+    rows = rng.integers(0, 1000, int(per.sum()) + 1).astype(np.int32)
+    l_real = int(per[idx].sum())
+    l_pad = {"exact": l_real, "short": l_real - 3}.get(
+        name.rsplit("-", 1)[-1], l_real + 5
+    )
+    return rows, off.astype(np.int32), idx.astype(np.int32), max(l_pad, 1)
+
+
+def _ragged_rows_loop(rows, off, idx, l_pad, pad_value):
+    s, b = off.shape[1] - 1, len(idx)
+    rows_flat, segments = [], []
+    for slot in range(s):
+        for ins, rec in enumerate(idx):
+            for k in range(off[rec, slot], off[rec, slot + 1]):
+                rows_flat.append(rows[k])
+                segments.append(slot * b + ins)
+    n = min(len(rows_flat), l_pad)
+    tail = l_pad - n
+    return (
+        np.array(rows_flat[:n] + [pad_value] * tail, np.int32),
+        np.array(segments[:n] + [s * b] * tail, np.int32),
+        np.arange(l_pad) < n,
+    )
+
+
+@pytest.mark.parametrize("pad", ["pad_row", "mesh_sentinel"])
+@pytest.mark.parametrize(
+    "name",
+    [
+        "ragged", "ragged-exact", "ragged-short",
+        "zero_head_mid_tail", "zero_head_mid_tail-exact",
+        "ones", "ones-exact", "repeated_records", "empty_batch",
+        "one_slot_one_record",
+    ],
+)
+def test_ragged_rows_equals_a_loop_over_the_segments(name, pad):
+    from paddlebox_tpu.train.resident_step import _ragged_rows
+
+    rows, off, idx, l_pad = _ragged_case(name)
+    s, b = off.shape[1] - 1, len(idx)
+    # the one-chip tier pads with a real row of the table, the mesh tier
+    # with ns*cap, one past every row id (a traced scalar there)
+    pad_value = 999 if pad == "pad_row" else jnp.int32(1000)
+    got = jax.jit(_ragged_rows, static_argnums=(2, 3, 4))(
+        jnp.asarray(rows), jnp.asarray(off)[idx], s, b, l_pad, pad_value
+    )
+    want = _ragged_rows_loop(rows, off, idx, l_pad, int(pad_value))
+    for g, w, what in zip(got, want, ("rows_flat", "segments", "valid")):
+        assert g.dtype == w.dtype and g.shape == w.shape, what
+        np.testing.assert_array_equal(np.asarray(g), w, err_msg=what)
+    if name == "zero_head_mid_tail":  # the case is what it says
+        lens = np.diff(off[idx], axis=1).T.reshape(-1)
+        assert lens[0] == 0 and lens[-1] == 0 and lens[-2] == 0
+        assert ((lens[1:-1] == 0) & (np.roll(lens, 1)[1:-1] == 0)).any()

@@ -208,18 +208,20 @@ def main():
         (rows_l, jnp.arange(L, dtype=jnp.int32)),
     )
 
-    # repeat/ragged expansion: cumsum + searchsorted at L
+    # ragged expansion as _ragged_rows does it: a scatter of the segment
+    # starts and a prefix sum over the L flat positions
     lens = jnp.asarray(rng.integers(0, 3, NUM_SLOTS * BATCH).astype(np.int32))
 
     def ragged(c, i):
         ln = c[0]
-        starts = jnp.cumsum(ln) - ln
-        seg = jnp.searchsorted(
-            jnp.cumsum(ln), jnp.arange(L, dtype=jnp.int32), side="right"
+        begin = jnp.cumsum(ln) - ln
+        marks = jnp.zeros((L,), jnp.int32).at[begin].add(
+            1, mode="drop", indices_are_sorted=True
         )
-        return (ln, seg.astype(jnp.float32).sum() * 0 + starts.astype(jnp.float32).sum() * 0)
+        seg = jnp.cumsum(marks) - 1
+        return (ln, seg.astype(jnp.float32).sum() * 0)
 
-    timed_loop("ragged expand (cumsum+searchsorted L)", ragged, (lens, jnp.float32(0)))
+    timed_loop("ragged expand (scatter of starts + cumsum L)", ragged, (lens, jnp.float32(0)))
 
     if "--scatter-sweep" in sys.argv:
         scatter_sweep(rng, artifact_path=artifact_path)
