@@ -4,6 +4,7 @@ from paddlebox_tpu.models.deepfm import DeepFM
 from paddlebox_tpu.models.wide_deep import WideDeep, DCN
 from paddlebox_tpu.models.mmoe import MMoE, task_head
 from paddlebox_tpu.models.rank import RankDeepFM
+from paddlebox_tpu.models.glm_moe_lite import GlmMoeLite, GlmMoeLiteConfig
 
 __all__ = [
     "mlp_init",
@@ -17,4 +18,6 @@ __all__ = [
     "MMoE",
     "task_head",
     "RankDeepFM",
+    "GlmMoeLite",
+    "GlmMoeLiteConfig",
 ]

@@ -29,3 +29,35 @@ class CTRModel(Protocol):
     def apply(self, params: Any, slot_feats: jnp.ndarray, dense: jnp.ndarray | None) -> jnp.ndarray:
         """-> logits [batch] (or [batch, n_tasks] for multi-task models)."""
         ...
+
+
+class SequenceLossModel(Protocol):
+    """A model that takes one sparse slot's pulled rows as a sequence and owns
+    its loss (a language model over token ids: ``models/glm_moe_lite.py``).
+
+    ``sequence_feed = True`` on the model object is the declaration:
+    ``CTRTrainer`` carries it to the step builders as
+    ``TrainStepConfig.sequence_len``. Every record holds exactly
+    ``seq_len`` keys in its one sparse slot; the step hands ``apply`` the
+    pulled rows unpooled, ``[batch, seq_len, embedx_dim]`` in record order
+    with the CVM columns dropped, and the record's dense float slot
+    (``dense_dim`` values: for a language model its token ids, the targets);
+    ``apply`` returns ``(loss, {"counters": ...})``. No seqpool+CVM, BCE or
+    AUC runs; the gradient of the rows goes through the push like any
+    feature's, and ``counters`` (one stacked vector named by
+    ``counter_names``) rides in the step's metrics."""
+
+    sequence_feed: bool
+    seq_len: int
+    dense_dim: int
+    counter_names: tuple
+
+    def init(self, rng) -> Any:
+        ...
+
+    def apply(self, params: Any, emb: jnp.ndarray, dense: jnp.ndarray):
+        """-> (loss scalar, {"counters": [n]})."""
+        ...
+
+    def record_counters(self, means) -> None:
+        ...

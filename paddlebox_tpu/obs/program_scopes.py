@@ -19,10 +19,15 @@ information out, ``jax_compilation_cache_include_metadata_in_key``): its map
 shows the scopes as they were then. Such an entry has no ``compile_s``.
 
 Scope paths are the program's own names only: ``jit(...)`` tokens, jax's
-structural names (``while/body``, ``closed_call``, ...), the
-``jvp(...)``/``transpose(...)`` wrappers of autodiff and the trailing
-primitive name are stripped, so a forward op and its backward twin land in
-the same scope.
+structural names (``while/body``, ``closed_call``, ``checkpoint``,
+``rematted_computation``, ...), the ``jvp(...)``/``transpose(...)`` wrappers
+of autodiff, an einsum's own spec and the trailing primitive name are
+stripped, and a path repeated under itself (the recomputed body of a
+``jax.checkpoint`` or of a scan over layers) is folded to its last
+occurrence, so a forward op, its backward twin and its recomputed twin land
+in the same scope. Code under ``checkpoint`` or ``scan`` names its leaf
+scopes by their whole path (``jax.named_scope("model/mla/scores")``): the
+name stack of a re-traced body starts anew there.
 """
 
 from __future__ import annotations
@@ -46,7 +51,7 @@ _WRAPPER = re.compile(r"\b\w+\(|\)")  # jvp( transpose( vmap( ... and their )
 _STRUCTURAL = frozenset((
     "jit", "while", "body", "cond", "closed_call", "core_call", "checkpoint",
     "remat", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
-    "shard_map", "branch_0_fun", "branch_1_fun",
+    "shard_map", "branch_0_fun", "branch_1_fun", "rematted_computation",
 ))
 
 
@@ -59,7 +64,14 @@ def scope_of(op_name: str) -> str:
     # have none only where a jit(...) token ends its name
     if tokens and tokens[-1] != "jit":
         tokens.pop()
-    return "/".join(t for t in tokens if t not in _STRUCTURAL)
+    # an einsum pushes its own spec ("bqhd,bkhd->bhqk"): no scope of the program's
+    path = [t for t in tokens if t not in _STRUCTURAL and "->" not in t]
+    # the backward pass of a ``jax.checkpoint`` body (and of a scan's) re-traces
+    # instructions that carry their whole path under the path of the call:
+    # "model/mla/scores/model/mla/scores" is the second "model/..." alone
+    if path and path[0] in path[1:]:
+        path = path[len(path) - 1 - path[::-1].index(path[0]):]
+    return "/".join(path)
 
 
 def scope_map(hlo_text: str) -> Dict[str, str]:

@@ -345,6 +345,13 @@ def make_resident_superstep(
     stacked along the scan axis. The per-step body is the classic
     make_train_step — only batch assembly is resident."""
     raw_step = make_train_step(model_apply, dense_opt, cfg, eval_mode=eval_mode)
+    if cfg.sequence_len and not np.all(rp._key_counts == cfg.sequence_len):
+        # the rows reach the model as [B, T, embedx]: a record of another
+        # length would shift every record behind it
+        raise ValueError(
+            f"a sequence feed of {cfg.sequence_len} keys a record, but the pass has "
+            f"records of {int(rp._key_counts.min())} to {int(rp._key_counts.max())}"
+        )
 
     def body(state, idx):
         batch = build_device_batch(rp, cfg, idx)

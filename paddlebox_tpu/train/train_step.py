@@ -85,8 +85,24 @@ class TrainStepConfig:
     # changes: show/clk counters keep their unweighted (or pv-ghost 0/1)
     # semantics, exactly as the reference's push records do.
     adjust_ins_weight: Optional[tuple] = None
+    # a sequence-feed model (models/base.py::SequenceLossModel): every record
+    # holds exactly this many keys in the one sparse slot; the model gets
+    # the pulled rows unpooled as [batch, sequence_len, embedx] with the
+    # record's dense slot and returns (loss, outputs) — no seqpool+CVM, BCE
+    # or AUC. 0 = a CTR model. CTRTrainer fills it in from the model object.
+    sequence_len: int = 0
 
     def __post_init__(self):
+        if self.sequence_len and (
+            self.num_slots != 1 or self.use_expand or self.model_takes_rank_offset
+            or self.adjust_ins_weight is not None or self.axis_name is not None
+            or self.dense_sync_mode != "step"
+        ):
+            raise NotImplementedError(
+                "a sequence-feed model trains one sparse slot on one device with "
+                "the dense optimizer in the step (no expand block, rank matrix, "
+                "instance weights, mesh axis or async/kstep dense mode)"
+            )
         if self.adjust_ins_weight is not None:
             nid, thr, ratio = self.adjust_ins_weight
             if not (0 <= nid < self.num_slots) or thr <= 0 or ratio < 0:
@@ -139,9 +155,22 @@ def local_forward_backward(
     gradient everywhere. ``loss_denom`` overrides the weight-sum denominator —
     the mesh step passes the GLOBAL (psum'd) weight sum so per-device ghost
     imbalance cannot skew sample weighting.
+
+    A sequence-feed model gets the slot's rows as ``[B, T, embedx]`` in
+    record order (CVM columns dropped) with the record's dense slot, and
+    returns ``(loss, outputs)`` itself; ``preds`` is then its outputs dict.
     """
 
     def loss_fn(p, flat_records):
+        if cfg.sequence_len:
+            B, T = cfg.batch_size, cfg.sequence_len
+            if flat_records.shape[0] < B * T:
+                raise ValueError(
+                    f"a batch of {B} records of {T} keys needs {B * T} pulled "
+                    f"rows, the feed is padded to {flat_records.shape[0]}"
+                )
+            emb = flat_records[: B * T, cfg.layout.cvm_offset:].reshape(B, T, -1)
+            return model_apply(p, emb, dense)
         if cfg.use_expand:  # trailing expand columns pool separately
             E = cfg.layout.expand_dim
             expand_flat = flat_records[:, -E:]
@@ -288,6 +317,7 @@ def make_train_step(
     """
     lay, opt = cfg.layout, cfg.sparse_opt
     S, B = cfg.num_slots, cfg.batch_size
+    owns_loss = bool(cfg.sequence_len)
 
     def step(state: TrainState, batch: Dict[str, jnp.ndarray]):
         uniq_rows = batch["uniq_rows"]
@@ -392,12 +422,15 @@ def make_train_step(
                         new_opt_state, state.opt_state,
                     )
 
-        with jax.named_scope("auc"):
-            auc_mask = None if ins_weight is None else (ins_weight > 0)
-            if finite is not None:
-                fin_mask = jnp.broadcast_to(finite, labels.shape)
-                auc_mask = fin_mask if auc_mask is None else (auc_mask & fin_mask)
-            new_auc = auc_update(state.auc, preds, labels, auc_mask)
+        if owns_loss:  # no prediction per instance to rank: the buckets stay
+            new_auc = state.auc
+        else:
+            with jax.named_scope("auc"):
+                auc_mask = None if ins_weight is None else (ins_weight > 0)
+                if finite is not None:
+                    fin_mask = jnp.broadcast_to(finite, labels.shape)
+                    auc_mask = fin_mask if auc_mask is None else (auc_mask & fin_mask)
+                new_auc = auc_update(state.auc, preds, labels, auc_mask)
         # a skipped batch never happened: the step counter (which paces
         # kstep param syncs and dump sampling) must not advance either
         step_inc = (
@@ -405,12 +438,11 @@ def make_train_step(
         )
         # preds/labels ride along for the host-side metric registry
         # (AddAucMonitor parity) — small [B] arrays, no sync forced
-        metrics = {
-            "loss": loss,
-            "step": state.step + step_inc,
-            "preds": preds,
-            "labels": labels,
-        }
+        metrics = {"loss": loss, "step": state.step + step_inc}
+        if owns_loss:  # the model's own outputs: its counters in one stacked array
+            metrics.update(preds)
+        else:
+            metrics.update(preds=preds, labels=labels)
         if finite is not None:
             metrics["nan_skipped"] = (~finite).astype(jnp.int32)
         if cfg.dense_sync_mode == "async" and not eval_mode:

@@ -18,6 +18,7 @@ working-set table is rebuilt per pass (pass-scoped HBM staging parity).
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from typing import Any, Callable, Dict, Optional, Sequence
 
@@ -98,6 +99,15 @@ class CTRTrainer:
         box: Optional[Any] = None,  # BoxWrapper whose test_mode gates eval
     ):
         self.model = model
+        if getattr(model, "sequence_feed", False):
+            # the model object says what it needs; the step builders read it
+            # from the config, whatever wraps model.apply
+            if cfg.sequence_len not in (0, model.seq_len):
+                raise ValueError(
+                    f"cfg.sequence_len {cfg.sequence_len} against the model's "
+                    f"seq_len {model.seq_len}"
+                )
+            cfg = dataclasses.replace(cfg, sequence_len=model.seq_len)
         self.cfg = cfg
         self.dense_opt = dense_opt or optax.adam(1e-3)
         self.plan = plan
@@ -135,6 +145,8 @@ class CTRTrainer:
         self.params: Any = None
         self.opt_state: Any = None
         self._state: Optional[TrainState] = None
+        self._dense_in_place = False  # see hand_over_dense
+        self._dense_with_pass = False  # handed over, and not (yet) returned
         # eval/infer mode (SetTestMode box_wrapper.cc:623 +
         # infer_from_dataset executor.py:1520): either set directly on the
         # trainer or inherited from the owning BoxWrapper each pass
@@ -176,6 +188,7 @@ class CTRTrainer:
 
     def init_params(self, rng=None) -> None:
         rng = rng if rng is not None else jax.random.PRNGKey(0)
+        self._dense_with_pass = False
         self.params = self.model.init(rng)
         if isinstance(self.dense_opt, Zero1Optimizer):
             # chunked state is built (and placed sharded) by
@@ -184,10 +197,47 @@ class CTRTrainer:
         else:
             self.opt_state = self.dense_opt.init(self.params)
 
+    def hand_over_dense(self, params: Any = None, opt_state: Any = None) -> None:
+        """Train the dense state in place: from this call on no pass makes a
+        second copy of it.
+
+        By default a pass trains device copies of ``params`` / ``opt_state``
+        (the step donates its state), so a reader of ``trainer.params`` — a
+        mid-pass ``save_dense``, a follower, a rollback — sees the values the
+        pass opened with. A dense state that cannot lie twice on the chip (a
+        language model's parameters and moments are most of its memory) is
+        handed over instead: every pass takes the trainer's own buffers.
+        While a pass runs, ``params`` and ``opt_state`` are None and
+        ``save_dense`` raises; ``train_pass`` re-points them at what the
+        steps returned when it ends — after a failed pass too, if the buffers
+        survived it. If they did not (an XLA error after donation) the state
+        is gone: ``init_params()``, then ``load_dense`` a checkpoint.
+
+        ``params`` given replace the trainer's (``opt_state`` fresh from the
+        optimizer unless given too); the caller keeps no use of them."""
+        if self.plan is not None:
+            raise NotImplementedError(
+                "the mesh path shards and copies the dense state itself "
+                "(init_sharded_train_state)"
+            )
+        if params is not None:
+            self.params = params
+            self.opt_state = opt_state if opt_state is not None else self.dense_opt.init(params)
+            self._dense_with_pass = False
+        self._dense_in_place = True
+
     def save_dense(self, path: str) -> None:
         """Dense checkpoint (worker-scope param dump parity,
         boxps_trainer.cc:123-131). Written tmp-then-rename so a crash
         mid-write can't corrupt the checkpoint a cursor already points to."""
+        if self._dense_in_place and self.params is None:
+            # flattening (None, None) would replace a good checkpoint with
+            # an npz of no leaves
+            raise RuntimeError(
+                "the dense state is handed over (hand_over_dense) and a pass "
+                "holds it: save after train_pass returns. If a pass failed "
+                "and took it along, init_params() and load_dense a checkpoint"
+            )
         path = path if path.endswith(".npz") else path + ".npz"
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         leaves, treedef = jax.tree.flatten((self.params, self.opt_state))
@@ -236,9 +286,18 @@ class CTRTrainer:
             and ws_key is not None
             and getattr(self, "_state_ws", None) is ws_key
         ):
+            if self._dense_in_place:  # the cached state's buffers are the trainer's
+                self.params = self.opt_state = None
+                self._dense_with_pass = True
             return self._state
         self._state_ws = ws_key
         if self.params is None:
+            if self._dense_with_pass:
+                raise RuntimeError(
+                    "the dense state was handed to a pass that failed and took "
+                    "it along (hand_over_dense keeps no copy): init_params(), "
+                    "then load_dense a checkpoint, before training on"
+                )
             self.init_params()
         if self.plan is None:
             with PROFILER.record_event("state.upload", "pass"):
@@ -247,14 +306,24 @@ class CTRTrainer:
             # so handing self.params's own buffers over would delete them —
             # a mid-pass save_dense or an aborted pass would then read dead
             # arrays (init_sharded_train_state makes the same copies on
-            # the mesh path)
+            # the mesh path). After hand_over_dense the pass takes the
+            # buffers themselves: params / opt_state are None until
+            # train_pass re-points them at what the steps returned
+            params, opt_state = self.params, self.opt_state
+            if self._dense_in_place:
+                self.params = self.opt_state = None
+                self._dense_with_pass = True
+            else:
+                params = jax.tree.map(jnp.copy, params)
+                opt_state = jax.tree.map(jnp.copy, opt_state)
             state = TrainState(
                 table=flat,
-                params=jax.tree.map(jnp.copy, self.params),
-                opt_state=jax.tree.map(jnp.copy, self.opt_state),
+                params=params,
+                opt_state=opt_state,
                 auc=auc_init(self.cfg.auc_buckets),
                 step=jnp.zeros((), jnp.int32),
             )
+            del params, opt_state
             # one placement for every leaf. A spliced pass table arrives
             # COMMITTED to its device (born under out_shardings) beside
             # uncommitted fresh leaves; the step's outputs are then all
@@ -1071,6 +1140,7 @@ class CTRTrainer:
             auc_pos0 = self._host_np(state.auc.pos).copy()
             auc_neg0 = self._host_np(state.auc.neg).copy()
         losses = []
+        self._pass_counters = []  # a sequence-feed model's stacked counters, per batch
         # join phase serves pv-merged batches with rank_offset + ghost
         # weights; update phase serves flat batches (EnablePvMerge branch,
         # data_feed.cc:2165-2198)
@@ -1162,6 +1232,9 @@ class CTRTrainer:
             except AttributeError:
                 pass  # host-side array: always alive
             self._state = st if alive else None
+            if self._dense_in_place and alive:  # handed over, and it survived
+                self.params, self.opt_state = st.params, st.opt_state
+                self._dense_with_pass = False
             raise
         state = holder["state"]
         # persist dense side for the next pass; state.table stays for writeback
@@ -1190,6 +1263,7 @@ class CTRTrainer:
             self.params = state.params
             self.opt_state = state.opt_state
         self._state = state
+        self._dense_with_pass = False
         if self.dump_pool is not None and self.dump_params_at_end:
             # DumpParam parity (device_worker.cc:131-133): dense params once
             # at pass end, one line per leaf
@@ -1219,6 +1293,11 @@ class CTRTrainer:
                 out["loss"] = float(jnp.mean(jnp.stack(losses))) if losses else float("nan")
                 out["nan_batches"] = 0.0
             out["batches"] = float(len(losses))
+            if self._pass_counters:  # the model's own: means over the pass, one read
+                means = np.asarray(jnp.mean(jnp.stack(self._pass_counters), axis=0))
+                out.update(zip(self.model.counter_names, map(float, means)))
+                self.model.record_counters(means)
+                self._pass_counters = []
             if not eval_mode:
                 # monitor parity: training-lifecycle counters (an eval pass
                 # trains nothing, so it bumps nothing). ins_num counts REAL
@@ -1268,6 +1347,8 @@ class CTRTrainer:
         if on_batch is not None:
             on_batch(i, m)
         losses.append(m["loss"])
+        if "counters" in m:
+            self._pass_counters.append(m["counters"])
         t_host.pause()
 
     def _dump_batch(self, step_i: int, m: Dict, aux: Dict) -> None:

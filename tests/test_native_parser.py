@@ -176,3 +176,42 @@ def test_native_edge_parity():
     got = native.parse_buffer((line + "\n").encode(), schema_both)
     assert_records_equal(got, [want])
     assert got[0].ins_id == lk32
+
+
+def test_native_parses_a_record_of_4096_keys_in_one_slot(tmp_path):
+    """A token record: one 65 KB line holding a dense slot of 4,096 floats and
+    a sparse slot of 4,096 thirteen-digit keys. No line or per-slot limit:
+    the columnar store, the float matrix and the resident pass's offsets
+    (past the uint8 count form, which holds 255 keys a slot) carry it."""
+    from paddlebox_tpu.train.resident_step import ResidentPass
+
+    T, n = 4096, 6
+    rng = np.random.default_rng(28)
+    ids = rng.integers(0, 19360, (n, T))
+    lines = [f"1 0.0 {T} " + " ".join(f"{i}.0" for i in row) + f" {T} "
+             + " ".join(str(10**12 + i) for i in row) for row in ids.tolist()]
+    assert min(len(ln) for ln in lines) > 65_000
+    schema = SlotSchema(
+        [SlotInfo("label", type="float", dense=True, dim=1),
+         SlotInfo("ids", type="float", dense=True, dim=T), SlotInfo("tokens")],
+        label_slot="label")
+    buf = ("\n".join(lines) + "\n").encode()
+    recs = native.parse_buffer(buf, schema)
+    assert len(recs) == n
+    assert_records_equal(recs, [parse_line(ln, schema) for ln in lines])
+    store = native.parse_buffer_columnar(buf, schema)
+    assert len(store) == n and np.all(store.key_counts() == T)
+    assert np.array_equal(np.asarray(store.u64_values).reshape(n, T), ids + 10**12)
+    assert np.array_equal(store.float_slot_matrix(schema.float_slot_index("ids"), T), ids)
+
+    class Ws:  # the rows a working set would resolve: here the id itself
+        n_mesh_shards, capacity = 1, 32768
+
+        @staticmethod
+        def lookup(keys):
+            return (np.asarray(keys) - 10**12).astype(np.int32)
+
+    rp = ResidentPass(store, Ws(), schema, dense_slot="ids", dense_dim=T)
+    assert rp.counts is None and rp.off.shape == (n, 2)  # 4,096 keys: the offset matrix
+    assert np.array_equal(np.asarray(rp.off)[:, 1] - np.asarray(rp.off)[:, 0], np.full(n, T))
+    assert np.array_equal(np.asarray(rp.dense), ids)

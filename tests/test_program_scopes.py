@@ -214,3 +214,72 @@ def test_recording_the_map_compiles_nothing_and_outlives_the_trainer(trained):
     gc.collect()
     entry = REGISTRY.get(PROGRAM)
     assert entry["instructions"] == n and "build_batch/dedup_sort" in entry["scopes"].values()
+
+
+# ---- under jax.checkpoint and a scan over layers (a language model's step) ----
+
+def _parent_scope_of(op_name: str) -> str:
+    """``scope_of`` as it was before it folded recomputed bodies."""
+    from paddlebox_tpu.obs import program_scopes as ps
+
+    first = op_name.split(";", 1)[0]
+    tokens = [t for t in ps._WRAPPER.sub("", ps._PROGRAM.sub("jit", first)).split("/") if t]
+    if tokens and tokens[-1] != "jit":
+        tokens.pop()
+    return "/".join(t for t in tokens if t not in ps._STRUCTURAL - {"rematted_computation"})
+
+
+def test_scope_of_folds_recomputed_and_scanned_bodies_to_one_path():
+    body = "jit(superstep)/while/body/closed_call/"
+    # forward, inside the layer scan; an einsum's own spec is no scope
+    assert scope_of(body + "jvp(model/mla/scores)/while/body/checkpoint/bqhd,bkhd->bhqk/dot_general") \
+        == "model/mla/scores"
+    # the backward pass re-traces a checkpointed body under the path of its call
+    assert scope_of(body + "transpose(jvp(model/mla/scores))/checkpoint/rematted_computation/"
+                    "model/mla/scores/bhqk,bkhd->bqhd/dot_general") == "model/mla/scores"
+    assert scope_of(body + "transpose(jvp(model/mtp/moe/shared))/while/body/checkpoint/"
+                    "rematted_computation/model/mtp/moe/experts/dot_general") == "model/mtp/moe/experts"
+    assert scope_of(body + "loss/head/while/body/checkpoint/rematted_computation/loss/head/"
+                    "reduce_max") == "loss/head"
+    # what has no repeat stays whole
+    assert scope_of(body + "push/merge/mul") == "push/merge"
+    assert scope_of(body + "build_batch/ragged_rows/segment_scan/scatter-add") \
+        == "build_batch/ragged_rows/segment_scan"
+
+
+def test_the_ctr_supersteps_scopes_are_what_they_were(trained):
+    names = re.findall(r'op_name="([^"]*)"', trained["text"])
+    assert len(names) > 100
+    assert [scope_of(n) for n in names] == [_parent_scope_of(n) for n in names]
+    found = set(REGISTRY.get(PROGRAM)["scopes"].values())
+    assert not [s for s in found if "remat" in s or "->" in s or s.startswith("model/")], found
+
+
+GLM_SCOPES = {
+    f"{pre}/{leaf}" for pre in ("model", "model/mtp")
+    for leaf in ("mla/q_proj", "mla/kv_proj", "mla/rope", "mla/scores", "mla/out_proj",
+                 "moe/router", "moe/dispatch", "moe/experts", "moe/combine", "moe/shared")
+} | {"model/dense_mlp", "model/mtp/eh_proj", "loss/head"}
+
+
+def test_a_language_models_superstep_maps_every_layer_scope_under_checkpoint_and_scan(tmp_path):
+    from test_glm_moe_lite import B as GB, H, T, TINY, _dataset as token_dataset, _token_files, _trainer
+
+    from benchmark.reference import glm_moe_lite as ref
+
+    ids = np.random.default_rng(2).integers(0, 64, (16, T))
+    box, ds = token_dataset(_token_files(tmp_path, ids))
+    tr = _trainer(box, ref.init(jax.random.PRNGKey(1), TINY, 3 + H))
+    assert tr.train_pass(ds, n_batches=8)["batches"] == 8
+    entry = REGISTRY.get(f"superstep/train/8x{GB}")
+    found = set(entry["scopes"].values())
+    assert GLM_SCOPES <= found, GLM_SCOPES - found
+    # nothing else under model/ or loss/: no doubled prefix, no structural name, no einsum spec
+    ours = {s for s in found if s.split("/")[0] in ("model", "loss", "mla", "moe", "mtp")}
+    assert ours == GLM_SCOPES, ours - GLM_SCOPES
+    shared = {s for s in STEP_SCOPES if s.split("/")[0] in ("build_batch", "pull", "push")} | {"dense_opt"}
+    assert shared <= found and not {"seqpool_cvm", "auc", "loss"} & found
+    # forward, recomputed and backward instructions all carry the scope: the
+    # scores' scope holds several dots (QK^T and PV, each of the three passes)
+    text_ops = [n for n, s in entry["scopes"].items() if s == "model/mla/scores"]
+    assert len(text_ops) >= 6
