@@ -21,9 +21,18 @@ loss in float32; matrix-product operands cast to bfloat16 with float32
 accumulation; the router's own product in float32 at ``highest``.
 
 Memory: every layer is recomputed in the backward (``jax.checkpoint``), the
-expert layers are one stacked body under ``lax.scan``, attention runs in
-query blocks over the causal prefix only, and the head's logits exist one
-block of positions at a time.
+expert layers are one stacked body under ``lax.scan``, the attention scores
+never exist whole, and the head's logits exist one block of positions at a
+time. The scores take one of two forms, chosen at trace time from what the
+call site can see (``fused_scores``: the backend and the shapes; counted under
+``model.mla.fused_scores`` / ``model.mla.blocked_scores``): on a TPU, at
+shapes its tiles divide, one fused kernel a pass
+(``ops/pallas_kernels.py::causal_attention``: tiles of ``attn_block``
+queries by ``attn_block`` keys in VMEM, online softmax, the tiles above the
+diagonal skipped, a backward that recomputes them from q, k, v and the rows'
+logsumexp); everywhere else ``attn_block`` queries at a time against their
+causal prefix, each block recomputed in the backward (``_attend_block``).
+Both keep the precision above.
 """
 
 from __future__ import annotations
@@ -36,6 +45,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from paddlebox_tpu.ops.pallas_kernels import LANE, causal_attention
+from paddlebox_tpu.utils.monitor import STAT_ADD
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 COUNTERS = ("loss_main", "loss_mtp", "tokens", "held_assignments", "expert_load_max_over_mean")
@@ -69,7 +81,7 @@ class GlmMoeLiteConfig:
     seq_len: int = 4096
     mtp_loss_weight: float = 0.3
     initializer_range: float = 0.02
-    attn_block: int = 512  # queries a block; keys are the causal prefix
+    attn_block: int = 512  # queries (and, in the fused kernel, keys) a tile of the scores
     loss_block: int = 1024  # positions whose logits exist at once
     expert_block: int = 512  # rows of one grouped product
 
@@ -163,6 +175,13 @@ def _attend_block(q, k, v, q0: int, n_q: int, scale: float):
     return _product("bhqk,bkhd->bqhd")(p, v)
 
 
+def fused_scores(backend: str, T: int, qk_dim: int, v_dim: int, block: int) -> bool:
+    """Whether a call site of ``mla`` takes the fused kernel: on a TPU, at
+    shapes the kernel tiles. Everything else runs the blocked form."""
+    return (backend == "tpu" and qk_dim == v_dim and qk_dim % LANE == 0
+            and block % LANE == 0 and T % block == 0)
+
+
 def mla(p, x, norm_w, c: GlmMoeLiteConfig, rope, scope: str):
     """x + attention(norm(x)): latent attention in its uncompressed (training)
     form. x [B, T, H]. ``scope`` is the block's absolute scope path: every
@@ -190,8 +209,13 @@ def mla(p, x, norm_w, c: GlmMoeLiteConfig, rope, scope: str):
         if T % Q:
             raise ValueError(f"seq_len {T} is not a multiple of attn_block {Q}")
         scale = float(dn + dr) ** -0.5
-        o = jnp.concatenate(
-            [_attend_block(q, k, v, i, Q, scale) for i in range(0, T, Q)], axis=1)
+        if fused_scores(jax.default_backend(), T, dn + dr, dv, Q):
+            STAT_ADD("model.mla.fused_scores")  # call sites lowered each way, at trace time
+            o = causal_attention(q, k, v, scale, Q)
+        else:
+            STAT_ADD("model.mla.blocked_scores")
+            o = jnp.concatenate(
+                [_attend_block(q, k, v, i, Q, scale) for i in range(0, T, Q)], axis=1)
     with jax.named_scope(f"{scope}/mla/out_proj"):
         return x + _mm(o.reshape(B, T, nh * dv), p["o"])
 

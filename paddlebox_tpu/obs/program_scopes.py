@@ -21,13 +21,14 @@ shows the scopes as they were then. Such an entry has no ``compile_s``.
 Scope paths are the program's own names only: ``jit(...)`` tokens, jax's
 structural names (``while/body``, ``closed_call``, ``checkpoint``,
 ``rematted_computation``, ...), the ``jvp(...)``/``transpose(...)`` wrappers
-of autodiff, an einsum's own spec and the trailing primitive name are
-stripped, and a path repeated under itself (the recomputed body of a
-``jax.checkpoint`` or of a scan over layers) is folded to its last
-occurrence, so a forward op, its backward twin and its recomputed twin land
-in the same scope. Code under ``checkpoint`` or ``scan`` names its leaf
-scopes by their whole path (``jax.named_scope("model/mla/scores")``): the
-name stack of a re-traced body starts anew there.
+of autodiff, an einsum's own spec, a Pallas kernel's own name and the
+trailing primitive name are stripped, and a path repeated under itself (the
+recomputed body of a ``jax.checkpoint`` or of a scan over layers) is folded
+to its last occurrence, so a forward op, its backward twin and its
+recomputed twin land in the same scope. Code under ``checkpoint`` or
+``scan`` names its leaf scopes by their whole path
+(``jax.named_scope("model/mla/scores")``): the name stack of a re-traced
+body starts anew there.
 """
 
 from __future__ import annotations
@@ -63,7 +64,11 @@ def scope_of(op_name: str) -> str:
     # the last token is the primitive; a constant has none, and is seen to
     # have none only where a jit(...) token ends its name
     if tokens and tokens[-1] != "jit":
-        tokens.pop()
+        # a kernel's ``name=`` is the scope ``pallas_call`` itself opens around
+        # the primitive (every kernel of ops/pallas_kernels.py on a model's
+        # path is named): the kernel's, not the program's
+        if tokens.pop() == "pallas_call" and tokens:
+            tokens.pop()
     # an einsum pushes its own spec ("bqhd,bkhd->bhqk"): no scope of the program's
     path = [t for t in tokens if t not in _STRUCTURAL and "->" not in t]
     # the backward pass of a ``jax.checkpoint`` body (and of a scan's) re-traces

@@ -247,6 +247,45 @@ def test_scope_of_folds_recomputed_and_scanned_bodies_to_one_path():
         == "build_batch/ragged_rows/segment_scan"
 
 
+# what the fused attention's nine custom calls carry in the token cell's compiled
+# superstep (copied from the chip's HLO, PR 29: the dense layer, the scanned
+# expert layers and the MTP module, each forward, recomputed under its
+# checkpoint and backward): the kernel's ``name=`` is the scope ``pallas_call``
+# itself opens around the primitive, and is no scope of the program's
+_STEP = "jit(superstep)/while/body/closed_call/"
+KERNEL_OP_NAMES = {
+    "model/mla/scores": [
+        _STEP + "jvp(model/mla/scores)/causal_attention_fwd/pallas_call",
+        _STEP + "transpose(jvp(jvp()))/checkpoint/rematted_computation/model/mla/scores/"
+        "causal_attention_fwd/pallas_call",
+        _STEP + "transpose(jvp(jvp()))/checkpoint/model/mla/scores/causal_attention_bwd/pallas_call",
+        _STEP + "jvp()/while/body/closed_call/model/mla/scores/causal_attention_fwd/pallas_call",
+        _STEP + "transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/"
+        "model/mla/scores/causal_attention_fwd/pallas_call",
+        _STEP + "transpose(jvp())/while/body/closed_call/checkpoint/model/mla/scores/"
+        "causal_attention_bwd/pallas_call",
+    ],
+    "model/mtp/mla/scores": [
+        _STEP + "jvp(model/mtp/mla/scores)/causal_attention_fwd/pallas_call",
+        _STEP + "transpose(jvp(jvp()))/checkpoint/rematted_computation/model/mtp/mla/scores/"
+        "causal_attention_fwd/pallas_call",
+        _STEP + "transpose(jvp(jvp()))/checkpoint/model/mtp/mla/scores/causal_attention_bwd/pallas_call",
+    ],
+}
+
+
+@pytest.mark.parametrize("scope", sorted(KERNEL_OP_NAMES))
+def test_a_named_kernels_custom_call_lands_in_the_scope_it_was_called_in(scope):
+    for op_name in KERNEL_OP_NAMES[scope]:
+        assert scope_of(op_name) == scope, op_name
+    # a kernel called under a jitted wrapper (ops/pull_push.py) and a primitive that
+    # merely follows a scope keep every level
+    assert scope_of("jit(superstep)/pull/table_gather/jit(pull_rows_pallas)/pallas_call") \
+        == "pull/table_gather"
+    assert scope_of("jit(superstep)/model/mla/scores/causal_attention_fwd/mul") \
+        == "model/mla/scores/causal_attention_fwd"
+
+
 def test_the_ctr_supersteps_scopes_are_what_they_were(trained):
     names = re.findall(r'op_name="([^"]*)"', trained["text"])
     assert len(names) > 100
