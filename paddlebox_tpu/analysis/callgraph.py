@@ -20,7 +20,9 @@ The pass resolves, over the FULL scanned module set:
     * ``obj.m()``    -> method ``m`` ONLY when exactly one class in the
                         scanned set defines it (unique-name resolution;
                         ambiguous names like ``get``/``close`` would
-                        overlink the graph into uselessness);
+                        overlink the graph into uselessness), and never
+                        when ``obj`` is a name the caller's module bound
+                        with ``import`` (``json.load`` is no method);
 
 - *thread entry points*: each ``threading.Thread(target=X)`` and
   ``executor.submit(X, ...)`` creation site mints a distinct thread label
@@ -142,6 +144,16 @@ class CallGraph:
         self._module_defs: Dict[Tuple[str, str], List[FuncNode]] = {}
         self._methods: Dict[Tuple[str, str], List[FuncNode]] = {}  # (cls, name)
         self._by_name: Dict[str, List[FuncNode]] = {}
+        # module path -> names bound by ``import x`` / ``import x.y as z``
+        self._imported: Dict[str, Set[str]] = {
+            ctx.path: {
+                (a.asname or a.name).split(".")[0]
+                for node in ast.walk(ctx.tree)
+                if isinstance(node, ast.Import)
+                for a in node.names
+            }
+            for ctx in self.modules
+        }
         for f in self.funcs:
             if f.cls is None and f.host is None:
                 self._module_defs.setdefault((f.module, f.name), []).append(f)
@@ -162,8 +174,11 @@ class CallGraph:
         if isinstance(fn, ast.Name):
             return self._resolve_name(caller, fn.id)
         if isinstance(fn, ast.Attribute):
-            if isinstance(fn.value, ast.Name) and fn.value.id in ("self", "cls"):
-                return self._resolve_method(caller.cls, fn.attr)
+            if isinstance(fn.value, ast.Name):
+                if fn.value.id in ("self", "cls"):
+                    return self._resolve_method(caller.cls, fn.attr)
+                if fn.value.id in self._imported.get(caller.module, ()):
+                    return []
             return self._resolve_unique_method(fn.attr)
         return []
 

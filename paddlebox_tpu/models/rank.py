@@ -5,8 +5,8 @@ The reference's join phase feeds pv-merged batches whose ``rank_offset``
 encodes each ad's rank and its peers' positions; RankAttention mixes
 features across the pv before the final logit (box_wrapper.h RankAttention
 + rank_attention_op.cu). Here that is one wrapper usable around any base
-model with ``init``/``apply`` (DeepFM, WideDeep, ...), consumed by
-bench.py's PBOX_BENCH_PV mode and the pv-phase tests.
+model with ``init``/``apply`` (DeepFM, WideDeep, ...), consumed by the
+pv-phase tests.
 """
 
 from __future__ import annotations

@@ -17,34 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from paddlebox_tpu import config
 from paddlebox_tpu.table.optimizers import SparseOptimizerConfig
 from paddlebox_tpu.table.value_layout import FeatureType, ValueLayout
-
-
-def _impl_for(op: str, table: jnp.ndarray, n_idx: int, unique_rows: bool = True) -> str:
-    """KernelPlan lookup for one op instance (ops/kernel_plan.py): per-shape
-    pallas-vs-native routing, resolved at trace time from the committed plan
-    artifact (or the builtin defaults, which honor ``use_pallas_sparse``)."""
-    from paddlebox_tpu.ops.kernel_plan import current_backend, get_plan
-
-    return get_plan().select(
-        op,
-        current_backend(),
-        table.shape[0],
-        table.shape[1],
-        n_idx,
-        unique_rows=unique_rows,
-    )
-
-
-def _gather_rows(table: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
-    """Row gather: XLA take, or the Pallas row-DMA kernel when planned."""
-    if _impl_for("pull", table, rows.shape[0]) == "pallas":
-        from paddlebox_tpu.ops.pallas_kernels import pull_rows_pallas
-
-        return pull_rows_pallas(table, rows)
-    return jnp.take(table, rows, axis=0)
 
 
 def embedx_active_mask(
@@ -84,7 +58,7 @@ def pull_sparse_rows(
     (box_wrapper.cu:54-63) — or, on VARIABLE layouts, per-column as the
     graded dims unlock.
     """
-    picked = _gather_rows(table, rows)  # [U, width]
+    picked = jnp.take(table, rows, axis=0)  # [U, width]
     cvm_block = picked[:, : layout.cvm_offset]
     embedx = picked[:, layout.embedx_col : layout.embedx_col + layout.embedx_dim]
     active = embedx_active_mask(layout, picked[:, layout.SHOW], embedx_threshold)
@@ -108,7 +82,7 @@ def pull_sparse_rows_extended(
     """
     if layout.expand_dim == 0:
         raise ValueError("layout has no expand block (expand_embed_dim == 0)")
-    picked = _gather_rows(table, rows)
+    picked = jnp.take(table, rows, axis=0)
     cvm_block = picked[:, : layout.cvm_offset]
     show = picked[:, layout.SHOW]
     # embedx follows the layout's gating (incl. VARIABLE graded dims);
@@ -141,19 +115,11 @@ def push_sparse_rows(
     """
     # the same gather as the pull's: XLA keeps one of the two
     with jax.named_scope("table_gather"):
-        old = _gather_rows(table, rows)  # [U, width]
+        old = jnp.take(table, rows, axis=0)  # [U, width]
     new_rows = sparse_update_rows(
         old, grads, show_counts, clk_counts, layout, opt, lr_scale
     )
-    # dedup'd rows are unique (pad-row repeats write identical contents), so
-    # the pallas per-row SET == scatter-add of deltas; without dedup the
-    # plan clamps to native (unique_rows=False makes pallas ineligible)
-    unique_rows = bool(config.get_flag("enable_pullpush_dedup_keys"))
     with jax.named_scope("table_scatter"):
-        if _impl_for("push", table, rows.shape[0], unique_rows=unique_rows) == "pallas":
-            from paddlebox_tpu.ops.pallas_kernels import write_rows_pallas
-
-            return write_rows_pallas(table, rows, new_rows)
         # Scatter the *delta* with add-semantics: with host dedup rows are
         # unique and this equals a set; without dedup
         # (enable_pullpush_dedup_keys=0) a key occurring in several slots

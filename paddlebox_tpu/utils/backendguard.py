@@ -5,8 +5,8 @@ does not. There is nothing to outlast and nobody to hand the chip to: a
 probe child would be a second process asking for a chip the parent may
 already hold. So bring-up is ``jax.devices()`` in this process, and a
 platform that is not the one the caller needs is a typed error — never a
-switch to another platform. A measurement entry point (``chip_smoke.py``,
-``bench.py``) passes ``require="tpu"``; code that is correct on any
+switch to another platform. An entry point that cannot do without the chip
+passes ``require="tpu"``; code that is correct on any
 platform (the trainer supervisor, the CPU test suite) passes nothing and
 gets the device stamp for its records.
 """

@@ -18,7 +18,7 @@ the directory must not move between runs:
 program is cached (the pass boundary's small eager scatters included), and
 registers a ``jax.monitoring`` listener ONCE per process; hit/miss/request
 counters surface as ``compile_cache.*`` stats and through :func:`stats`,
-which ``chip_smoke.py`` and ``bench.py`` embed in their JSON so a cold run
+which ``chip_smoke.py`` and ``benchmark/run.py`` embed in their JSON so a cold run
 (hits == 0) and a warm run (hits > 0, shorter warm-up) are distinguishable.
 """
 

@@ -69,14 +69,6 @@ def _run_without_chip(cmd, cwd):
 
 
 @pytest.mark.slow
-def test_bench_exits_nonzero_without_a_chip():
-    proc = _run_without_chip([sys.executable, "bench.py"], REPO)
-    assert proc.returncode != 0
-    assert "tpu" in proc.stderr and "cpu" in proc.stderr
-    assert proc.stdout.strip() == ""  # no CPU number under a per-chip name
-
-
-@pytest.mark.slow
 def test_chip_smoke_fails_alone_in_a_directory(tmp_path):
     """The driver runs the script alone, without the program: it must fail
     there, and without a chip, and print no result either way."""

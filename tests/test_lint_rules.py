@@ -431,6 +431,30 @@ class TestRaceDetector:
         """)
         assert rule_findings(res, "THR006") == []
 
+    def test_a_call_on_an_imported_module_is_no_method_call(self, tmp_path):
+        # ``json.load`` in a worker must not link the thread to the one class
+        # of the scanned set that has a method ``load``
+        res = lint_source(tmp_path, """
+            import json
+            import threading
+
+            class Store:
+                def __init__(self):
+                    self.epoch = 0
+                    threading.Thread(target=read_meta, args=("m",)).start()
+
+                def load(self, path):
+                    self.epoch = 1
+
+            def read_meta(path):
+                with open(path) as f:
+                    return json.load(f)
+
+            def restore(store, path):
+                store.load(path)
+        """)
+        assert rule_findings(res, "THR006") == []
+
     def test_synchronized_by_annotation_is_quiet(self, tmp_path):
         # same two-thread _stage shape as the positive, but the init site
         # documents the non-lock mechanism — the annotation exempts it

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from paddlebox_tpu.ops import (
     cvm_transform,
@@ -11,6 +12,7 @@ from paddlebox_tpu.ops import (
     push_sparse_rows,
 )
 from paddlebox_tpu.table import SparseOptimizerConfig, ValueLayout
+from paddlebox_tpu.table.value_layout import FeatureType
 
 
 LAY = ValueLayout(embedx_dim=4)
@@ -244,3 +246,122 @@ def test_variable_locked_dims_never_trained():
     # g2 energy reflects only unlocked dims: row 0 accumulated half of row 1
     g2 = new[:, lay.embedx_g2_col] - old[:, lay.embedx_g2_col]
     assert abs(g2[0] - 0.5 * g2[1]) < 1e-6
+
+
+# ---- every feature type's pull and push against numpy ------------------------------
+
+THRESHOLD, SCALE = 10.0, 0.5
+ALL_OPT = SparseOptimizerConfig(embed_lr=0.3, embedx_lr=0.2, initial_g2sum=2.0,
+                                embedx_threshold=THRESHOLD, weight_bounds=0.8)
+
+
+def _np_active(lay: ValueLayout, show: np.ndarray) -> np.ndarray:
+    """[U, D] bool: a VARIABLE row unlocks its columns by quarters at doubling
+    thresholds, every other type the whole vector at the threshold."""
+    D = lay.embedx_dim
+    if lay.feature_type is FeatureType.VARIABLE:
+        need = THRESHOLD * 2.0 ** (np.arange(D) * 4 // D)
+        return show[:, None] >= need[None, :]
+    return np.repeat((show >= THRESHOLD)[:, None], D, axis=1)
+
+
+def _np_pull(lay, table, rows):
+    picked, co = table[rows], lay.cvm_offset
+    embedx = np.where(_np_active(lay, picked[:, lay.SHOW]), picked[:, co:co + lay.embedx_dim] * SCALE, 0.0)
+    return np.concatenate([picked[:, :co], embedx], axis=1)
+
+
+def _np_push(lay, table, rows, grads, show_c, clk_c, lr_scale, opt):
+    """Rows are distinct, so the scatter-add of the delta is a set."""
+    out, old = table.astype(np.float64), table[rows].astype(np.float64)
+    co, D, g = lay.cvm_offset, lay.embedx_dim, grads.astype(np.float64)
+    new = old.copy()
+    new[:, lay.SHOW] += show_c
+    new[:, lay.CLK] += clk_c
+    # one adagrad scalar over columns 2..cvm_offset: CONV's and PCOC's extras and embed_w
+    g2_e = old[:, lay.embed_g2_col] + (g[:, 2:co] ** 2).sum(axis=1)
+    step = opt.embed_lr * lr_scale * np.sqrt(opt.initial_g2sum / (opt.initial_g2sum + g2_e))
+    new[:, 2:co] = np.clip(old[:, 2:co] - step[:, None] * g[:, 2:co], -opt.weight_bounds, opt.weight_bounds)
+    # the embedx vector with its one shared g2 (mean energy), behind the pull's mask
+    gx = np.where(_np_active(lay, old[:, lay.SHOW]), g[:, co:co + D], 0.0)
+    g2_x = old[:, lay.embedx_g2_col] + (gx ** 2).mean(axis=1)
+    step = opt.embedx_lr * lr_scale * np.sqrt(opt.initial_g2sum / (opt.initial_g2sum + g2_x))
+    new[:, co:co + D] = np.clip(old[:, co:co + D] - step[:, None] * gx, -opt.weight_bounds, opt.weight_bounds)
+    new[:, lay.embed_g2_col], new[:, lay.embedx_g2_col] = g2_e, g2_x
+    out[rows] = new
+    return out
+
+
+@pytest.mark.parametrize("op", ["pull", "push"])
+@pytest.mark.parametrize("ft", list(FeatureType), ids=lambda ft: ft.value)
+def test_pull_and_push_match_numpy_for_every_feature_type(ft, op):
+    lay = ValueLayout(embedx_dim=8, feature_type=ft,
+                      expand_embed_dim=8 if ft is FeatureType.SHARE_EMBEDDING else 0)
+    assert lay.cvm_offset == {"conv": 4, "pcoc": 8, "share_embedding": 10}.get(ft.value, 3)
+    assert lay.width == lay.cvm_offset + 8 + 2 and lay.embedx_g2_col == lay.width - 1
+    rng = np.random.default_rng(31)
+    table = rng.uniform(-0.7, 0.7, (12, lay.width)).astype(np.float32)
+    # shows on both sides of the threshold and of each of VARIABLE's quarters
+    table[:, lay.SHOW] = [0, 9, 10, 19, 20, 39, 40, 79, 80, 200, 5, 50]
+    table[:, lay.CLK] = rng.integers(0, 5, 12)
+    table[:, lay.embed_g2_col:] = rng.uniform(0, 2, (12, 2))
+    rows = np.array([9, 0, 3, 4, 7, 8, 2, 11], np.int32)  # distinct; 1, 5, 6, 10 stay
+    if op == "pull":
+        got = pull_sparse_rows(jnp.asarray(table), jnp.asarray(rows), lay, THRESHOLD, SCALE)
+        assert got.shape == (len(rows), lay.pull_width)
+        np.testing.assert_allclose(got, _np_pull(lay, table, rows), rtol=1e-6)
+        return
+    grads = rng.normal(0, 1.5, (len(rows), lay.pull_width)).astype(np.float32)  # some steps reach the clip
+    show_c = rng.integers(1, 4, len(rows)).astype(np.float32)
+    clk_c = rng.integers(0, 2, len(rows)).astype(np.float32)
+    lr_scale = rng.uniform(0.5, 2.0, len(rows)).astype(np.float32)
+    grads[3], show_c[3], clk_c[3] = 0.0, 0.0, 0.0  # an all-zero record
+    got = np.asarray(push_sparse_rows(
+        jnp.asarray(table), jnp.asarray(rows), jnp.asarray(grads), jnp.asarray(show_c),
+        jnp.asarray(clk_c), lay, ALL_OPT, jnp.asarray(lr_scale)))
+    want = _np_push(lay, table, rows, grads, show_c, clk_c, lr_scale, ALL_OPT)
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+    np.testing.assert_array_equal(got[[1, 5, 6, 10]], table[[1, 5, 6, 10]])  # rows not pushed
+    np.testing.assert_allclose(got[rows[3]], table[rows[3]], rtol=0, atol=1e-7)  # the zero record: identity
+    assert (np.abs(want[rows, 2:lay.cvm_offset + 8]) == ALL_OPT.weight_bounds).any()  # the clip was reached
+    moved = np.abs(got[rows] - table[rows])[np.arange(len(rows)) != 3]
+    assert (moved[:, 2:lay.cvm_offset] > 0).all()  # every cvm extra and embed_w is trained
+
+
+# ---- the table's gather and scatter are XLA's, under their scopes, at any width -----
+
+def _primitives(jaxpr, prefix=""):
+    """(primitive, named scope) of every equation, through nested jits."""
+    for eqn in jaxpr.eqns:
+        scope = "/".join(s for s in (prefix, str(eqn.source_info.name_stack)) if s)
+        yield eqn.primitive.name, scope
+        for sub in eqn.params.values():
+            if hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):  # a ClosedJaxpr
+                yield from _primitives(sub.jaxpr, scope)
+
+
+@pytest.mark.parametrize("embedx,uniq", [
+    (16, 4096), (64, 4100),  # the CTR cells' rows: 21 and 69 columns
+    (123, 4096),             # 128 columns and U % 8 == 0: a lane-aligned row
+    (2048, 3000),            # the token cell's row
+])
+def test_table_ops_lower_to_xla_gather_and_scatter_at_every_width(embedx, uniq):
+    lay, opt = ValueLayout(embedx_dim=embedx), SparseOptimizerConfig()
+
+    def step(table, rows, grads, show, clk):
+        with jax.named_scope("pull"):
+            pulled = pull_sparse_rows(table, rows, lay, opt.embedx_threshold)
+        with jax.named_scope("push"):
+            return pulled, push_sparse_rows(table, rows, grads, show, clk, lay, opt)
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    args = (f32(8192, lay.width), jax.ShapeDtypeStruct((uniq,), jnp.int32),
+            f32(uniq, lay.pull_width), f32(uniq), f32(uniq))
+    text = jax.jit(step).lower(*args).as_text()
+    assert "custom_call" not in text
+    assert text.count('"stablehlo.scatter"') == 1
+    ops = list(_primitives(jax.make_jaxpr(step)(*args).jaxpr))
+    assert [o for o in ops if o[0].startswith(("gather", "scatter"))] == [
+        ("gather", "pull/table_gather"), ("gather", "push/table_gather"),
+        ("scatter-add", "push/table_scatter")]
+    assert not [o for o in ops if "call" in o[0]], ops  # no kernel, no callback

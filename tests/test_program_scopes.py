@@ -278,10 +278,10 @@ KERNEL_OP_NAMES = {
 def test_a_named_kernels_custom_call_lands_in_the_scope_it_was_called_in(scope):
     for op_name in KERNEL_OP_NAMES[scope]:
         assert scope_of(op_name) == scope, op_name
-    # a kernel called under a jitted wrapper (ops/pull_push.py) and a primitive that
-    # merely follows a scope keep every level
-    assert scope_of("jit(superstep)/pull/table_gather/jit(pull_rows_pallas)/pallas_call") \
-        == "pull/table_gather"
+    # an unnamed kernel called under a jitted wrapper and a primitive that merely
+    # follows a scope keep every level
+    assert scope_of("jit(superstep)/model/mla/scores/jit(causal_attention)/pallas_call") \
+        == "model/mla/scores"
     assert scope_of("jit(superstep)/model/mla/scores/causal_attention_fwd/mul") \
         == "model/mla/scores/causal_attention_fwd"
 

@@ -403,8 +403,8 @@ def test_resident_mesh_dense_features_match(tmp_path):
 
 def test_prepare_pass_prefreezes_shapes(tmp_path):
     """After prepare_pass over the full partition, train_pass must not grow
-    the pads or build a second superstep (the warm-start contract bench.py
-    relies on to keep compiles out of its timed region)."""
+    the pads or build a second superstep (the warm-start contract the
+    benchmark relies on to keep compiles out of its timed window)."""
     ds, tr, _ = _fresh(tmp_path)
     tr.prepare_pass(ds, n_batches=8)
     rp = tr._get_resident(ds)

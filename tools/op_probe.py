@@ -5,7 +5,6 @@ iterations, so per-op device time = (blocked wall - overhead) / K.
 
 from __future__ import annotations
 
-import json
 import os
 import sys
 import time
@@ -53,52 +52,8 @@ def sweep_point_names():
     """Addressable scatter-sweep probe points, in run order (``only=``
     runs one of them)."""
     return [f"w{w}" for w in SWEEP_WIDTHS] + [
-        "hints", "gather_set", "bf16", "pallas",
+        "hints", "gather_set", "bf16",
     ]
-
-
-SWEEP_ARTIFACT_VERSION = 1
-
-
-def _sweep_shape() -> dict:
-    return {"rows": ROWS, "u": U, "w": W}
-
-
-def load_sweep_artifact(path: str):
-    """Partial sweep artifact at ``path``, or None if absent/stale.
-
-    Stale = different schema version, probe shape, or backend: measured
-    points from a different experiment must not be "resumed" into this
-    one, so the sweep starts fresh (the old file is overwritten on the
-    first completed point)."""
-    import jax
-
-    try:
-        with open(path) as f:
-            art = json.load(f)
-    # absence/corruption probe: None (cache miss, re-sweep) IS the answer
-    # pbox-lint: disable=EXC007
-    except (OSError, ValueError):
-        return None
-    if (
-        art.get("version") != SWEEP_ARTIFACT_VERSION
-        or art.get("shape") != _sweep_shape()
-        or art.get("backend") != jax.default_backend()
-        or not isinstance(art.get("points"), dict)
-    ):
-        return None
-    return art
-
-
-def new_sweep_artifact() -> dict:
-    import jax
-
-    return {
-        "version": SWEEP_ARTIFACT_VERSION,
-        "shape": _sweep_shape(),
-        "backend": jax.default_backend(),
-        "points": {},
-    }
 
 
 def main():
@@ -106,12 +61,9 @@ def main():
         print("\n".join(sweep_point_names()))
         return
     only = None
-    artifact_path = None
     for a in sys.argv[1:]:
         if a.startswith("--scatter-sweep="):
             only = a.split("=", 1)[1]
-        if a.startswith("--sweep-artifact="):
-            artifact_path = a.split("=", 1)[1]
     if only is not None:
         # single-point mode: skip the baseline probes so the per-point
         # subprocess pays backend init + ONE probe, nothing else
@@ -119,9 +71,7 @@ def main():
             print(f"unknown sweep point {only!r}; known: "
                   + " ".join(sweep_point_names()), file=sys.stderr)
             sys.exit(2)
-        scatter_sweep(
-            np.random.default_rng(0), only=only, artifact_path=artifact_path
-        )
+        scatter_sweep(np.random.default_rng(0), only=only)
         return
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.standard_normal((ROWS, W)).astype(np.float32) * 0.01)
@@ -224,57 +174,19 @@ def main():
     timed_loop("ragged expand (scatter of starts + cumsum L)", ragged, (lens, jnp.float32(0)))
 
     if "--scatter-sweep" in sys.argv:
-        scatter_sweep(rng, artifact_path=artifact_path)
+        scatter_sweep(rng)
 
 
-def scatter_sweep(rng, only=None, artifact_path=None):
+def scatter_sweep(rng, only=None):
     """Candidate strategies against the ~16 ms scatter-add floor at
     U=131k/W=21 (VERDICT r4 item 5; box_wrapper.cu:31-456 PushCopy is the
     reference's hand-written answer to the same problem). Run on a HEALTHY
     chip; each row prints device ms/op. Interpretation notes inline.
 
-    ``only`` restricts the run to one point of :func:`sweep_point_names`.
-
-    ``artifact_path`` makes the sweep RESUMABLE: each finished point is
-    recorded (atomically) into a structured JSON artifact, and points
-    already present are skipped — so a sweep killed by a wedge/timeout
-    halfway keeps its measurements, and re-running finishes only the
-    remainder. tools/tune_kernels.py consumes this artifact."""
-    art = None
-    done = set()
-    if artifact_path is not None:
-        art = load_sweep_artifact(artifact_path) or new_sweep_artifact()
-        done = set(art["points"])
-
-    skip_printed = set()
+    ``only`` restricts the run to one point of :func:`sweep_point_names`."""
 
     def want(name):
-        if only is not None and only != name:
-            return False
-        if name in done:
-            if name not in skip_printed:
-                skip_printed.add(name)
-                print(f"{name:44s} skipped (already in {artifact_path})")
-            return False
-        return True
-
-    def finish(name, ms=None, skipped=None):
-        """Record one completed point and publish the artifact NOW — the
-        next point may be the one that wedges."""
-        if art is None:
-            return
-        entry = {
-            "finished_at": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())
-        }
-        if ms is not None:
-            entry["ms"] = round(float(ms), 3)
-        if skipped is not None:
-            entry["skipped"] = skipped
-        art["points"][name] = entry
-        from paddlebox_tpu.utils.fs import atomic_write
-
-        with atomic_write(artifact_path) as f:
-            json.dump(art, f, indent=1)
+        return only is None or only == name
 
     if only is None:
         print("\n--- scatter strategy sweep (U=131k unique rows) ---")
@@ -288,12 +200,11 @@ def scatter_sweep(rng, only=None, artifact_path=None):
             continue
         t = jnp.zeros((ROWS, w), jnp.float32)
         g = jnp.asarray(rng.standard_normal((U, w)).astype(np.float32))
-        dt = timed_loop(
+        timed_loop(
             f"scatter-add uniq sorted W={w:<3d}",
             lambda c, i: (c[0].at[rows_s].add(c[1] * 1e-6), c[1]),
             (t, g),
         )
-        finish(f"w{w}", ms=dt)
 
     if want("hints") or want("gather_set") or want("bf16"):
         t21 = jnp.zeros((ROWS, W), jnp.float32)
@@ -301,7 +212,7 @@ def scatter_sweep(rng, only=None, artifact_path=None):
 
     # sorted + hint combos at W=21 (hints measured no-op before; re-check)
     if want("hints"):
-        dt = timed_loop(
+        timed_loop(
             "scatter-add W=21 hints(sorted+unique)",
             lambda c, i: (
                 c[0].at[rows_s].add(
@@ -311,12 +222,11 @@ def scatter_sweep(rng, only=None, artifact_path=None):
             ),
             (t21, g21),
         )
-        finish("hints", ms=dt)
 
     # gather-modify-SET (unique rows): scatter with set semantics instead
     # of add — different lowering, sometimes different cost
     if want("gather_set"):
-        dt = timed_loop(
+        timed_loop(
             "gather+set W=21 (set semantics)",
             lambda c, i: (
                 c[0].at[rows_s].set(jnp.take(c[0], rows_s, axis=0) + c[1] * 1e-6),
@@ -324,12 +234,11 @@ def scatter_sweep(rng, only=None, artifact_path=None):
             ),
             (t21, g21),
         )
-        finish("gather_set", ms=dt)
 
     # bf16 update payload into an f32 table (half the update bytes; the
     # read-modify-write of the table itself is unchanged)
     if want("bf16"):
-        dt = timed_loop(
+        timed_loop(
             "scatter-add W=21 bf16 updates",
             lambda c, i: (
                 c[0].at[rows_s].add((c[1] * 1e-6).astype(jnp.bfloat16).astype(jnp.float32)),
@@ -337,30 +246,6 @@ def scatter_sweep(rng, only=None, artifact_path=None):
             ),
             (t21, g21),
         )
-        finish("bf16", ms=dt)
-
-    # Pallas per-row DMA set on a lane-aligned (W=128) table: the write
-    # path the flag-gated kernel family already implements — viable only
-    # if the padded table's HBM cost is acceptable
-    if want("pallas"):
-        try:
-            from paddlebox_tpu.ops.pallas_kernels import write_rows_pallas
-
-            if jax.default_backend() == "tpu":
-                t128 = jnp.zeros((ROWS, 128), jnp.float32)
-                g128 = jnp.asarray(rng.standard_normal((U, 128)).astype(np.float32))
-                dt = timed_loop(
-                    "pallas write_rows W=128 (set)",
-                    lambda c, i: (write_rows_pallas(c[0], rows_s, c[1]), c[1]),
-                    (t128, g128),
-                )
-                finish("pallas", ms=dt)
-            else:
-                print("pallas W=128 probe skipped: backend is not tpu")
-                finish("pallas", skipped="backend is not tpu")
-        except Exception as e:  # pragma: no cover
-            print(f"pallas W=128 probe skipped: {e}")
-            finish("pallas", skipped=str(e)[:200])
 
 
 if __name__ == "__main__":
