@@ -332,6 +332,17 @@ def build_device_batch(
     return batch
 
 
+def per_batch_metrics(mstack: Dict[str, jnp.ndarray]) -> tuple:
+    """A scan's stacked metrics ``{field: [K, ...]}`` as K dicts of one batch
+    each. Every superstep calls this on its own scan outputs before it
+    returns (K x fields static slices inside the program, microseconds), so
+    the host indexes a tuple between two dispatches and launches nothing:
+    an eager ``v[j]`` is a device program of its own, queued behind the
+    superstep in flight."""
+    k = len(next(iter(mstack.values())))
+    return tuple({f: v[j] for f, v in mstack.items()} for j in range(k))
+
+
 def make_resident_superstep(
     model_apply: Callable,
     dense_opt,
@@ -341,9 +352,9 @@ def make_resident_superstep(
 ) -> Callable:
     """Build ``superstep(state, idx_block [K, B]) -> (state, metrics[K])``.
 
-    One dispatch runs K full train steps via lax.scan; metrics come back
-    stacked along the scan axis. The per-step body is the classic
-    make_train_step — only batch assembly is resident."""
+    One dispatch runs K full train steps via lax.scan; metrics come back a
+    batch at a time, a tuple of K dicts (per_batch_metrics). The per-step
+    body is the classic make_train_step — only batch assembly is resident."""
     raw_step = make_train_step(model_apply, dense_opt, cfg, eval_mode=eval_mode)
     if cfg.sequence_len and not np.all(rp._key_counts == cfg.sequence_len):
         # the rows reach the model as [B, T, embedx]: a record of another
@@ -358,7 +369,8 @@ def make_resident_superstep(
         return raw_step(state, batch)
 
     def superstep(state, idx_block):
-        return jax.lax.scan(body, state, idx_block)
+        state, mstack = jax.lax.scan(body, state, idx_block)
+        return state, per_batch_metrics(mstack)
 
     return jax.jit(superstep, donate_argnums=(0,))
 
@@ -438,7 +450,8 @@ def make_resident_pv_superstep(
         return raw_step(state, batch)
 
     def superstep(state, pos_block):
-        return jax.lax.scan(body, state, pos_block)
+        state, mstack = jax.lax.scan(body, state, pos_block)
+        return state, per_batch_metrics(mstack)
 
     return jax.jit(superstep, donate_argnums=(0,))
 
@@ -521,7 +534,8 @@ def make_resident_pv_mesh_superstep(
             out_specs=(state_specs, metric_specs),
             check_vma=False,
         )
-        return mapped(state, pos_block, arrs, pv_idx, pv_ro, pv_w)
+        state, mstack = mapped(state, pos_block, arrs, pv_idx, pv_ro, pv_w)
+        return state, per_batch_metrics(mstack)
 
     jitted = _jax.jit(superstep, donate_argnums=(0,))
 
@@ -763,7 +777,8 @@ def make_resident_mesh_superstep(
             out_specs=(state_specs, metric_specs),
             check_vma=False,
         )
-        return mapped(state, idx_block, arrs)
+        state, mstack = mapped(state, idx_block, arrs)
+        return state, per_batch_metrics(mstack)
 
     jitted = _jax.jit(superstep, donate_argnums=(0,))
 

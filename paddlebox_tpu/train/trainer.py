@@ -879,8 +879,13 @@ class CTRTrainer:
         """Superstep dispatch: K batches per lax.scan call, index-only feed.
 
         Yields the same (batch_index, metrics, aux) stream as the classic
-        stepper — metrics are lazy scan-axis slices of the stacked chunk
-        output, so unconsumed fields never leave the device.
+        stepper. The superstep program hands its K batches' metrics back
+        itself, one dict of device arrays a batch (per_batch_metrics), so
+        batch j's metrics are ``per_batch[j]``: nothing is sliced, launched
+        or read on the host between two dispatches, and unconsumed fields
+        never leave the device. After dispatching superstep c the loop
+        waits for superstep c - 1 and only then hands out c's batches: the
+        device always holds the next program while the host consumes.
 
         ``use_pv`` switches to the join-phase tier: batches come from the
         pass's PvPlan (already resident on device), so the per-chunk feed is
@@ -942,7 +947,10 @@ class CTRTrainer:
                 )
                 if use_pv:
                     # the batches live on device already — feed POSITIONS
-                    idx_dev = jnp.arange(c0, c0 + len(chunk), dtype=jnp.int32)
+                    # (a transfer: jnp.arange would be a program of its own)
+                    idx_dev = jnp.asarray(
+                        np.arange(c0, c0 + len(chunk), dtype=np.int32)
+                    )
                 elif self.plan is not None:
                     # [K, B_local] -> [K, n_local, b]: record r -> device
                     # r // b, the same ins // b mapping the sharded packer
@@ -966,7 +974,7 @@ class CTRTrainer:
                     avals = jax.tree.map(_aval_of, (holder["state"], idx_dev))
                 t_disp.start()
                 with PROFILER.record_event("superstep_dispatch", "pass"):
-                    holder["state"], mstack = sstep(holder["state"], idx_dev)
+                    holder["state"], per_batch = sstep(holder["state"], idx_dev)
                 t_disp.pause()
                 if avals is not None:
                     self._sstep_recorded.add((id(sstep), idx_dev.shape))
@@ -974,10 +982,10 @@ class CTRTrainer:
                 if profile:
                     t_dev.start()
                     with PROFILER.record_event("device_superstep", "device"):
-                        jax.block_until_ready(mstack["loss"])
+                        jax.block_until_ready(per_batch[-1]["loss"])
                     t_dev.pause()
                 else:
-                    inflight.append(mstack["loss"])
+                    inflight.append(per_batch[-1]["loss"])
                     if len(inflight) > 1:  # double-buffer supersteps
                         t_dev.start()
                         with PROFILER.record_event("superstep_wait", "device"):
@@ -986,8 +994,7 @@ class CTRTrainer:
                 chunk_ids = ids_fut.result() if ids_fut is not None else None
                 # the consumer's work on each batch runs inside this span
                 with PROFILER.record_event("superstep_consume", "pass"):
-                    for j, idx in enumerate(chunk):
-                        m = {k: v[j] for k, v in mstack.items()}
+                    for j, (idx, m) in enumerate(zip(chunk, per_batch)):
                         aux = {}
                         if has_meta:
                             aux["cmatch"] = store.cmatch[idx]

@@ -56,7 +56,7 @@ def _schema():
     )
 
 
-def _fresh(tmp_path, batch_size=16, mesh=None, n_shards=2):
+def _fresh(tmp_path, batch_size=16, mesh=None, n_shards=2, check_nan=False):
     layout = ValueLayout(embedx_dim=4)
     table = HostSparseTable(
         layout, SparseOptimizerConfig(embedx_threshold=0.0),
@@ -78,7 +78,7 @@ def _fresh(tmp_path, batch_size=16, mesh=None, n_shards=2):
         num_slots=S, batch_size=per_dev, layout=layout,
         sparse_opt=SparseOptimizerConfig(embedx_threshold=0.0),
         auc_buckets=1000, model_takes_rank_offset=True,
-        axis_name=mesh.axis if mesh is not None else None,
+        axis_name=mesh.axis if mesh is not None else None, check_nan=check_nan,
     )
     tr = CTRTrainer(model, cfg, dense_opt=optax.adam(1e-2), plan=mesh)
     tr.init_params(jax.random.PRNGKey(0))
