@@ -5,6 +5,7 @@ from paddlebox_tpu.models.wide_deep import WideDeep, DCN
 from paddlebox_tpu.models.mmoe import MMoE, task_head
 from paddlebox_tpu.models.rank import RankDeepFM
 from paddlebox_tpu.models.glm_moe_lite import GlmMoeLite, GlmMoeLiteConfig
+from paddlebox_tpu.models.afmoe import Afmoe, AfmoeConfig
 
 __all__ = [
     "mlp_init",
@@ -20,4 +21,6 @@ __all__ = [
     "RankDeepFM",
     "GlmMoeLite",
     "GlmMoeLiteConfig",
+    "Afmoe",
+    "AfmoeConfig",
 ]

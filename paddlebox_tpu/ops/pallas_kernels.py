@@ -1,8 +1,10 @@
 """Pallas TPU kernels on a cell's path: the fused causal attention of
-``models/glm_moe_lite.py`` (the token cell, since PR 29).
+``models/glm_moe_lite.py`` (the token cell, since PR 29) and of
+``models/afmoe.py`` (grouped-query heads, window and full layers, since PR 33).
 
 A kernel lives here when a call site chooses it from what it can observe
-(backend and shapes: ``models/glm_moe_lite.py::fused_scores``), its XLA form
+(backend and shapes: ``models/glm_moe_lite.py::fused_scores``,
+``models/afmoe.py::fused_scores``), its XLA form
 stays as every other backend's path and as its oracle
 (``tests/test_fused_attention.py``), a counter says which form was lowered,
 and a benchmark cell runs it. The table gather and scatter of
@@ -35,6 +37,16 @@ LANE = 128  # Mosaic lane width
 # [B, T, H * D]: a head is a column block, so nothing is transposed on the way
 # in or out. Measured (v5e, PR 29, 2 x 4,096 x 20 heads of 256): forward 3.3 ms,
 # backward 6.2 ms, against 11.3 and 14.7 ms of XLA's blocked form.
+#
+# Two static generalisations (PR 33), both absent from the call that names
+# neither. ``group``: query head h reads key-value head h // group; k and v are
+# never expanded, and dk, dv are summed over a group's query heads in a float32
+# block that stays in VMEM for the whole key-value head. ``window``: key j is
+# visible to query i iff 0 <= i - j < window (a multiple of the tile); the
+# inner grid axis then runs over the ``band`` + 1 = window / tile + 1 tiles a
+# query tile can see and no further (key tile i - band .. i forward, query tile
+# j .. j + band backward): the tile on the band's edge is masked (it shows what
+# the diagonal tile hides), those past an end of the sequence run empty.
 
 _MASKED = -1e30  # what a score above the diagonal is set to (exp gives 0.0)
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -57,7 +69,7 @@ def _above_diagonal(blk: int, keys_first: bool):
     return key > query
 
 
-def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale):
+def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale, band):
     i, j = pl.program_id(2), pl.program_id(3)  # query tile, key tile
     blk, width = acc_sc.shape
 
@@ -67,11 +79,13 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_s
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    def step(diagonal: bool):
+    def step(diagonal: bool, edge: bool = False):
         s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
                                 preferred_element_type=jnp.float32) * scale
         if diagonal:
             s = jnp.where(_above_diagonal(blk, keys_first=False), _MASKED, s)
+        if edge:  # a whole band behind: the keys still inside it are those the diagonal hides
+            s = jnp.where(_above_diagonal(blk, keys_first=False), s, _MASKED)
         m_prev = m_sc[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -81,8 +95,14 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_s
         acc_sc[...] = acc_sc[...] * _lanes(alpha, width) + jnp.dot(
             p.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32)
 
-    pl.when(j < i)(lambda: step(False))
-    pl.when(j == i)(lambda: step(True))
+    if band is None:
+        pl.when(j < i)(lambda: step(False))
+        pl.when(j == i)(lambda: step(True))
+    else:  # the inner axis is the band's: key tile i - band .. i, those before the sequence empty
+        key = i - band + j
+        pl.when((j > 0) & (j < band) & (key >= 0))(lambda: step(False))
+        pl.when(j == band)(lambda: step(True))
+        pl.when((j == 0) & (key >= 0))(lambda: step(False, edge=True))
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -92,17 +112,24 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_s
 
 
 def _attention_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
-                          dq_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, scale):
+                          dq_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, scale, band, group):
     """Scores transposed, [keys, queries]: the rows' statistics are then rows
     of lanes, and four of the five products need no transposed operand."""
     j, i = pl.program_id(2), pl.program_id(3)  # key tile, query tile
     blk = q_ref.shape[0]
+    inner = i  # the inner axis' own index: at its end a key tile's dk, dv are complete
+    if group != 1:
+        first_of_group = pl.program_id(1) % group == 0
+    if band is not None:  # the inner axis is the band's: query tile j .. j + band
+        i = j + i
 
-    def step(diagonal: bool):
+    def step(diagonal: bool, edge: bool = False):
         q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
         st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
         if diagonal:
             st = jnp.where(_above_diagonal(blk, keys_first=True), _MASKED, st)
+        if edge:
+            st = jnp.where(_above_diagonal(blk, keys_first=True), st, _MASKED)
         pt = jnp.exp(st - lse_ref[...])
         dv = jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
         dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
@@ -116,7 +143,11 @@ def _attention_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
             dk_sc[...] += dk
             dv_sc[...] += dv
 
-        @pl.when(j == 0)  # every query tile meets key tile 0 first
+        if edge:  # a query tile a whole band on meets the tile on the band's edge first
+            dq_ref[rows, :] = dq
+            return
+
+        @pl.when(j == 0)  # every other query tile meets key tile 0 first
         def _():
             dq_ref[rows, :] = dq
 
@@ -124,27 +155,75 @@ def _attention_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         def _():
             dq_ref[rows, :] += dq
 
-    pl.when(i > j)(lambda: step(False))
-    pl.when(i == j)(lambda: step(True))
+    if band is None:
+        pl.when(i > j)(lambda: step(False))
+        pl.when(i == j)(lambda: step(True))
+    else:
+        n_q = dq_ref.shape[0] // blk
+        pl.when((i > j) & (i < j + band) & (i < n_q))(lambda: step(False))
+        pl.when(i == j)(lambda: step(True))
+        pl.when((i == j + band) & (i < n_q))(lambda: step(False, edge=True))
 
-    @pl.when(i == pl.num_programs(3) - 1)
+    @pl.when(inner == pl.num_programs(3) - 1)
     def _():
-        dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+        if group == 1:
+            dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+            return
+        # the key-value head's float32 dk, dv stay in VMEM while its query heads pass
+        keys = pl.ds(pl.multiple_of(j * blk, blk), blk)
+
+        @pl.when(first_of_group)
+        def _():
+            dk_ref[keys, :], dv_ref[keys, :] = dk_sc[...], dv_sc[...]
+
+        @pl.when(jnp.logical_not(first_of_group))
+        def _():
+            dk_ref[keys, :] += dk_sc[...]
+            dv_ref[keys, :] += dv_sc[...]
 
 
 def _flat(a):
     return a.reshape(*a.shape[:2], -1)  # [B, T, H, D] -> [B, T, H * D]
 
 
-def _attention_fwd(q, k, v, scale, blk, interpret):
+def _band(window, T: int, blk: int):
+    """Tiles between a query tile and the tile on its band's edge; None where
+    every earlier key is visible."""
+    if window is None or window >= T:
+        return None
+    if window % blk:
+        raise ValueError(f"window {window} is not a multiple of the tile {blk}")
+    return window // blk
+
+
+def _kv_head(group: int):
+    """Query head -> its key-value head (the identity, and no division traced, at group 1)."""
+    return (lambda h: h) if group == 1 else (lambda h: h // group)
+
+
+def _grouped_params(group: int):
+    if group == 1:
+        return _ATTENTION_PARAMS
+    # a key-value head's dk, dv gather over its query heads: the head axis is a sequence too
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_ATTENTION_PARAMS.vmem_limit_bytes)
+
+
+def _attention_fwd(q, k, v, scale, blk, interpret, group, window):
     B, T, H, D = q.shape
+    band, n, kv_head = _band(window, T, blk), T // blk, _kv_head(group)
     q_spec = pl.BlockSpec((None, blk, D), lambda b, h, i, j: (b, i, h))
-    # a skipped step (j > i) names the tile it already holds
-    kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, i, j: (b, jnp.minimum(j, i), h))
+    if band is None:
+        # a skipped step (j > i) names the tile it already holds
+        k_tile = lambda i, j: jnp.minimum(j, i)  # noqa: E731
+    else:  # a step before the sequence names the first tile the row needs
+        k_tile = lambda i, j: jnp.maximum(i - band + j, 0)  # noqa: E731
+    kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, i, j: (b, k_tile(i, j), kv_head(h)))
     o, lse = pl.pallas_call(
-        functools.partial(_attention_fwd_kernel, scale=scale),
-        grid=(B, H, T // blk, T // blk),
+        functools.partial(_attention_fwd_kernel, scale=scale, band=band),
+        grid=(B, H, n, n if band is None else band + 1),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, pl.BlockSpec((None, None, 1, blk), lambda b, h, i, j: (b, h, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((B, T, H * D), jnp.float32),
@@ -156,44 +235,58 @@ def _attention_fwd(q, k, v, scale, blk, interpret):
     return o.reshape(B, T, H, D), lse
 
 
-def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret):
+def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret, group, window):
     B, T, H, D = q.shape
+    band, n, kv_head = _band(window, T, blk), T // blk, _kv_head(group)
     # the row term of the softmax's transpose, sum(dp * p) = sum(do * o), from the float32 output
     di = jnp.sum(do.astype(jnp.float32) * o, axis=-1).transpose(0, 2, 1).reshape(B, H, 1, T)
-    # a skipped step (i < j) names the tiles the diagonal step is about to need
-    q_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, jnp.maximum(i, j), h))
-    row_spec = pl.BlockSpec((None, None, 1, blk), lambda b, h, j, i: (b, h, 0, jnp.maximum(i, j)))
-    kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, j, h))
+    if band is None:
+        # a skipped step (i < j) names the tiles the diagonal step is about to need
+        q_tile = lambda j, i: jnp.maximum(i, j)  # noqa: E731
+    else:  # a step past the sequence names the tiles it already holds
+        q_tile = lambda j, i: jnp.minimum(j + i, n - 1)  # noqa: E731
+    q_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, q_tile(j, i), h))
+    row_spec = pl.BlockSpec((None, None, 1, blk), lambda b, h, j, i: (b, h, 0, q_tile(j, i)))
+    kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, j, kv_head(h)))
+    if group == 1:
+        dkv_spec, dkv_dtypes = kv_spec, (k.dtype, v.dtype)
+    else:  # float32 and whole, resident for the key-value head's ``group`` query heads
+        dkv_spec = pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, kv_head(h)))
+        dkv_dtypes = (jnp.float32, jnp.float32)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_attention_bwd_kernel, scale=scale),
-        grid=(B, H, T // blk, T // blk),
+        functools.partial(_attention_bwd_kernel, scale=scale, band=band, group=group),
+        grid=(B, H, n, n if band is None else band + 1),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, h)), kv_spec, kv_spec],
+        out_specs=[pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, h)), dkv_spec, dkv_spec],
         out_shape=[jax.ShapeDtypeStruct((B, T, H * D), jnp.float32),
-                   jax.ShapeDtypeStruct((B, T, H * D), k.dtype),
-                   jax.ShapeDtypeStruct((B, T, H * D), v.dtype)],
+                   jax.ShapeDtypeStruct((B, T, H // group * D), dkv_dtypes[0]),
+                   jax.ShapeDtypeStruct((B, T, H // group * D), dkv_dtypes[1])],
         scratch_shapes=[pltpu.VMEM((blk, D), jnp.float32), pltpu.VMEM((blk, D), jnp.float32)],
-        compiler_params=_ATTENTION_PARAMS, interpret=interpret, name="causal_attention_bwd",
+        compiler_params=_grouped_params(group), interpret=interpret, name="causal_attention_bwd",
     )(_flat(q), _flat(k), _flat(v), _flat(do.astype(q.dtype)), lse, di)
-    return dq.astype(q.dtype).reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    return (dq.astype(q.dtype).reshape(q.shape), dk.astype(k.dtype).reshape(k.shape),
+            dv.astype(v.dtype).reshape(v.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def causal_attention(q, k, v, scale: float, block: int, interpret: bool = False):
-    """q, k, v [B, T, H, D] bfloat16 -> softmax(q k^T * scale, causal) v
-    [B, T, H, D] float32, in tiles of ``block`` queries by ``block`` keys. T a
-    multiple of ``block``, ``block`` and D multiples of 128. The cotangents of
-    q, k and v come back in their dtype."""
-    return _attention_fwd(q, k, v, scale, block, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def causal_attention(q, k, v, scale: float, block: int, interpret: bool = False,
+                     group: int = 1, window=None):
+    """q [B, T, H, D], k, v [B, T, H / group, D] bfloat16 -> softmax(q k^T *
+    scale, causal) v [B, T, H, D] float32, in tiles of ``block`` queries by
+    ``block`` keys; query head h attends key-value head h // ``group``, and
+    with a ``window`` only to the keys less than ``window`` behind it. T and
+    ``window`` multiples of ``block``, ``block`` and D multiples of 128. The
+    cotangents of q, k and v come back in their dtype."""
+    return _attention_fwd(q, k, v, scale, block, interpret, group, window)[0]
 
 
-def _causal_attention_fwd(q, k, v, scale, block, interpret):
-    o, lse = _attention_fwd(q, k, v, scale, block, interpret)
+def _causal_attention_fwd(q, k, v, scale, block, interpret, group, window):
+    o, lse = _attention_fwd(q, k, v, scale, block, interpret, group, window)
     return o, (q, k, v, o, lse)
 
 
-def _causal_attention_bwd(scale, block, interpret, res, do):
-    return _attention_bwd(*res, do, scale, block, interpret)
+def _causal_attention_bwd(scale, block, interpret, group, window, res, do):
+    return _attention_bwd(*res, do, scale, block, interpret, group, window)
 
 
 causal_attention.defvjp(_causal_attention_fwd, _causal_attention_bwd)

@@ -3,9 +3,14 @@ against the blocked form it replaces on a TPU (``models/glm_moe_lite.py::
 _attend_block``, which stays as every other backend's path and is the oracle
 here): interpret mode on the CPU at a small tiled shape, the rule that chooses
 between the two, its counters, and the kernels compiled at the token cell's
-shapes for a described v5e."""
+shapes for a described v5e. Since PR 33 also with grouped-query heads and a
+window (``models/afmoe.py``), against that model's blocked form, and GLM's call
+held to the kernel it had before."""
 
 from __future__ import annotations
+
+import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -13,8 +18,9 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from paddlebox_tpu.models import afmoe  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
-from paddlebox_tpu.models import GlmMoeLite, GlmMoeLiteConfig  # noqa: E402
+from paddlebox_tpu.models import Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig  # noqa: E402
 from paddlebox_tpu.ops.pallas_kernels import causal_attention  # noqa: E402
 from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
 
@@ -134,6 +140,139 @@ def test_mla_through_the_kernel_agrees_with_mla_through_the_blocks(monkeypatch):
     assert _rel(dgot, dwant) < 1e-2
 
 
+# ---- grouped-query heads and a window (PR 33) ---------------------------------------
+
+WINDOW, GROUP = 256, 8
+
+
+def _grouped(T: int):
+    ks = jax.random.split(jax.random.PRNGKey(33 + T), 4)
+    q = jax.random.normal(ks[0], (1, T, GROUP, D)).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(a, (1, T, 1, D)).astype(jnp.bfloat16) for a in ks[1:3])
+    return q, k, v, jax.random.normal(ks[3], (1, T, GROUP, D))
+
+
+def _blocked_grouped(q, k, v, window):
+    return jnp.concatenate([afmoe._attend_block(q, k, v, i, 128, SCALE, GROUP, window)
+                            for i in range(0, q.shape[1], 128)], axis=1)
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+@pytest.mark.parametrize("T", [WINDOW, 2 * WINDOW, 4 * WINDOW])
+def test_group_8_and_a_window_match_the_blocked_form_output_and_gradients(block, T):
+    """Eight query heads over one key-value head, a band of ``WINDOW``: T = W
+    (the band hides nothing), 2W and 4W (tiles on the edge, tiles skipped)."""
+    q, k, v, g = _grouped(T)
+    fused = lambda q, k, v: causal_attention(q, k, v, SCALE, block, True, GROUP, WINDOW)  # noqa: E731
+    want_f = lambda q, k, v: _blocked_grouped(q, k, v, WINDOW)  # noqa: E731
+    o, want = fused(q, k, v), want_f(q, k, v)
+    assert o.dtype == jnp.float32 and o.shape == want.shape == q.shape
+    # p's rounding to bfloat16 (2**-9 an element) averages over a row's visible keys: at most 256
+    assert _rel(o, want) < 3e-3 and float(jnp.max(jnp.abs(o - want))) < 2e-2
+    grad = lambda f: jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)  # noqa: E731
+    for a, b in zip(grad(fused), grad(want_f)):
+        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
+        assert _rel(a, b) < 8e-3  # dk, dv are sums over the group's 8 heads, each side rounds once
+    if T > WINDOW:  # and the window is there: full causal is another function
+        assert _rel(o, _blocked_grouped(q, k, v, None)) > 0.05
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+def test_a_key_a_window_behind_leaves_the_output_bit_equal_and_one_nearer_does_not(block):
+    q, k, v, _ = _grouped(4 * WINDOW)
+    at = 130
+    k2, v2 = k.at[:, at].set(k[:, at] * -3 + 1), v.at[:, at].set(v[:, at] + 7)
+    o, o2 = (np.asarray(causal_attention(q, a, b, SCALE, block, True, GROUP, WINDOW))
+             for a, b in ((k, v), (k2, v2)))
+    same = np.all(o == o2, axis=(0, 2, 3))  # by query position
+    assert same[:at].all() and not same[at:at + WINDOW].any()  # i - j = 0 .. W - 1: seen
+    assert same[at + WINDOW:].all()  # i - j >= W: not seen
+
+
+def test_glms_call_lowers_to_the_kernel_it_had_before_group_and_window():
+    """``group`` 1 and no window trace to the jaxpr of the parent commit's
+    kernel pair (PR 32's ``ops/pallas_kernels.py``: kernel bodies, grids, block
+    index maps), source locations aside. The digest was taken from that commit
+    with these lines; a change to the kernel GLM's cell runs has to change it."""
+    x = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
+    f = lambda q, k, v: jnp.sum(causal_attention(q, k, v, 128 ** -0.5, 128, True))  # noqa: E731
+    text = str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(x, x, x))
+    digest = hashlib.sha256(re.sub(r"\S+\.py:\d+", "", text).encode()).hexdigest()
+    assert digest == "08284859e123d045f642ba5a667039d8c6900aa70022fd0b8d687eda077c7d61"
+
+
+def test_no_group_and_no_window_are_the_call_that_names_neither(qkvg):
+    q, k, v, g = qkvg
+    run = lambda *a: jax.value_and_grad(  # noqa: E731
+        lambda q, k, v: jnp.sum(causal_attention(q, k, v, SCALE, 128, True, *a) * g),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.tree.leaves(run())
+    for args in ((1, None), (1, T), (1, 4 * T)):  # a window no shorter than the record hides nothing
+        assert all(np.array_equal(a, b) for a, b in zip(jax.tree.leaves(run(*args)), want))
+
+
+@pytest.mark.parametrize("backend,t,d,block,window,fused", [
+    ("tpu", 8192, 128, 512, 2048, True),    # the Trinity cell's window layers
+    ("tpu", 8192, 128, 512, None, True),    # and its full layer
+    ("cpu", 8192, 128, 512, 2048, False),   # tier-1, whatever the shape
+    ("tpu", 8192, 128, 512, 2000, False),   # a window the tiles do not divide
+    ("tpu", 1024, 128, 512, 2048, True),    # a window longer than the record: full causal
+    ("tpu", 32, 16, 8, 16, False),          # the toy cell's widths
+    ("tpu", 8192, 128, 64, 2048, False),    # a query block the kernel does not tile
+])
+def test_afmoes_path_is_chosen_from_backend_and_shapes(backend, t, d, block, window, fused):
+    assert afmoe.fused_scores(backend, t, d, block, window) is fused
+
+
+AF_TILED = AfmoeConfig(hidden_size=64, num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+                       sliding_window=128, seq_len=256, attn_block=128)
+
+
+def test_afmoes_call_sites_are_counted_by_kind_under_the_path_they_were_lowered_to(monkeypatch):
+    names = ("model.attn.fused_window_scores", "model.attn.fused_full_scores",
+             "model.attn.blocked_scores")
+    stats = lambda: tuple(STAT_GET(n) for n in names)  # noqa: E731
+    c = AF_TILED
+    p = Afmoe(c)._attn_init(jax.random.PRNGKey(0))
+    x, ln = jnp.zeros((1, c.seq_len, c.hidden_size)), jnp.ones((c.hidden_size,))
+    rope = glm.rope_tables(c.seq_len, c.head_dim, c.rope_theta)
+    trace = lambda s: str(jax.make_jaxpr(  # noqa: E731
+        lambda p, x, s=s: afmoe.attention(p, x, ln, ln, c, rope, s))(p, x))
+    w0, f0, b0 = stats()
+    assert "pallas_call" not in trace(True)  # the CPU: the blocked form, at any shape
+    assert stats() == (w0, f0, b0 + 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert trace(True).count("pallas_call") == 1 and stats() == (w0 + 1, f0, b0 + 1)
+    assert trace(False).count("pallas_call") == 1 and stats() == (w0 + 1, f0 + 1, b0 + 1)
+    # a step told its kind holds both call sites, one kernel each
+    text = str(jax.make_jaxpr(lambda p, x, s: afmoe.attention(p, x, ln, ln, c, rope, s))(
+        p, x, jnp.asarray(True)))
+    assert text.count("pallas_call") == 2 and stats() == (w0 + 2, f0 + 2, b0 + 1)
+
+
+@pytest.mark.parametrize("sliding", [True, False])
+def test_afmoe_attention_through_the_kernel_agrees_with_it_through_the_blocks(monkeypatch, sliding):
+    """The whole block both ways (the kernel interpreted): the layout in and
+    out of the kernel, the group, the window, the scale."""
+    c = AfmoeConfig(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+                    sliding_window=128, seq_len=384, attn_block=128)
+    p = Afmoe(c)._attn_init(jax.random.PRNGKey(1))
+    p = jax.tree.map(lambda a: a * 20 if a.ndim == 2 else a, p)  # scores of order 1, not 1e-3
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, c.seq_len, c.hidden_size))
+    ln = jnp.ones((c.hidden_size,))
+    rope = glm.rope_tables(c.seq_len, c.head_dim, c.rope_theta)
+    run = lambda: jax.value_and_grad(lambda x: jnp.sum(  # noqa: E731
+        afmoe.attention(p, x, ln, ln, c, rope, sliding) ** 2))(x)
+    want, dwant = run()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(afmoe, "causal_attention",
+                        lambda q, k, v, s, block, interpret, group, window: causal_attention(
+                            q, k, v, s, block, True, group, window))
+    got, dgot = run()
+    assert float(got) == pytest.approx(float(want), rel=1e-3)
+    assert _rel(dgot, dwant) < 1e-2
+
+
 # ---- compiled for the chip, without the chip ---------------------------------------
 
 @pytest.fixture(scope="module")
@@ -166,3 +305,28 @@ def test_both_kernels_compile_for_a_v5e_at_the_token_cells_shapes(one_chip):
     assert len(kernels) == 2 and set(kernels.values()) == {"model/mla/scores"}, kernels
     # no score block in HBM: the program's temporaries are the statistics and the row term
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 * 4096 * 20 * 256 * 4
+
+
+def test_grouped_window_and_full_kernels_compile_for_a_v5e_at_the_trinity_cells_shapes(one_chip):
+    """1 x 8,192 x 32 query heads over 4 key-value heads of 128, tiles of 512:
+    the window layers' pair (a band of 4 tiles) and the full layer's."""
+    from paddlebox_tpu.obs.program_scopes import scope_map
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32, sharding=one_chip)
+    for scope, window in (("model/attn/scores_window", 2048), ("model/attn/scores_full", None)):
+        def step(q, k, v, g, scope=scope, window=window):
+            with jax.named_scope(scope):
+                return jax.grad(lambda q, k, v: jnp.sum(
+                    causal_attention(q, k, v, 128 ** -0.5, 512, False, 8, window) * g),
+                    argnums=(0, 1, 2))(q, k, v)
+
+        compiled = jax.jit(step).lower(q, kv, kv, g).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        kernels = {n: s for n, s in scope_map(text).items() if "causal_attention" in n}
+        assert len(kernels) == 2 and set(kernels.values()) == {scope}, kernels
+        # no score block and no 8-fold k, v, dk or dv in HBM: the temporaries are the
+        # float32 output and dq, the statistics, the row term and dk, dv at 4 heads
+        assert compiled.memory_analysis().temp_size_in_bytes < 3 * 8192 * 32 * 128 * 4
