@@ -242,7 +242,13 @@ def group_layout(expert_of, G: int, R: int):
     every row (A = none), each block's expert, the number of blocks in use
     and the held experts' loads.
     The rows are enough for the worst case (every assignment held), the work
-    is by the blocks in use."""
+    is by the blocks in use.
+    The order of a block's rows: its expert's assignments first, ascending
+    (the sort is stable), the padding (A) after them; the rows past the blocks
+    in use are all padding. Assignments are numbered token by token and
+    ``top_k`` gives a token an expert once, so a block's real rows are
+    distinct tokens, ascending: ``_add_rows`` cuts a block on that
+    (``tests/test_moe_combine.py`` holds it)."""
     A = expert_of.shape[0]
     M = (-(-A // R) + G) * R
     counts = jnp.sum(expert_of[:, None] == jnp.arange(G)[None, :], axis=0, dtype=jnp.int32)
@@ -266,13 +272,38 @@ def _expert_block(xb, wg, wu, wd):
     return hg, hu, h, _mm(h, wd)
 
 
+COMBINE_ROWS = 1024  # the most rows one scatter-add joins to the token sum
+
+
+def _add_rows(acc, tb, rows):
+    """acc[tb[r]] += rows[r] over one block's rows (tb == N: padding,
+    dropped), ``COMBINE_ROWS`` rows a scatter-add, cut at static offsets
+    whatever the rows hold. A block's tokens are distinct (``group_layout``),
+    so no two of its rows meet and a token receives the one addition a block
+    that a whole block's scatter-add gave it, bit for bit; what the cut buys is
+    the price of a row: above 1,024 update rows the TPU compiler sorts a
+    scatter's indices and reads its updates through the permutation, four
+    times the time a row (PERF.md section 6, PR 34). The pieces a block took
+    are counted at trace time (``model.moe.combine_pieces`` over
+    ``model.moe.combine_calls``)."""
+    R = tb.shape[0]
+    STAT_ADD("model.moe.combine_calls")
+    STAT_ADD("model.moe.combine_pieces", -(-R // COMBINE_ROWS))
+    for r in range(0, R, COMBINE_ROWS):
+        acc = acc.at[tb[r:r + COMBINE_ROWS]].add(rows[r:r + COMBINE_ROWS], mode="drop")
+    return acc
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(8, 9))
 def grouped_experts(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R: int, scope: str):
     """y[t] = sum over the rows r of token t of gate[r] * expert(x[t]), the
     expert of row r being its block's. x [N, H]; wg, wu [G, H, I], wd
-    [G, I, H]; gate [M] float32; tok [M] the row's token (N = no token). One
-    pass over the ``n_blocks`` blocks in use: gather the block's tokens, the
-    expert's three products, scatter-add the weighted result."""
+    [G, I, H]; gate [M] float32; tok [M] the row's token (N = no token), in
+    ``group_layout``'s order: a block's real rows first, their tokens
+    distinct and ascending, its padding after. One pass over the ``n_blocks``
+    blocks in use: gather the block's tokens, the expert's three products,
+    add the weighted rows to their tokens (``_add_rows``, which depends on
+    that order: a token at most once a block; the backward's ``dx`` likewise)."""
     return _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope)[0]
 
 
@@ -291,7 +322,7 @@ def _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope):
         with jax.named_scope(f"{scope}/moe/experts"):
             yb = _expert_block(xb, wg[e], wu[e], wd[e])[3]
         with jax.named_scope(f"{scope}/moe/combine"):
-            return y.at[tb].add(yb * gb[:, None], mode="drop")
+            return _add_rows(y, tb, yb * gb[:, None])
 
     return lax.fori_loop(0, n_blocks, body, jnp.zeros((N, H), F32)), res
 
@@ -329,7 +360,7 @@ def _grouped_bwd(R, scope, res, dy):
             dxb = (jnp.dot(dhg, wgT[e], preferred_element_type=F32)
                    + jnp.dot(dhu, wuT[e], preferred_element_type=F32))
         with jax.named_scope(f"{scope}/moe/combine"):
-            dx = dx.at[tb].add(dxb, mode="drop")
+            dx = _add_rows(dx, tb, dxb)
             dgate = lax.dynamic_update_slice_in_dim(dgate, dgb, j * R, 0)
         return dx, dwg, dwu, dwd, dgate
 

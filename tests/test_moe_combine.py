@@ -1,0 +1,180 @@
+"""How a block's rows join the token sum (``models/glm_moe_lite.py``:
+``group_layout``, ``grouped_experts``, ``_add_rows``), both token models'
+expert layer.
+
+(a) ``routed_experts`` against a dense per-token sum, value and gradients,
+over block heights below, at and above ``COMBINE_ROWS``; (b) value and ``dx``
+bit for bit what one scatter-add of a whole block gave (the form PR 34
+deleted, kept here as the oracle); (c) the order of a block's rows, which the
+cut relies on; (d) the trace-time counters say how a call site was lowered.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
+from paddlebox_tpu.models import GlmMoeLiteConfig  # noqa: E402
+from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
+
+H, I = 16, 8
+P = glm.COMBINE_ROWS
+
+
+def _config(R: int, E: int, G: int, k: int, offset: int) -> GlmMoeLiteConfig:
+    return GlmMoeLiteConfig(hidden_size=H, moe_intermediate_size=I, n_routed_experts=E,
+                            num_experts_per_tok=k, experts_held=G, experts_offset=offset,
+                            expert_block=R)
+
+
+def _inputs(seed: int, N: int, E: int, G: int, k: int, never=None):
+    """Seeded weights of G held experts, N tokens, each routed to k distinct
+    experts of E (none to ``never``) with weights g, and a cotangent."""
+    rng = np.random.default_rng(seed)
+    w = lambda *s: jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)  # noqa: E731
+    p = {"gate": w(G, H, I), "up": w(G, H, I), "down": w(G, I, H)}
+    score = rng.random((N, E))
+    if never is not None:
+        score[:, never] = -1.0
+    idx = jnp.asarray(np.argsort(-score, axis=1)[:, :k], jnp.int32)
+    g = jnp.asarray(rng.random((N, k)) + 0.1, jnp.float32)
+    return p, w(N, H), idx, g, w(N, H)
+
+
+def _dense(p, x, idx, g, c):
+    """Every held expert over every token, weighed by what the token gave it."""
+    y = jnp.zeros_like(x)
+    for e in range(c.experts_held):
+        weight = jnp.sum(jnp.where(idx == e + c.experts_offset, g, 0.0), axis=1, keepdims=True)
+        y = y + glm.swiglu(jax.tree.map(lambda a: a[e], p), x) * weight  # noqa: B023
+    return y
+
+
+def _rel(a, b) -> float:
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+# ---- (a) against the dense sum ------------------------------------------------
+
+CASES = {
+    # name: (R, N, E, G, k, offset, never chosen)
+    "block_of_8": (8, 40, 6, 3, 2, 1, None),
+    "below_a_piece": (P // 2, 700, 4, 2, 2, 1, None),
+    "one_piece": (P, 1400, 4, 2, 2, 0, None),
+    "a_piece_and_eight_rows": (P + 8, 2 * P + 100, 2, 2, 1, 0, None),
+    "three_pieces_the_last_all_padding": (3 * P, 3 * P, 2, 2, 1, 0, None),
+    "an_expert_holds_nothing": (3 * P, 600, 4, 3, 2, 0, 1),
+    "every_assignment_held": (8, 40, 3, 3, 2, 0, None),
+    "every_assignment_held_tall": (P + P // 2, 2 * P, 2, 2, 2, 0, None),
+    "none_held": (8, 40, 6, 2, 2, 6, None),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_routed_experts_give_the_dense_sum_and_its_gradients(case):
+    R, N, E, G, k, offset, never = CASES[case]
+    c = _config(R, E, G, k, offset)
+    p, x, idx, g, dy = _inputs(list(CASES).index(case), N, E, G, k, never)
+
+    def loss(fn):
+        return lambda p, x, g: jnp.sum(fn(p, x, g) * dy)
+
+    def routed(p, x, g):
+        return glm.routed_experts(p, x, idx, g, c, "model")[0]
+
+    y, counts = jax.jit(lambda p, x, g: glm.routed_experts(p, x, idx, g, c, "model"))(p, x, g)
+    held = np.bincount(np.asarray(idx).ravel() - offset + E, minlength=2 * E)[E:E + G]
+    assert np.array_equal(np.asarray(counts), held)
+    want = _dense(p, x, idx, g, c)
+    got_grads = jax.jit(jax.grad(loss(routed), argnums=(0, 1, 2)))(p, x, g)
+    want_grads = jax.grad(loss(lambda p, x, g: _dense(p, x, idx, g, c)), argnums=(0, 1, 2))(p, x, g)
+    if case == "none_held":
+        assert held.sum() == 0 and not np.any(np.asarray(y))
+        assert not any(np.any(np.asarray(a)) for a in jax.tree.leaves(got_grads))
+        return
+    if case.startswith("every_assignment_held"):
+        assert held.sum() == N * k  # the worst case the rows are sized for
+    if case == "an_expert_holds_nothing":
+        assert held[never] == 0 and not np.any(np.asarray(got_grads[0]["down"][never]))
+    if case == "three_pieces_the_last_all_padding":
+        assert P < held.max() <= 2 * P  # real rows in two pieces of a block, none in the third
+    assert _rel(y, want) < 1e-5
+    for name, a, b in [("x", got_grads[1], want_grads[1]), ("g", got_grads[2], want_grads[2])] + [
+            (n, got_grads[0][n], want_grads[0][n]) for n in ("gate", "up", "down")]:
+        assert _rel(a, b) < 2e-5, name
+
+
+# ---- (b) bit for bit the whole block's scatter-add ------------------------------
+
+def _whole_block(acc, tb, rows):
+    """The combine PR 34 deleted: one scatter-add of all the block's rows."""
+    return acc.at[tb].add(rows, mode="drop")
+
+
+@pytest.mark.parametrize("R", [8, 3 * P])
+def test_y_and_dx_are_bit_for_bit_what_one_scatter_add_a_block_gave(R, monkeypatch):
+    N, E, G, k = (40, 6, 3, 2) if R == 8 else (3 * P, 2, 2, 1)
+    c = _config(R, E, G, k, 0)
+    p, x, idx, g, dy = _inputs(R, N, E, G, k)
+
+    def y_and_dx():
+        y, vjp = jax.vjp(lambda x: glm.routed_experts(p, x, idx, g, c, "model")[0], x)
+        return np.asarray(y), np.asarray(vjp(dy)[0])
+
+    got = y_and_dx()
+    monkeypatch.setattr(glm, "_add_rows", _whole_block)
+    want = y_and_dx()
+    assert np.any(want[0]) and np.any(want[1])
+    assert np.array_equal(got[0], want[0]) and np.array_equal(got[1], want[1])
+
+
+# ---- (c) the order of a block's rows ----------------------------------------------
+
+@pytest.mark.parametrize("seed", range(8))
+def test_a_blocks_real_rows_come_first_their_tokens_ascending_and_distinct(seed):
+    rng = np.random.default_rng(340 + seed)
+    N, E = int(rng.integers(4, 41)), int(rng.integers(2, 13))
+    k, R = int(rng.integers(1, min(E, 4) + 1)), int(rng.integers(2, 17))
+    G = int(rng.integers(1, E + 1))
+    offset = int(rng.integers(0, E - G + 1))
+    idx = np.argsort(rng.random((N, E)), axis=1)[:, :k]  # top_k: a token an expert once
+    local = idx.reshape(-1) - offset
+    expert_of = np.where((local >= 0) & (local < G), local, G)
+    A = N * k
+    src, blk, n_blocks, counts = (np.asarray(a) for a in glm.group_layout(jnp.asarray(expert_of), G, R))
+    n_blocks = int(n_blocks)
+    assert src.shape[0] == (-(-A // R) + G) * R  # rows for the worst case
+    assert np.array_equal(counts, np.bincount(expert_of, minlength=G + 1)[:G])
+    assert n_blocks == int(np.sum(-(-counts // R)))
+    seen = []
+    for j in range(n_blocks):
+        rows = src[j * R:(j + 1) * R]
+        n_real = int(np.sum(rows < A))
+        assert n_real > 0 and np.all(rows[:n_real] < A) and np.all(rows[n_real:] == A)  # padding after
+        assert np.all(expert_of[rows[:n_real]] == blk[j])  # one expert a block
+        assert np.all(np.diff(rows[:n_real] // k) > 0)  # tokens strictly ascending: none twice
+        seen.append(rows[:n_real])
+    assert np.all(src[n_blocks * R:] == A)  # past the blocks in use: padding alone
+    seen = np.concatenate(seen) if seen else np.zeros((0,), np.int64)
+    assert np.array_equal(np.sort(seen), np.flatnonzero(expert_of < G))  # every held one, once
+
+
+# ---- (d) the counters -----------------------------------------------------------
+
+@pytest.mark.parametrize("R,pieces", [(8, 1), (P, 1), (P + 8, 2), (3 * P, 3)])
+def test_the_counters_say_how_many_pieces_a_call_sites_block_took(R, pieces):
+    c = _config(R, 4, 2, 2, 0)
+    p, x, idx, g, dy = _inputs(1, 24, 4, 2, 2)
+    stats = lambda: np.array([STAT_GET("model.moe.combine_calls"),  # noqa: E731
+                              STAT_GET("model.moe.combine_pieces")])
+    before = stats()
+    text = str(jax.make_jaxpr(lambda x: glm.routed_experts(p, x, idx, g, c, "model")[0])(x))
+    assert (stats() - before).tolist() == [1, pieces]  # the forward's y
+    assert text.count("scatter-add") == pieces
+    before = stats()
+    jax.make_jaxpr(jax.grad(lambda x: jnp.sum(glm.routed_experts(p, x, idx, g, c, "model")[0] * dy)))(x)
+    assert (stats() - before).tolist() == [2, 2 * pieces]  # the forward's y and the backward's dx
