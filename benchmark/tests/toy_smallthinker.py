@@ -1,0 +1,36 @@
+"""The SmallThinker cell at a size a CPU test can hold: the real configuration
+and mix files with their sizes replaced (every mechanism kept: the router
+ahead of attention, ReLU-gated experts, 8 experts top 2 with 2 held and no
+shared one, a group of query heads that is no power of two (6 over 2), a
+window shorter than the record, the full layer without positions first), and
+limits read off toy runs."""
+
+from benchmark import run as bench_run
+
+WORKLOAD = "smallthinker_21b_ep8.pass_train"
+TOY_LIMITS = {
+    "early_loss_gap": 1e-4, "logit_gap": 1e-3, "counter_gap": 0.0,
+    "sparse_grad_gap": 0.02, "sparse_delta_gap": 0.02,
+    "dense_grad_gap": 0.02, "dense_delta_gap": 0.02, "router_flip_share": 0.01,
+}
+TOY_SIZES = dict(
+    hidden_size=64, embedx_dim=64, num_attention_heads=6, num_key_value_heads=2, head_dim=16,
+    sliding_window_size=16, moe_ffn_hidden_size=48, router_experts=8,
+    moe_num_primary_experts=2, experts_offset=2, moe_num_active_primary_experts=2,
+    num_hidden_layers=4, held_layers=[0, 1, 2, 3], held_sliding_layout=[0, 1, 1, 1],
+    held_rope_layout=[0, 1, 1, 1], vocab_size=64, seq_len=32, batch_size=2,
+    attn_block=8, loss_block=16, expert_block=8)
+
+
+def cell(seed: int = 3_000_000_035, trace: bool = False, **cfg_over) -> dict:
+    cfg = bench_run.load_json("benchmark", "configs", "smallthinker_21b_ep8.json")
+    cfg.update(TOY_SIZES)
+    # rows of range 1: at the toy's width and 32 positions a row of range 4 drowns what an
+    # attention block adds, and the router placed after it (the planted fault) chooses alike
+    cfg["sparse_opt"] = {**cfg["sparse_opt"], "initial_range": 1.0}
+    cfg.update(cfg_over)
+    mix = bench_run.load_json("benchmark", "traffic", "pass_tokens.smallthinker.json")
+    mix.update(seq_len=cfg["seq_len"], vocab=cfg["vocab_size"],
+               train_records=32 * cfg["batch_size"])
+    return {"workload": WORKLOAD, "chips": 1, "cfg": cfg, "mix": mix,
+            "limits": dict(TOY_LIMITS), "seed": seed, "seconds": 1.0, "trace": trace}

@@ -6,6 +6,7 @@ from paddlebox_tpu.models.mmoe import MMoE, task_head
 from paddlebox_tpu.models.rank import RankDeepFM
 from paddlebox_tpu.models.glm_moe_lite import GlmMoeLite, GlmMoeLiteConfig
 from paddlebox_tpu.models.afmoe import Afmoe, AfmoeConfig
+from paddlebox_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 
 __all__ = [
     "mlp_init",
@@ -23,4 +24,6 @@ __all__ = [
     "GlmMoeLiteConfig",
     "Afmoe",
     "AfmoeConfig",
+    "SmallThinker",
+    "SmallThinkerConfig",
 ]

@@ -211,6 +211,57 @@ def moe_layer(p, x, c: AfmoeConfig, rope, sliding, scope: str = "model"):
     return out, idx.reshape(B, T, -1), counts
 
 
+# ---- the loss and the counters of a window-and-full model (``smallthinker`` shares them) ----
+
+
+def feed_ids(emb, ids, c):
+    """The record's token ids as int32, once the feed fits the model."""
+    B, T, _ = emb.shape
+    if T != c.seq_len or ids.shape != (B, T):
+        raise ValueError(f"sequence feed of {emb.shape} / {ids.shape}, seq_len {c.seq_len}")
+    return ids.astype(jnp.int32)
+
+
+def window_loss(params, x, ids, c) -> Dict[str, Any]:
+    """The last hidden state x [B, T, H] through the final norm and the head
+    (``params["final_norm"]``, ``params["head"]``) against the next token:
+    ``parts`` [2], ``token_logits`` [2, B, T] and ``loss`` as ``Afmoe.forward``
+    describes them."""
+    B, T, H = x.shape
+    with jax.named_scope("loss/head"):
+        pos = jnp.arange(T)
+        targets = jnp.concatenate([ids[:, 1:], jnp.zeros((B, 1), jnp.int32)], axis=1)
+        h = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        tl, lse = head_logits(params["head"], h.reshape(B * T, H), targets.reshape(-1),
+                              c.loss_block)
+        tl, lse = tl.reshape(1, B, T), lse.reshape(1, B, T)
+        has = pos < T - 1
+        mask = jnp.stack([has & (pos < c.sliding_window),
+                          has & (pos >= c.sliding_window)]).astype(F32)[:, None, :]
+        sums = jnp.sum((lse - tl) * mask, axis=(1, 2))
+        parts = sums / jnp.maximum(B * jnp.sum(mask, axis=(1, 2)), 1.0)
+        loss = jnp.sum(sums) / (B * (T - 1))
+    return {"parts": parts, "token_logits": jnp.concatenate([tl, lse]), "loss": loss}
+
+
+def window_counters(out, emb) -> list:
+    """``COUNTERS`` of one batch, from what ``forward`` gave."""
+    parts, loads = out["parts"], out["loads"].astype(F32)
+    return [parts[0], parts[1], jnp.asarray(float(emb.shape[0] * emb.shape[1])),
+            jnp.sum(loads), jnp.max(loads) / jnp.maximum(jnp.mean(loads), 1e-9)]
+
+
+def record_window_counters(means) -> None:
+    """A pass's mean ``COUNTERS`` into the monitor registry (literal names)."""
+    from paddlebox_tpu.utils.monitor import STAT_SET
+
+    STAT_SET("model.loss_in_window", float(means[0]))
+    STAT_SET("model.loss_past_window", float(means[1]))
+    STAT_SET("model.tokens_per_step", float(means[2]))
+    STAT_SET("model.held_assignments_per_step", float(means[3]))
+    STAT_SET("model.expert_load_max_over_mean", float(means[4]))
+
+
 # ---- the model ------------------------------------------------------------------
 
 
@@ -312,27 +363,9 @@ class Afmoe:
         positions that have a target. emb [B, T, H]: the token slot's pulled
         rows, CVM columns dropped; ids [B, T]: the record's token ids (whole
         numbers in float32 or int32), relative to the held slice."""
-        c = self.cfg
-        B, T, H = emb.shape
-        if T != c.seq_len or ids.shape != (B, T):
-            raise ValueError(f"sequence feed of {emb.shape} / {ids.shape}, seq_len {c.seq_len}")
-        ids = ids.astype(jnp.int32)
+        ids = feed_ids(emb, ids, self.cfg)
         x, choices, loads = self.hidden_states(params, emb)
-        with jax.named_scope("loss/head"):
-            pos = jnp.arange(T)
-            targets = jnp.concatenate([ids[:, 1:], jnp.zeros((B, 1), jnp.int32)], axis=1)
-            h = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-            tl, lse = head_logits(params["head"], h.reshape(B * T, H), targets.reshape(-1),
-                                  c.loss_block)
-            tl, lse = tl.reshape(1, B, T), lse.reshape(1, B, T)
-            has = pos < T - 1
-            mask = jnp.stack([has & (pos < c.sliding_window),
-                              has & (pos >= c.sliding_window)]).astype(F32)[:, None, :]
-            sums = jnp.sum((lse - tl) * mask, axis=(1, 2))
-            parts = sums / jnp.maximum(B * jnp.sum(mask, axis=(1, 2)), 1.0)
-            loss = jnp.sum(sums) / (B * (T - 1))
-        return {"parts": parts, "token_logits": jnp.concatenate([tl, lse]),
-                "router_choices": choices, "loads": loads, "loss": loss}
+        return {**window_loss(params, x, ids, self.cfg), "router_choices": choices, "loads": loads}
 
     def apply(self, params, emb, ids):
         """The training loss of one batch (``forward``'s arguments) and the
@@ -340,19 +373,7 @@ class Afmoe:
         ``counter_names``."""
         out = self.forward(params, emb, ids)
         with jax.named_scope("loss/head"):
-            parts, loads = out["parts"], out["loads"].astype(F32)
-            counters = jnp.stack([
-                parts[0], parts[1], jnp.asarray(float(emb.shape[0] * emb.shape[1])),
-                jnp.sum(loads), jnp.max(loads) / jnp.maximum(jnp.mean(loads), 1e-9)])
+            counters = jnp.stack(window_counters(out, emb))
         return out["loss"], {"counters": lax.stop_gradient(counters)}
 
-    @staticmethod
-    def record_counters(means) -> None:
-        """A pass's mean counters into the monitor registry (literal names)."""
-        from paddlebox_tpu.utils.monitor import STAT_SET
-
-        STAT_SET("model.loss_in_window", float(means[0]))
-        STAT_SET("model.loss_past_window", float(means[1]))
-        STAT_SET("model.tokens_per_step", float(means[2]))
-        STAT_SET("model.held_assignments_per_step", float(means[3]))
-        STAT_SET("model.expert_load_max_over_mean", float(means[4]))
+    record_counters = staticmethod(record_window_counters)

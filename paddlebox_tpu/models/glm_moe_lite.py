@@ -227,9 +227,21 @@ def swiglu(p, x):
 # ---- the routed experts a chip holds ------------------------------------------
 
 
-def route(p, x, c: GlmMoeLiteConfig):
-    """x [N, H] -> (chosen experts [N, k] int32, their weights [N, k])."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(F32), p["w"], precision=lax.Precision.HIGHEST))
+def route(p, x, c: GlmMoeLiteConfig, form: str = "sigmoid_bias_norm"):
+    """x [N, H] -> (chosen experts [N, k] int32, their weights [N, k]).
+    ``form`` (static) is the router's: ``sigmoid_bias_norm`` chooses by
+    sigmoid + bias and weighs by the chosen sigmoids over their sum, times
+    ``routed_scaling_factor``; ``softmax_of_chosen`` chooses by the logits and
+    weighs by a softmax over the chosen ones (a softmax over all of them
+    renormalised over the chosen is the same numbers) and reads neither a bias
+    nor a scale."""
+    s = jnp.dot(x.astype(F32), p["w"], precision=lax.Precision.HIGHEST)
+    if form == "softmax_of_chosen":
+        chosen, idx = lax.top_k(s, c.num_experts_per_tok)
+        return idx.astype(jnp.int32), jax.nn.softmax(chosen, axis=1)
+    if form != "sigmoid_bias_norm":
+        raise ValueError(f"router form {form!r}")
+    s = jax.nn.sigmoid(s)
     _, idx = lax.top_k(s + lax.stop_gradient(p["bias"]), c.num_experts_per_tok)
     chosen = jnp.take_along_axis(s, idx, axis=1)
     g = chosen / jnp.sum(chosen, axis=1, keepdims=True) * c.routed_scaling_factor
@@ -266,9 +278,9 @@ def group_layout(expert_of, G: int, R: int):
     return src, blk_expert, ends[-1] // R, counts
 
 
-def _expert_block(xb, wg, wu, wd):
+def _expert_block(xb, wg, wu, wd, act: str):
     hg, hu = _mm(xb, wg), _mm(xb, wu)
-    h = jax.nn.silu(hg) * hu
+    h = (jax.nn.silu(hg) if act == "silu" else jax.nn.relu(hg)) * hu
     return hg, hu, h, _mm(h, wd)
 
 
@@ -294,20 +306,27 @@ def _add_rows(acc, tb, rows):
     return acc
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def grouped_experts(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R: int, scope: str):
+ACTS = ("silu", "relu")  # the gate's activation: down((silu | relu)(x gate) * (x up))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def grouped_experts(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R: int, scope: str,
+                    act: str = "silu"):
     """y[t] = sum over the rows r of token t of gate[r] * expert(x[t]), the
-    expert of row r being its block's. x [N, H]; wg, wu [G, H, I], wd
+    expert of row r being its block's, its gate's activation ``act`` (static,
+    forward and the hand-written backward). x [N, H]; wg, wu [G, H, I], wd
     [G, I, H]; gate [M] float32; tok [M] the row's token (N = no token), in
     ``group_layout``'s order: a block's real rows first, their tokens
     distinct and ascending, its padding after. One pass over the ``n_blocks``
     blocks in use: gather the block's tokens, the expert's three products,
     add the weighted rows to their tokens (``_add_rows``, which depends on
     that order: a token at most once a block; the backward's ``dx`` likewise)."""
-    return _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope)[0]
+    return _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope, act)[0]
 
 
-def _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope):
+def _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope, act):
+    if act not in ACTS:
+        raise ValueError(f"gate activation {act!r}")
     N, H = x.shape
     xe = jnp.concatenate([x.astype(BF16), jnp.zeros((1, H), BF16)])
     res = (x, wg, wu, wd, gate, tok, blk_expert, n_blocks)
@@ -320,14 +339,14 @@ def _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope):
         with jax.named_scope(f"{scope}/moe/dispatch"):
             xb = xe[tb]
         with jax.named_scope(f"{scope}/moe/experts"):
-            yb = _expert_block(xb, wg[e], wu[e], wd[e])[3]
+            yb = _expert_block(xb, wg[e], wu[e], wd[e], act)[3]
         with jax.named_scope(f"{scope}/moe/combine"):
             return _add_rows(y, tb, yb * gb[:, None])
 
     return lax.fori_loop(0, n_blocks, body, jnp.zeros((N, H), F32)), res
 
 
-def _grouped_bwd(R, scope, res, dy):
+def _grouped_bwd(R, scope, act, res, dy):
     x, wg, wu, wd, gate, tok, blk_expert, n_blocks = res
     N, H = x.shape
     xe = jnp.concatenate([x.astype(BF16), jnp.zeros((1, H), BF16)])
@@ -347,13 +366,17 @@ def _grouped_bwd(R, scope, res, dy):
         with jax.named_scope(f"{scope}/moe/dispatch"):
             xb, dyb = xe[tb], dye[tb]
         with jax.named_scope(f"{scope}/moe/experts"):
-            hg, hu, h, yb = _expert_block(xb, wg[e], wu[e], wd[e])
+            hg, hu, h, yb = _expert_block(xb, wg[e], wu[e], wd[e], act)
             dgb = jnp.sum(yb * dyb, axis=1)
             dyb = (dyb * gb[:, None]).astype(BF16)
             dh = jnp.dot(dyb, wdT[e], preferred_element_type=F32)
-            sg = jax.nn.sigmoid(hg)
-            dhu = (dh * hg * sg).astype(BF16)
-            dhg = (dh * hu * sg * (1.0 + hg * (1.0 - sg))).astype(BF16)
+            if act == "silu":
+                sg = jax.nn.sigmoid(hg)
+                dhu = (dh * hg * sg).astype(BF16)
+                dhg = (dh * hu * sg * (1.0 + hg * (1.0 - sg))).astype(BF16)
+            else:  # relu: the gate passes where it is positive
+                dhu = (dh * jax.nn.relu(hg)).astype(BF16)
+                dhg = jnp.where(hg > 0, dh * hu, 0.0).astype(BF16)
             dwd = add_at(dwd, e, jnp.dot(h.astype(BF16).T, dyb, preferred_element_type=F32))
             dwg = add_at(dwg, e, jnp.dot(xb.T, dhg, preferred_element_type=F32))
             dwu = add_at(dwu, e, jnp.dot(xb.T, dhu, preferred_element_type=F32))
@@ -371,9 +394,10 @@ def _grouped_bwd(R, scope, res, dy):
 grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def routed_experts(p, x, idx, g, c: GlmMoeLiteConfig, scope: str):
-    """The held experts' part of the layer, for x [N, H] routed as (idx, g).
-    Returns it with the held experts' loads [experts_held]."""
+def routed_experts(p, x, idx, g, c: GlmMoeLiteConfig, scope: str, act: str = "silu"):
+    """The held experts' part of the layer, for x [N, H] routed as (idx, g),
+    the gate's activation ``act``. Returns it with the held experts' loads
+    [experts_held]."""
     N, k = idx.shape
     G = c.experts_held
     with jax.named_scope(f"{scope}/moe/dispatch"):
@@ -383,7 +407,7 @@ def routed_experts(p, x, idx, g, c: GlmMoeLiteConfig, scope: str):
         tok = jnp.where(src < N * k, src // k, N)
         gate = jnp.concatenate([g.reshape(-1), jnp.zeros((1,), F32)])[src]
     y = grouped_experts(x, p["gate"], p["up"], p["down"], gate, tok, blk_expert, n_blocks,
-                        c.expert_block, scope)
+                        c.expert_block, scope, act)
     return y, counts
 
 

@@ -5,7 +5,8 @@ here): interpret mode on the CPU at a small tiled shape, the rule that chooses
 between the two, its counters, and the kernels compiled at the token cell's
 shapes for a described v5e. Since PR 33 also with grouped-query heads and a
 window (``models/afmoe.py``), against that model's blocked form, and GLM's call
-held to the kernel it had before."""
+held to the kernel it had before; since PR 35 at a group that is no power of
+two (7, ``models/smallthinker.py``) and at that model's 16k shapes."""
 
 from __future__ import annotations
 
@@ -145,15 +146,15 @@ def test_mla_through_the_kernel_agrees_with_mla_through_the_blocks(monkeypatch):
 WINDOW, GROUP = 256, 8
 
 
-def _grouped(T: int):
+def _grouped(T: int, group: int = GROUP, kv_heads: int = 1):
     ks = jax.random.split(jax.random.PRNGKey(33 + T), 4)
-    q = jax.random.normal(ks[0], (1, T, GROUP, D)).astype(jnp.bfloat16)
-    k, v = (jax.random.normal(a, (1, T, 1, D)).astype(jnp.bfloat16) for a in ks[1:3])
-    return q, k, v, jax.random.normal(ks[3], (1, T, GROUP, D))
+    q = jax.random.normal(ks[0], (1, T, kv_heads * group, D)).astype(jnp.bfloat16)
+    k, v = (jax.random.normal(a, (1, T, kv_heads, D)).astype(jnp.bfloat16) for a in ks[1:3])
+    return q, k, v, jax.random.normal(ks[3], (1, T, kv_heads * group, D))
 
 
-def _blocked_grouped(q, k, v, window):
-    return jnp.concatenate([afmoe._attend_block(q, k, v, i, 128, SCALE, GROUP, window)
+def _blocked_grouped(q, k, v, window, group: int = GROUP):
+    return jnp.concatenate([afmoe._attend_block(q, k, v, i, 128, SCALE, group, window)
                             for i in range(0, q.shape[1], 128)], axis=1)
 
 
@@ -175,6 +176,26 @@ def test_group_8_and_a_window_match_the_blocked_form_output_and_gradients(block,
         assert _rel(a, b) < 8e-3  # dk, dv are sums over the group's 8 heads, each side rounds once
     if T > WINDOW:  # and the window is there: full causal is another function
         assert _rel(o, _blocked_grouped(q, k, v, None)) > 0.05
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+@pytest.mark.parametrize("window", [WINDOW, None])
+def test_group_7_over_two_key_value_heads_matches_the_blocked_form_output_and_gradients(block, window):
+    """SmallThinker's group, the first that is no power of two: 14 query heads
+    over 2 key-value heads, a band of ``WINDOW`` in 4 W (tiles on the edge,
+    tiles skipped) and full causal."""
+    q, k, v, g = _grouped(4 * WINDOW, 7, 2)
+    fused = lambda q, k, v: causal_attention(q, k, v, SCALE, block, True, 7, window)  # noqa: E731
+    want_f = lambda q, k, v: _blocked_grouped(q, k, v, window, 7)  # noqa: E731
+    o, want = fused(q, k, v), want_f(q, k, v)
+    assert o.dtype == jnp.float32 and o.shape == want.shape == q.shape
+    assert _rel(o, want) < 3e-3 and float(jnp.max(jnp.abs(o - want))) < 2e-2
+    grad = lambda f: jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)  # noqa: E731
+    for a, b in zip(grad(fused), grad(want_f)):
+        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
+        assert _rel(a, b) < 8e-3  # dk, dv are sums over the group's 7 heads, each side rounds once
+    # query head h reads key-value head h // 7: the two key-value heads swapped is another function
+    assert _rel(o, fused(q, k[:, :, ::-1], v[:, :, ::-1])) > 0.05
 
 
 @pytest.mark.parametrize("block", BLOCKS)
@@ -222,6 +243,33 @@ def test_no_group_and_no_window_are_the_call_that_names_neither(qkvg):
 ])
 def test_afmoes_path_is_chosen_from_backend_and_shapes(backend, t, d, block, window, fused):
     assert afmoe.fused_scores(backend, t, d, block, window) is fused
+
+
+@pytest.mark.parametrize("backend,window,fused", [
+    ("tpu", 4096, True),    # the SmallThinker cell's window layers: 32 tiles a side, a band of 8 + 1
+    ("tpu", None, True),    # and its full layer
+    ("cpu", 4096, False),   # tier-1, whatever the shape
+])
+def test_smallthinkers_path_is_chosen_from_backend_and_shapes(backend, window, fused, monkeypatch):
+    """The model's scores part is ``afmoe._scores`` itself, with its rule and
+    its counters: at the cell's shapes a TPU takes one kernel a kind."""
+    from paddlebox_tpu.models import SmallThinkerConfig, smallthinker
+
+    c = SmallThinkerConfig()  # the published widths, the cell's record and blocks
+    assert (c.group, c.seq_len, c.head_dim, c.attn_block, c.sliding_window) == (7, 16384, 128, 512, 4096)
+    assert afmoe.fused_scores(backend, c.seq_len, c.head_dim, c.attn_block, window) is fused
+    assert not afmoe.fused_scores("tpu", 32, 16, 8, 16)  # the toy cell's widths stay blocked
+    assert smallthinker._scores is afmoe._scores
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    small = SmallThinkerConfig(hidden_size=64, num_attention_heads=7, num_key_value_heads=1,
+                               seq_len=1024, sliding_window=512, attn_block=128)
+    x = jax.ShapeDtypeStruct((1, small.seq_len, small.hidden_size), jnp.float32)
+    p = {"q": jnp.zeros((64, 7 * 128)), "k": jnp.zeros((64, 128)), "v": jnp.zeros((64, 128)),
+         "o": jnp.zeros((7 * 128, 64))}
+    rope = glm.rope_tables(small.seq_len, small.head_dim, small.rope_theta)
+    text = str(jax.make_jaxpr(lambda x: smallthinker.attention(
+        p, x, jnp.ones((64,)), small, rope, window is not None))(x))
+    assert text.count("pallas_call") == (1 if fused else 0)
 
 
 AF_TILED = AfmoeConfig(hidden_size=64, num_attention_heads=2, num_key_value_heads=1, head_dim=128,
@@ -330,3 +378,30 @@ def test_grouped_window_and_full_kernels_compile_for_a_v5e_at_the_trinity_cells_
         # no score block and no 8-fold k, v, dk or dv in HBM: the temporaries are the
         # float32 output and dq, the statistics, the row term and dk, dv at 4 heads
         assert compiled.memory_analysis().temp_size_in_bytes < 3 * 8192 * 32 * 128 * 4
+
+
+def test_group_7_window_and_full_kernels_compile_for_a_v5e_at_the_smallthinker_cells_shapes(one_chip):
+    """1 x 16,384 x 28 query heads over 4 key-value heads of 128, tiles of 512:
+    32 tiles a side (16 was the most), the window layers' pair (a band of
+    8 + 1 tiles) and the full layer's; the backward holds three whole-T
+    float32 blocks of [16384, 128] (dq; dk, dv over a group's 7 heads) in VMEM."""
+    from paddlebox_tpu.obs.program_scopes import scope_map
+
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.float32, sharding=one_chip)
+    for scope, window in (("model/attn/scores_window", 4096), ("model/attn/scores_full", None)):
+        def step(q, k, v, g, scope=scope, window=window):
+            with jax.named_scope(scope):
+                return jax.grad(lambda q, k, v: jnp.sum(
+                    causal_attention(q, k, v, 128 ** -0.5, 512, False, 7, window) * g),
+                    argnums=(0, 1, 2))(q, k, v)
+
+        compiled = jax.jit(step).lower(q, kv, kv, g).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        kernels = {n: s for n, s in scope_map(text).items() if "causal_attention" in n}
+        assert len(kernels) == 2 and set(kernels.values()) == {scope}, kernels
+        # no score block and no 7-fold k, v, dk or dv in HBM: the temporaries are the
+        # float32 output and dq, the statistics, the row term and dk, dv at 4 heads
+        assert compiled.memory_analysis().temp_size_in_bytes < 3 * 16384 * 28 * 128 * 4
