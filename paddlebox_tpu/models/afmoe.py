@@ -22,10 +22,15 @@ only ``sliding_window`` keys back; ``x += norm((o * sigmoid(gate)) W_o)``;
 sqrt(hidden) (``mup_enabled``).
 
 Precision as ``glm_moe_lite``: float32 but for the bfloat16 operands of the
-matrix products. Memory: every layer recomputed in the backward; the expert
-layers are one stacked body under ``lax.scan`` **whose step is told its
-kind** (a traced flag: ``lax.cond`` picks rope and window or neither, so both
-kinds compile once whatever their order); the scores take the fused kernel
+matrix products. Memory: every layer recomputed in the backward from its
+input, but for the fused scores' float32 output and logsumexp, which each
+layer's checkpoint keeps by name (``ops/pallas_kernels.py::KEEP_SCORES``; 0.67
+GB over the cell's five layers of one 8k record): q, k, v are the
+recomputation's anyway, so the backward kernel is fed without the forward
+kernel's second run. The expert layers are one stacked body under
+``lax.scan`` **whose step is told its kind** (a traced flag: ``lax.cond``
+picks rope and window or neither, so both kinds compile once whatever their
+order); the scores take the fused kernel
 (``ops/pallas_kernels.py::causal_attention`` with ``group`` and ``window``) on
 a TPU at shapes it tiles and query blocks against their visible keys
 (``_attend_block``) everywhere else, chosen and counted at trace time
@@ -46,7 +51,7 @@ from jax import lax
 from paddlebox_tpu.models.glm_moe_lite import (
     BF16, F32, _mm, _product, apply_rope, head_logits, rms_norm, rope_tables, route,
     routed_experts, swiglu)
-from paddlebox_tpu.ops.pallas_kernels import LANE, causal_attention
+from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES, LANE, causal_attention
 from paddlebox_tpu.utils.monitor import STAT_ADD
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -340,9 +345,12 @@ class Afmoe:
         if c.mup_enabled:
             x = x * float(c.hidden_size) ** 0.5
         for p, sliding in zip(params["dense"], kinds):
-            x = jax.checkpoint(lambda p, x, s=sliding: dense_layer(p, x, c, rope, s))(p, x)
+            x = jax.checkpoint(lambda p, x, s=sliding: dense_layer(p, x, c, rope, s),
+                               policy=KEEP_SCORES)(p, x)
+        # checkpoints that keep the scores' output and logsumexp, at trace time
+        STAT_ADD("model.attn.keep_scores_sites", c.num_dense_layers + 1)
 
-        @jax.checkpoint
+        @partial(jax.checkpoint, policy=KEEP_SCORES)
         def body(x, layer):
             p, sliding = layer  # one compiled body: the step is told its kind
             x, idx, counts = moe_layer(p, x, c, rope, sliding)

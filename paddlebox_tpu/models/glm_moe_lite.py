@@ -20,7 +20,12 @@ Precision: parameters, residual stream, norms, rope, router, softmax and
 loss in float32; matrix-product operands cast to bfloat16 with float32
 accumulation; the router's own product in float32 at ``highest``.
 
-Memory: every layer is recomputed in the backward (``jax.checkpoint``), the
+Memory: every layer is recomputed in the backward from its input
+(``jax.checkpoint``), but for the fused scores' float32 output and logsumexp,
+which each layer's checkpoint keeps by name
+(``ops/pallas_kernels.py::KEEP_SCORES``; 1.01 GB over the cell's six attention
+layers of two 4k records): q, k, v are the recomputation's anyway, so the
+backward kernel is fed without the forward kernel's second run. The
 expert layers are one stacked body under ``lax.scan``, the attention scores
 never exist whole, and the head's logits exist one block of positions at a
 time. The scores take one of two forms, chosen at trace time from what the
@@ -46,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from paddlebox_tpu.ops.pallas_kernels import LANE, causal_attention
+from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES, LANE, causal_attention
 from paddlebox_tpu.utils.monitor import STAT_ADD
 
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -539,17 +544,19 @@ class GlmMoeLite:
         c = self.cfg
         rope = rope_tables(emb.shape[1], c.qk_rope_head_dim, c.rope_theta)
         x = emb.astype(F32)
+        # checkpoints that keep the scores' output and logsumexp, at trace time
+        STAT_ADD("model.mla.keep_scores_sites", len(params["dense"]) + 2)
         for p in params["dense"]:
-            x = jax.checkpoint(lambda p, x: dense_layer(p, x, c, rope))(p, x)
+            x = jax.checkpoint(lambda p, x: dense_layer(p, x, c, rope), policy=KEEP_SCORES)(p, x)
 
-        @jax.checkpoint
+        @partial(jax.checkpoint, policy=KEEP_SCORES)
         def body(x, p):
             x, idx, counts = moe_layer(p, x, c, rope)
             return x, (idx, counts)
 
         x, (choices, loads) = lax.scan(body, x, params["moe"])
 
-        @jax.checkpoint
+        @partial(jax.checkpoint, policy=KEEP_SCORES)
         def mtp(m, x, emb):
             with jax.named_scope("model/mtp/eh_proj"):
                 nxt = jnp.concatenate([emb[:, 1:], jnp.zeros_like(emb[:, :1])], axis=1)

@@ -30,8 +30,13 @@ causally, on a sliding layer only ``sliding_window`` keys back;
 post-attention stream, by the choice made before it.
 
 Precision as ``glm_moe_lite``: float32 but for the bfloat16 operands of the
-matrix products. Memory: every layer recomputed in the backward; the stack is
-one body under ``lax.scan`` whose step is told its kind, as ``afmoe``'s.
+matrix products. Memory: every layer recomputed in the backward from its
+input, but for the fused scores' float32 output and logsumexp, which the
+layer's checkpoint keeps by name (``ops/pallas_kernels.py::KEEP_SCORES``: 0.94
+GB over the cell's four layers of one 16k record): q, k, v are the
+recomputation's anyway, so the backward kernel is fed without the forward
+kernel's second run. The stack is one body under ``lax.scan`` whose step is
+told its kind, as ``afmoe``'s.
 """
 
 from __future__ import annotations
@@ -47,6 +52,8 @@ from jax import lax
 from paddlebox_tpu.models import afmoe
 from paddlebox_tpu.models.afmoe import _scores
 from paddlebox_tpu.models.glm_moe_lite import F32, _mm, rms_norm, rope_tables, route, routed_experts
+from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
+from paddlebox_tpu.utils.monitor import STAT_ADD
 
 COUNTERS = afmoe.COUNTERS + ("unrouted_tokens", "block_rows")
 
@@ -178,7 +185,10 @@ class SmallThinker:
         c = self.cfg
         rope = rope_tables(emb.shape[1], c.head_dim, c.rope_theta)
 
-        @jax.checkpoint
+        # checkpoints that keep the scores' output and logsumexp, at trace time
+        STAT_ADD("model.attn.keep_scores_sites")
+
+        @partial(jax.checkpoint, policy=KEEP_SCORES)
         def body(x, step):
             p, sliding = step  # one compiled body: the step is told its kind
             x, idx, counts = layer(p, x, c, rope, sliding)

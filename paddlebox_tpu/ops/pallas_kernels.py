@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -232,7 +233,7 @@ def _attention_fwd(q, k, v, scale, blk, interpret, group, window):
                         pltpu.VMEM((blk, D), jnp.float32)],
         compiler_params=_ATTENTION_PARAMS, interpret=interpret, name="causal_attention_fwd",
     )(_flat(q), _flat(k), _flat(v))
-    return o.reshape(B, T, H, D), lse
+    return o, lse  # o as the kernel writes it: [B, T, H * D]
 
 
 def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret, group, window):
@@ -277,11 +278,19 @@ def causal_attention(q, k, v, scale: float, block: int, interpret: bool = False,
     with a ``window`` only to the keys less than ``window`` behind it. T and
     ``window`` multiples of ``block``, ``block`` and D multiples of 128. The
     cotangents of q, k and v come back in their dtype."""
-    return _attention_fwd(q, k, v, scale, block, interpret, group, window)[0]
+    return _attention_fwd(q, k, v, scale, block, interpret, group, window)[0].reshape(q.shape)
+
+
+# The two of the backward's residuals that only the forward kernel can give (q, k, v are a
+# layer's recomputation anyway). Under a checkpoint that takes ``KEEP_SCORES`` they are kept
+# and the recomputation's kernel call is dead code; under any other they are the identity.
+SCORES_OUT, SCORES_LSE = "causal_attention_out", "causal_attention_lse"
+KEEP_SCORES = jax.checkpoint_policies.save_only_these_names(SCORES_OUT, SCORES_LSE)
 
 
 def _causal_attention_fwd(q, k, v, scale, block, interpret, group, window):
     o, lse = _attention_fwd(q, k, v, scale, block, interpret, group, window)
+    o, lse = checkpoint_name(o, SCORES_OUT).reshape(q.shape), checkpoint_name(lse, SCORES_LSE)
     return o, (q, k, v, o, lse)
 
 

@@ -11,18 +11,25 @@ two (7, ``models/smallthinker.py``) and at that model's 16k shapes."""
 from __future__ import annotations
 
 import hashlib
+import json
+import os
 import re
+from functools import partial
 
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
+from jax import lax  # noqa: E402
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 
 from paddlebox_tpu.models import afmoe  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
-from paddlebox_tpu.models import Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig  # noqa: E402
-from paddlebox_tpu.ops.pallas_kernels import causal_attention  # noqa: E402
+from paddlebox_tpu.models import (  # noqa: E402
+    Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, SmallThinker, SmallThinkerConfig)
+from paddlebox_tpu.ops.pallas_kernels import (  # noqa: E402
+    KEEP_SCORES, SCORES_LSE, SCORES_OUT, causal_attention)
 from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
 
 B, T, H, D = 1, 256, 2, 128
@@ -211,15 +218,19 @@ def test_a_key_a_window_behind_leaves_the_output_bit_equal_and_one_nearer_does_n
 
 
 def test_glms_call_lowers_to_the_kernel_it_had_before_group_and_window():
-    """``group`` 1 and no window trace to the jaxpr of the parent commit's
-    kernel pair (PR 32's ``ops/pallas_kernels.py``: kernel bodies, grids, block
-    index maps), source locations aside. The digest was taken from that commit
-    with these lines; a change to the kernel GLM's cell runs has to change it."""
+    """``group`` 1 and no window trace to the jaxpr of PR 32's kernel pair
+    (``ops/pallas_kernels.py``: kernel bodies, grids, block index maps), source
+    locations aside, and since PR 36 two ``name`` equations behind the forward
+    call (the flat output and the logsumexp, the identity unless a checkpoint
+    keeps them). The digest was taken anew from PR 36's tree (it was
+    ``08284859...`` from commit 631c704 to 866c886): a diff of the two texts
+    shows those two equations and the variables renamed after them, nothing of
+    a kernel. A change to the kernel GLM's cell runs has to change it."""
     x = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
     f = lambda q, k, v: jnp.sum(causal_attention(q, k, v, 128 ** -0.5, 128, True))  # noqa: E731
     text = str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(x, x, x))
     digest = hashlib.sha256(re.sub(r"\S+\.py:\d+", "", text).encode()).hexdigest()
-    assert digest == "08284859e123d045f642ba5a667039d8c6900aa70022fd0b8d687eda077c7d61"
+    assert digest == "68b3e6182885371b39f558d6453dc3759b471777a6c52d6a50dc89695a33ecc5"
 
 
 def test_no_group_and_no_window_are_the_call_that_names_neither(qkvg):
@@ -321,6 +332,118 @@ def test_afmoe_attention_through_the_kernel_agrees_with_it_through_the_blocks(mo
     assert _rel(dgot, dwant) < 1e-2
 
 
+# ---- o and the logsumexp kept across a layer's checkpoint, by name (PR 36) -------------
+
+def _kernel_calls(text: str):
+    """(forward, backward) kernel calls in a jaxpr's text."""
+    return tuple(len(re.findall(rf"name=causal_attention_{d}\b", text)) for d in ("fwd", "bwd"))
+
+
+def _checkpointed_stack(group: int, window, policy):
+    """Three scanned layers under ``jax.checkpoint``, each told its kind by a
+    traced flag: with a ``window`` a ``lax.cond`` over a windowed and a full
+    call site (``afmoe``'s and ``smallthinker``'s stack), without one a single
+    full call site (GLM's). -> (the gradient's jaxpr as text, loss and gradients)."""
+    T, hid, layers = 256, 32, 3
+    ks = jax.random.split(jax.random.PRNGKey(36), 5)
+    w = {"q": jax.random.normal(ks[0], (layers, hid, group * D)) * 0.3,
+         "k": jax.random.normal(ks[1], (layers, hid, D)) * 0.3,
+         "v": jax.random.normal(ks[2], (layers, hid, D)) * 0.3,
+         "o": jax.random.normal(ks[3], (layers, group * D, hid)) * 0.1}
+    x = jax.random.normal(ks[4], (1, T, hid))
+    call = lambda win: (  # noqa: E731
+        lambda q, k, v: causal_attention(q, k, v, SCALE, 128, True, group, win))
+
+    @partial(jax.checkpoint, policy=policy)
+    def layer(x, step):
+        p, kind = step
+        q, k, v = ((x @ p[n]).reshape(1, T, -1, D).astype(jnp.bfloat16) for n in "qkv")
+        o = call(None)(q, k, v) if window is None else lax.cond(kind, call(window), call(None), q, k, v)
+        return x + o.reshape(1, T, group * D) @ p["o"], None
+
+    loss = lambda w, x: jnp.sum(  # noqa: E731
+        lax.scan(layer, x, (w, jnp.asarray([True, False, True])))[0] ** 2)
+    grad = jax.value_and_grad(loss, argnums=(0, 1))
+    return str(jax.make_jaxpr(grad)(w, x)), grad(w, x)
+
+
+@pytest.mark.parametrize("window", [128, None])
+@pytest.mark.parametrize("group", [1, 7])
+def test_a_checkpoint_that_keeps_the_scores_runs_each_forward_kernel_once_and_changes_no_bit(group, window):
+    """Under a layer checkpoint the backward needs (q, k, v, o, lse): q, k, v
+    are the layer's recomputation, o and lse only the kernel's second run
+    gives, unless the checkpoint keeps them (``KEEP_SCORES``): then every call
+    site's forward kernel stands once in the gradient, not twice, the backward
+    once either way, and the backward kernel receives the very arrays."""
+    sites = 1 if window is None else 2
+    plain, want = _checkpointed_stack(group, window, None)
+    kept, got = _checkpointed_stack(group, window, KEEP_SCORES)
+    assert _kernel_calls(plain) == (2 * sites, sites)
+    assert _kernel_calls(kept) == (sites, sites)
+    assert all(np.array_equal(a, b) for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+
+
+def test_the_policy_keeps_the_two_names_the_forward_rule_gives_and_no_other():
+    x = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(causal_attention(q, k, v, SCALE, 128, True)), argnums=(0, 1, 2)))(x, x, x))
+    named = dict(re.findall(r"(f32\[[\d,]+\]) = name\[name=(\w+)\]", text))
+    # the kernel's flat [B, T, H * D] float32 output, before its reshape, and the rows' logsumexp
+    assert named == {"f32[1,256,256]": SCORES_OUT, "f32[1,2,1,256]": SCORES_LSE}
+
+    def f(x):  # no step's derivative reads its own output: a step runs again only to feed a later one
+        a = checkpoint_name(x ** 3, SCORES_OUT)
+        b = checkpoint_name(jnp.cos(a), SCORES_LSE)
+        c = checkpoint_name(jnp.log1p(b), "causal_attention_other")
+        return jnp.sum(c ** 2)
+
+    runs = lambda policy: [  # noqa: E731
+        len(re.findall(op, str(jax.make_jaxpr(jax.grad(jax.checkpoint(f, policy=policy)))(
+            jnp.ones((4,)))))) for op in (r"integer_pow\[y=3\]", "= cos ", "= log1p ")]
+    assert runs(None) == [2, 2, 2]  # without a policy a name is the identity
+    assert runs(KEEP_SCORES) == [1, 1, 2]
+
+
+_SMALL = dict(hidden_size=64, seq_len=256, attn_block=128, loss_block=128, expert_block=128,
+              moe_intermediate_size=16, num_experts_per_tok=2, experts_held=4, vocab_size=64)
+# name -> (the model at shapes the kernel tiles, its call sites of the kernel, its checkpoint
+# sites, the counter of the latter): GLM's dense layer, scanned expert layer and MTP module;
+# Trinity's dense layer (its kind static) and a scan body of two branches; SmallThinker's body
+KEEPERS = {
+    "glm": (lambda: GlmMoeLite(GlmMoeLiteConfig(
+        **_SMALL, num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=64,
+        qk_rope_head_dim=64, v_head_dim=128, num_hidden_layers=3, first_k_dense_replace=1,
+        intermediate_size=32, n_routed_experts=8)), 3, 3, "model.mla.keep_scores_sites"),
+    "trinity": (lambda: Afmoe(AfmoeConfig(
+        **_SMALL, num_attention_heads=2, num_key_value_heads=1, head_dim=128, sliding_window=128,
+        layer_types=("sliding_attention", "full_attention", "sliding_attention"),
+        num_dense_layers=1, intermediate_size=32, num_experts=8)), 3, 2,
+        "model.attn.keep_scores_sites"),
+    "smallthinker": (lambda: SmallThinker(SmallThinkerConfig(
+        **_SMALL, num_attention_heads=7, num_key_value_heads=1, sliding_window=128,
+        layer_kinds=(0, 1, 1), num_experts=8)), 2, 1, "model.attn.keep_scores_sites"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KEEPERS))
+def test_every_token_models_checkpoints_keep_the_scores_and_count_themselves(monkeypatch, name):
+    """All three models took the policy (PR 36: each cell's superstep still
+    fits the chip and its step is shorter, ``PERF.md`` section 6): on a TPU the gradient of
+    ``apply`` holds each call site's forward kernel once, every checkpoint of
+    a layer carries ``KEEP_SCORES``, and the trace-time counter says how many."""
+    make, calls, sites, stat = KEEPERS[name]
+    model = make()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    before = STAT_GET(stat)
+    text = str(jax.make_jaxpr(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True))(
+        params, f32(1, 256, 64), f32(1, 256)))
+    assert _kernel_calls(text) == (calls, calls)
+    assert len(re.findall(r"policy=<function save_only_these_names", text)) == sites
+    assert STAT_GET(stat) - before == sites  # apply was traced once
+
+
 # ---- compiled for the chip, without the chip ---------------------------------------
 
 @pytest.fixture(scope="module")
@@ -405,3 +528,34 @@ def test_group_7_window_and_full_kernels_compile_for_a_v5e_at_the_smallthinker_c
         # no score block and no 7-fold k, v, dk or dv in HBM: the temporaries are the
         # float32 output and dq, the statistics, the row term and dk, dv at 4 heads
         assert compiled.memory_analysis().temp_size_in_bytes < 3 * 16384 * 28 * 128 * 4
+
+
+@pytest.mark.parametrize("kept", [True, False])
+def test_smallthinkers_loss_and_gradient_compile_for_a_v5e_with_each_forward_kernel_once(
+        one_chip, monkeypatch, kept):
+    """The cell's model (``benchmark/configs/smallthinker_21b_ep8.json``: one
+    record of 16,384 tokens, 4 layers, blocks of 4,096), loss and gradient of
+    every leaf and of the rows, the fused path forced: 4 kernel calls (a
+    window and a full forward, a window and a full backward: the scan body's
+    two branches) where the layer's checkpoint keeps o and the logsumexp, 6
+    (each forward twice) where it does not. What keeping costs: the stacked
+    ``[4, 1, 16384, 3584]`` float32 o, 0.94 GB, held once (5.99 GB of
+    temporaries against 3.84; 6.11 where the 4-D output is the one named)."""
+    from benchmark.models import smallthinker as build
+    from paddlebox_tpu.models import smallthinker
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "smallthinker_21b_ep8.json")) as f:
+        cfg = json.load(f)
+    model = build.build(cfg, 3 + cfg["embedx_dim"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if not kept:
+        monkeypatch.setattr(smallthinker, "KEEP_SCORES", None)
+    B, T, H = cfg["batch_size"], cfg["seq_len"], cfg["hidden_size"]
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(on, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    emb, ids = (jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in ((B, T, H), (B, T)))
+    compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
+        params, emb, ids).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == (4 if kept else 6)
+    assert compiled.memory_analysis().temp_size_in_bytes < (6.1e9 if kept else 3.9e9)  # 5.99 / 3.84 today
