@@ -33,7 +33,7 @@ from paddlebox_tpu.data.device_pack import BatchPacker, pack_batch, pack_batch_s
 from paddlebox_tpu.data.pipeline import prefetch
 from paddlebox_tpu.metrics.auc import auc_compute, auc_init
 from paddlebox_tpu.metrics.registry import MetricRegistry
-from paddlebox_tpu.obs.program_scopes import REGISTRY as PROGRAMS
+from paddlebox_tpu.obs.program_scopes import REGISTRY as PROGRAMS, memory_of
 from paddlebox_tpu.parallel.mesh import (
     MeshPlan,
     local_slice,
@@ -862,15 +862,17 @@ class CTRTrainer:
         return ss
 
     def _record_superstep(self, sstep, avals, eval_mode: bool) -> None:
-        """Once a superstep program has run: its instruction -> scope map
-        into the process's program registry (obs/program_scopes.py).
-        ``lower`` with the call's own avals hands back the executable the
-        call just built — nothing compiles a second time."""
+        """Once a superstep program has run: its instruction -> scope map,
+        the account of what the scopes leave out and the executable's own
+        memory figures into the process's program registry
+        (obs/program_scopes.py). ``lower`` with the call's own avals hands
+        back the executable the call just built — nothing compiles a second
+        time."""
         shape = "x".join(str(d) for d in avals[1].shape)
         name = f"superstep/{'eval' if eval_mode else 'train'}/{shape}"
         with PROFILER.record_event("superstep_scope_map", "pass"):
-            text = sstep.lower(*avals).compile().as_text()
-            PROGRAMS.record(name, "superstep", text)
+            compiled = sstep.lower(*avals).compile()
+            PROGRAMS.record(name, "superstep", compiled.as_text(), memory=memory_of(compiled))
 
     def _resident_stepper(
         self, dataset, n_batches, holder, eval_mode, profile, t_feed, t_disp, t_dev,

@@ -16,8 +16,9 @@ This CLI is the read side for all three:
   python tools/obs_report.py --merge-traces out.json rank0.json rank1.json ...
 
   # a jax.profiler device trace under the program's own names: device time
-  # by named scope, and the trainer's span over each of the longest idle
-  # gaps (SCOPES.json is REGISTRY.dump()'s file; default <trace>.scopes.json)
+  # by named scope, the time outside every scope by kind and by the scope it
+  # serves, and the trainer's span over each of the longest idle gaps
+  # (SCOPES.json is REGISTRY.dump()'s file; default <trace>.scopes.json)
   python tools/obs_report.py --device-trace TRACE.xplane.pb [SCOPES.json]
 
   # self-contained smoke of histogram/series/recorder/merge (verify drive)
@@ -288,10 +289,12 @@ def read_device_trace(path: str) -> dict:
 def device_trace_report(trace_path: str, scopes_path: Optional[str] = None) -> dict:
     """Device seconds by the program's named scopes over the whole trace,
     and its ten longest idle gaps under the innermost span that covered
-    each. The reduction is the benchmark's own (``trace_reduce.reduce``,
-    ``scope_times.scope_seconds``); this only reads the events and joins
-    them with the program registry's dump."""
-    from benchmark import scope_times, trace_reduce
+    each; under them the time outside every scope by kind and by the
+    scope it serves, and each program's own memory figures, where the dump
+    has them. The reduction is the benchmark's own (``trace_reduce.reduce``,
+    ``scope_times.scope_seconds``, ``unscoped_times.account``); this only
+    reads the events and joins them with the program registry's dump."""
+    from benchmark import scope_times, trace_reduce, unscoped_times
 
     if scopes_path is None:
         scopes_path = trace_path.removesuffix(".xplane.pb") + ".scopes.json"
@@ -312,10 +315,22 @@ def device_trace_report(trace_path: str, scopes_path: Optional[str] = None) -> d
                 if scope != scope_times.OTHER:  # what ran outside this program's executions
                     by_scope[scope] = by_scope.get(scope, 0.0) + sec / len(trace["devices"])
     by_scope[scope_times.OTHER] = max(red["busy_s"] - sum(by_scope.values()), 0.0)
+    # what the scopes leave out, by kind and by the scope it serves: the programs'
+    # own account (a dump of a build before it has none)
+    labelled: Dict[str, float] = {}
+    for prog in programs.values():
+        if prog.get("unscoped") is not None:
+            for label, sec in unscoped_times.by_label(
+                    trace, *window, prog, prog["fun_name"]).items():
+                if label != scope_times.OTHER:
+                    labelled[label] = labelled.get(label, 0.0) + sec
+    account = unscoped_times.account(labelled) if labelled else None
     return {
         "window_s": red["window_s"], "busy_s": red["busy_s"],
         "programs": {n: p["instructions"] for n, p in programs.items()},
         "scope_s": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])),
+        "unscoped_s": account and {"kinds": account["kinds"], "labels": account["labels"]},
+        "memory": {n: p["memory"] for n, p in programs.items() if p.get("memory")},
         "idle_gaps": red["idle_gaps"],
     }
 
@@ -431,6 +446,16 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
               f"programs: {rep['programs']}")
         for scope, sec in rep["scope_s"].items():
             print(f"  {100 * sec / rep['busy_s']:6.2f}%  {sec:.6f} s  {scope or '(no scope)'}")
+        if rep["unscoped_s"]:
+            print("outside every named scope, by kind:")
+            for kind, sec in rep["unscoped_s"]["kinds"].items():
+                print(f"  {100 * sec / rep['busy_s']:6.2f}%  {sec:.6f} s  {kind}")
+            print("  the largest, by kind|the scope it serves:")
+            for label, sec in list(rep["unscoped_s"]["labels"].items())[:12]:
+                print(f"  {100 * sec / rep['busy_s']:6.2f}%  {sec:.6f} s  {label}")
+        for name, memory in rep["memory"].items():
+            print(f"memory of {name}: " + ", ".join(
+                f"{k} {v / 1e9:.3f} GB" for k, v in memory.items()))
         print("longest idle gaps, by the span that covered each:")
         for span, sec in rep["idle_gaps"]:
             print(f"  {1e3 * sec:9.3f} ms  {span}")
