@@ -434,6 +434,31 @@ def memory_of(compiled) -> Dict[str, int]:
             if getattr(stats, attr, None) is not None}
 
 
+_HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}
+
+
+def table_layout_of(hlo_text: str, table) -> Dict[str, str]:
+    """The entry and the result layout of the pass table (``table``: its
+    shape and dtype) as a compiled superstep's text states them, e.g.
+    ``{"in": "{1,0:T(8,128)}", "out": "{1,0:T(8,128)}"}``: the first argument
+    and the first result of that shape in ``entry_computation_layout``. Equal
+    means the table crosses a dispatch as the loop carries it; {} where the
+    text names no such layout."""
+    head = hlo_text[: hlo_text.find("\n")]
+    at = head.find("entry_computation_layout={(")
+    cut = head.find(")->", at)
+    dtype = _HLO_DTYPE.get(str(table.dtype))
+    if at < 0 or cut < 0 or dtype is None:
+        return {}
+    shape = re.compile(
+        re.escape(f"{dtype}[{','.join(map(str, table.shape))}]") + r"(\{[^}]*\})"
+    )
+    found = shape.search(head, at, cut), shape.search(head, cut)
+    if None in found:
+        return {}
+    return {"in": found[0].group(1), "out": found[1].group(1)}
+
+
 class ProgramRegistry:
     """Scope maps and build times of the programs this process compiled,
     by the program's name. Plain data: survives ``jax.clear_caches()`` and
@@ -474,13 +499,15 @@ class ProgramRegistry:
                 build[key] = build.get(key, 0.0) + float(duration)
 
     def record(self, name: str, fun_name: str, hlo_text: str,
-               memory: Optional[Dict[str, int]] = None) -> dict:
+               memory: Optional[Dict[str, int]] = None,
+               table_layout: Optional[Dict[str, str]] = None) -> dict:
         scopes, unscoped = program_maps(hlo_text)
         with self._lock:
             build = self._build.get(fun_name)
             entry = {
                 "fun_name": fun_name, "instructions": len(scopes),
                 "scopes": scopes, "unscoped": unscoped, "memory": dict(memory or {}),
+                "table_layout": dict(table_layout or {}),
                 **(build or {}),
             }
             if build is not None:

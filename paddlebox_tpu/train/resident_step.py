@@ -36,6 +36,7 @@ import numpy as np
 
 from paddlebox_tpu import config
 from paddlebox_tpu.data.device_pack import _round_bucket
+from paddlebox_tpu.train.table_format import TableFormatProgram
 from paddlebox_tpu.train.train_step import TrainStepConfig, make_train_step
 from paddlebox_tpu.utils.trace import record_event
 
@@ -354,7 +355,9 @@ def make_resident_superstep(
 
     One dispatch runs K full train steps via lax.scan; metrics come back a
     batch at a time, a tuple of K dicts (per_batch_metrics). The per-step
-    body is the classic make_train_step — only batch assembly is resident."""
+    body is the classic make_train_step — only batch assembly is resident.
+    The table crosses a dispatch in the layout the scan carries it in
+    (train/table_format.py)."""
     raw_step = make_train_step(model_apply, dense_opt, cfg, eval_mode=eval_mode)
     if cfg.sequence_len and not np.all(rp._key_counts == cfg.sequence_len):
         # the rows reach the model as [B, T, embedx]: a record of another
@@ -372,7 +375,7 @@ def make_resident_superstep(
         state, mstack = jax.lax.scan(body, state, idx_block)
         return state, per_batch_metrics(mstack)
 
-    return jax.jit(superstep, donate_argnums=(0,))
+    return TableFormatProgram(superstep)
 
 
 # ---- resident pv (join-phase) tier -----------------------------------------
@@ -453,7 +456,7 @@ def make_resident_pv_superstep(
         state, mstack = jax.lax.scan(body, state, pos_block)
         return state, per_batch_metrics(mstack)
 
-    return jax.jit(superstep, donate_argnums=(0,))
+    return TableFormatProgram(superstep)
 
 
 def make_resident_pv_mesh_superstep(

@@ -33,7 +33,11 @@ from paddlebox_tpu.data.device_pack import BatchPacker, pack_batch, pack_batch_s
 from paddlebox_tpu.data.pipeline import prefetch
 from paddlebox_tpu.metrics.auc import auc_compute, auc_init
 from paddlebox_tpu.metrics.registry import MetricRegistry
-from paddlebox_tpu.obs.program_scopes import REGISTRY as PROGRAMS, memory_of
+from paddlebox_tpu.obs.program_scopes import (
+    REGISTRY as PROGRAMS,
+    memory_of,
+    table_layout_of,
+)
 from paddlebox_tpu.parallel.mesh import (
     MeshPlan,
     local_slice,
@@ -48,6 +52,11 @@ from paddlebox_tpu.train.sharded_step import (
 from paddlebox_tpu.train.resident_step import (
     ResidentPass,
     make_resident_superstep,
+)
+from paddlebox_tpu.train.table_format import (
+    aval_of as _aval_of,
+    jit_state_step,
+    put_table,
 )
 from paddlebox_tpu.train.train_step import (
     TrainState,
@@ -69,14 +78,6 @@ config.define_flag(
     "round-trip latency behind compute, shallow enough that transfers and "
     "executions don't pile up on the transport",
 )
-
-
-def _aval_of(x) -> jax.ShapeDtypeStruct:
-    """Shape, dtype and (where the array is committed to one) sharding: what
-    jit keys its executable on."""
-    return jax.ShapeDtypeStruct(
-        x.shape, x.dtype, sharding=x.sharding if x.committed else None
-    )
 
 
 class CTRTrainer:
@@ -145,6 +146,9 @@ class CTRTrainer:
         self.params: Any = None
         self.opt_state: Any = None
         self._state: Optional[TrainState] = None
+        self._table_src: Any = None  # a pass table on its way up (_table_up)
+        self._table_fmt: Any = None  # the format a superstep put it up in
+        self._table_steps: Dict = {}  # per-batch steps that keep such a format
         self._dense_in_place = False  # see hand_over_dense
         self._dense_with_pass = False  # handed over, and not (yet) returned
         # eval/infer mode (SetTestMode box_wrapper.cc:623 +
@@ -300,8 +304,6 @@ class CTRTrainer:
                 )
             self.init_params()
         if self.plan is None:
-            with PROFILER.record_event("state.upload", "pass"):
-                flat = jnp.asarray(dev_table.reshape(-1, dev_table.shape[-1]))
             # device COPIES of params/opt_state: the step donates its state,
             # so handing self.params's own buffers over would delete them —
             # a mid-pass save_dense or an aborted pass would then read dead
@@ -316,11 +318,12 @@ class CTRTrainer:
             else:
                 params = jax.tree.map(jnp.copy, params)
                 opt_state = jax.tree.map(jnp.copy, opt_state)
+            auc = auc_init(self.cfg.auc_buckets)
             state = TrainState(
-                table=flat,
+                table=None,
                 params=params,
                 opt_state=opt_state,
-                auc=auc_init(self.cfg.auc_buckets),
+                auc=auc,
                 step=jnp.zeros((), jnp.int32),
             )
             del params, opt_state
@@ -329,7 +332,22 @@ class CTRTrainer:
             # uncommitted fresh leaves; the step's outputs are then all
             # committed, so the second dispatch would see a new argument
             # signature and compile the whole scan program a second time
-            return jax.device_put(state, next(iter(flat.devices())))
+            device = next(iter(
+                (dev_table if isinstance(dev_table, jax.Array) else auc.pos).devices()
+            ))
+            state = jax.device_put(state, device)
+            # the table itself goes up on its way into the first program
+            # that takes it, in that program's format (_table_up): until
+            # then its leaf says only what it will be
+            self._table_src = dev_table
+            self._table_fmt = None
+            return state._replace(
+                table=jax.ShapeDtypeStruct(
+                    (dev_table.size // dev_table.shape[-1], dev_table.shape[-1]),
+                    dev_table.dtype,
+                    sharding=jax.sharding.SingleDeviceSharding(device),
+                )
+            )
         return init_sharded_train_state(
             self.plan,
             dev_table,
@@ -339,6 +357,36 @@ class CTRTrainer:
             opt_state=self.opt_state,
             local_dense=self.cfg.dense_sync_mode == "kstep",
         )
+
+    def _table_up(self, state: TrainState, fmt=None) -> TrainState:
+        """The pass table, where it is not up yet, goes up in ``fmt``: the
+        format the compiled superstep about to take it carries it in, so
+        that no dispatch of the pass copies it (None, for the per-batch
+        step: the default layout). One format for the pass's life: every
+        later program is built for the format the table has."""
+        if isinstance(state.table, jax.Array):
+            return state
+        with PROFILER.record_event("state.upload", "pass"):
+            table = put_table(self._table_src, next(iter(state.step.devices())), fmt)
+        self._table_src, self._table_fmt = None, fmt
+        return state._replace(table=table)
+
+    def _table_step(self, eval_mode: bool):
+        """The one-chip per-batch step for this pass's table: the plain jit
+        where the table lies in the default layout, else one that hands the
+        table back in the format a superstep put it up in."""
+        fmt = self._table_fmt
+        if fmt is None:
+            return self._eval_step() if eval_mode else self._step
+        step = self._table_steps.get((eval_mode, fmt))
+        if step is None:
+            step = self._table_steps[(eval_mode, fmt)] = jit_state_step(
+                make_train_step(
+                    self.model.apply, self.dense_opt, self.cfg, eval_mode=eval_mode
+                ),
+                fmt_out=fmt,
+            )
+        return step
 
     @property
     def _n_pack_devices(self) -> int:
@@ -695,6 +743,7 @@ class CTRTrainer:
 
         max_inflight = config.get_flag("max_inflight_steps")
         inflight: deque = deque()
+        holder["state"] = self._table_up(holder["state"])
         it = iter(iterator)
         i = 0
         while True:
@@ -863,16 +912,21 @@ class CTRTrainer:
 
     def _record_superstep(self, sstep, avals, eval_mode: bool) -> None:
         """Once a superstep program has run: its instruction -> scope map,
-        the account of what the scopes leave out and the executable's own
-        memory figures into the process's program registry
-        (obs/program_scopes.py). ``lower`` with the call's own avals hands
-        back the executable the call just built — nothing compiles a second
-        time."""
+        the account of what the scopes leave out, the executable's own
+        memory figures and the table's entry and result layout into the
+        process's program registry (obs/program_scopes.py). ``sstep`` is the
+        executable that ran (one chip), or a mesh superstep whose ``lower``
+        with the call's own avals hands back the executable the call just
+        built — nothing compiles a second time."""
         shape = "x".join(str(d) for d in avals[1].shape)
         name = f"superstep/{'eval' if eval_mode else 'train'}/{shape}"
         with PROFILER.record_event("superstep_scope_map", "pass"):
-            compiled = sstep.lower(*avals).compile()
-            PROGRAMS.record(name, "superstep", compiled.as_text(), memory=memory_of(compiled))
+            compiled = sstep if self.plan is None else sstep.lower(*avals).compile()
+            text = compiled.as_text()
+            PROGRAMS.record(
+                name, "superstep", text, memory=memory_of(compiled),
+                table_layout=table_layout_of(text, avals[0].table),
+            )
 
     def _resident_stepper(
         self, dataset, n_batches, holder, eval_mode, profile, t_feed, t_disp, t_dev,
@@ -976,11 +1030,17 @@ class CTRTrainer:
                     avals = jax.tree.map(_aval_of, (holder["state"], idx_dev))
                 t_disp.start()
                 with PROFILER.record_event("superstep_dispatch", "pass"):
-                    holder["state"], per_batch = sstep(holder["state"], idx_dev)
+                    run = sstep
+                    if self.plan is None:
+                        # built ahead of its first call: the table goes up
+                        # in the format this program carries it in
+                        run, fmt = sstep.executable(holder["state"], idx_dev)
+                        holder["state"] = self._table_up(holder["state"], fmt)
+                    holder["state"], per_batch = run(holder["state"], idx_dev)
                 t_disp.pause()
                 if avals is not None:
                     self._sstep_recorded.add((id(sstep), idx_dev.shape))
-                    self._record_superstep(sstep, avals, eval_mode)
+                    self._record_superstep(run, avals, eval_mode)
                 if profile:
                     t_dev.start()
                     with PROFILER.record_event("device_superstep", "device"):
@@ -1164,13 +1224,13 @@ class CTRTrainer:
             step_fn = None
         elif use_pv:
             iterator = self._pv_feed_iter(dataset, n_batches)
-            step_fn = self._eval_step() if eval_mode else self._step
+            step_fn = self._table_step(eval_mode)
         elif dataset.store is not None:
             iterator = self._fast_feed_iter(dataset, n_batches)
-            step_fn = self._eval_step() if eval_mode else self._step
+            step_fn = self._table_step(eval_mode)
         else:
             iterator = self._slow_feed_iter(dataset, n_batches)
-            step_fn = self._eval_step() if eval_mode else self._step
+            step_fn = self._table_step(eval_mode)
         if self.plan is not None and jax.process_count() > 1:
             if dataset.store is None:
                 raise RuntimeError(
@@ -1240,12 +1300,16 @@ class CTRTrainer:
                 alive = not st.table.is_deleted()
             except AttributeError:
                 pass  # host-side array: always alive
-            self._state = st if alive else None
+            # a table that never went up (_table_up) is no state to cache:
+            # the retry rebuilds from the dataset's pass-open table too
+            self._state = st if alive and self._table_src is None else None
+            self._table_src = None
             if self._dense_in_place and alive:  # handed over, and it survived
                 self.params, self.opt_state = st.params, st.opt_state
                 self._dense_with_pass = False
             raise
-        state = holder["state"]
+        # a call that ran no batch still leaves a table for writeback
+        state = self._table_up(holder["state"])
         # persist dense side for the next pass; state.table stays for writeback
         if eval_mode:
             # values are bit-identical, but the OLD buffers were donated into

@@ -20,16 +20,31 @@ registers a ``jax.monitoring`` listener ONCE per process; hit/miss/request
 counters surface as ``compile_cache.*`` stats and through :func:`stats`,
 which ``chip_smoke.py`` and ``benchmark/run.py`` embed in their JSON so a cold run
 (hits == 0) and a warm run (hits > 0, shorter warm-up) are distinguishable.
+
+**What the cache must not serve** (jax 0.9.0, found on the chip and on the CPU,
+PR 38): an executable read back from the cache has lost every argument and
+result layout that was not the device's default. It takes a buffer laid out
+as the program asked for one in the default layout (the TPU refuses the size,
+the CPU computes on the wrong elements) and labels its results likewise. So a
+program that carries such a layout is built under :func:`bypassed`, and a
+process that holds an array in one (a pass table in the layout its loop
+carries it in, ``train/table_format.py``) calls :func:`suspend`: from then on
+every eager reader of that array would otherwise write, and a later process
+read, an entry of that kind.
 """
 
 from __future__ import annotations
 
+import contextlib
+import logging
 import os
 import threading
 from typing import Dict, Optional
 
 from paddlebox_tpu import config
-from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_GET
+from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_GET, STAT_SET
+
+logger = logging.getLogger(__name__)
 
 _ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_DIR = os.path.join(
@@ -113,7 +128,43 @@ def disable() -> None:
         jax.config.update("jax_compilation_cache_dir", None)
     with _lock:
         _state["dir"] = None
+    jax.config.update("jax_enable_compilation_cache", True)  # a suspension ends here
     compilation_cache.reset_cache()
+
+
+def _use_cache(on: bool) -> None:
+    import jax
+    from jax._src import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", on)
+    compilation_cache.reset_cache()  # jax latches its answer at the first compile
+
+
+def suspend(reason: str) -> None:
+    """No program of this process is read from or written to the cache from
+    now on (see the module's note on layouts). :func:`disable` lifts it."""
+    import jax
+
+    if jax.config.jax_enable_compilation_cache:
+        _use_cache(False)
+        STAT_SET("compile_cache.suspended", 1)
+        logger.info("persistent compile cache off for this process: %s", reason)
+
+
+@contextlib.contextmanager
+def bypassed():
+    """What compiles inside is neither read from nor written to the cache.
+    Process-wide while it lasts: a compile on another thread misses too."""
+    import jax
+
+    was_on = bool(jax.config.jax_enable_compilation_cache)
+    if was_on:
+        _use_cache(False)
+    try:
+        yield
+    finally:
+        if was_on:
+            _use_cache(True)
 
 
 def stats() -> Dict:

@@ -331,6 +331,8 @@ def device_trace_report(trace_path: str, scopes_path: Optional[str] = None) -> d
         "scope_s": dict(sorted(by_scope.items(), key=lambda kv: -kv[1])),
         "unscoped_s": account and {"kinds": account["kinds"], "labels": account["labels"]},
         "memory": {n: p["memory"] for n, p in programs.items() if p.get("memory")},
+        "table_layout": {n: p["table_layout"] for n, p in programs.items()
+                         if p.get("table_layout")},
         "idle_gaps": red["idle_gaps"],
     }
 
@@ -456,6 +458,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         for name, memory in rep["memory"].items():
             print(f"memory of {name}: " + ", ".join(
                 f"{k} {v / 1e9:.3f} GB" for k, v in memory.items()))
+        for name, lay in rep["table_layout"].items():
+            print(f"pass table of {name}: enters as {lay['in']}, leaves as {lay['out']}")
         print("longest idle gaps, by the span that covered each:")
         for span, sec in rep["idle_gaps"]:
             print(f"  {1e3 * sec:9.3f} ms  {span}")
