@@ -7,6 +7,7 @@ from paddlebox_tpu.models.rank import RankDeepFM
 from paddlebox_tpu.models.glm_moe_lite import GlmMoeLite, GlmMoeLiteConfig
 from paddlebox_tpu.models.afmoe import Afmoe, AfmoeConfig
 from paddlebox_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
+from paddlebox_tpu.models.sdar import Sdar, SdarConfig
 
 __all__ = [
     "mlp_init",
@@ -26,4 +27,6 @@ __all__ = [
     "AfmoeConfig",
     "SmallThinker",
     "SmallThinkerConfig",
+    "Sdar",
+    "SdarConfig",
 ]

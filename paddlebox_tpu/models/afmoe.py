@@ -4,7 +4,8 @@ stack, routed experts beside a shared one; a language model trained through
 the pass path, the second ``SequenceLossModel`` (``models/base.py``) beside
 ``models/glm_moe_lite.py``, whose pieces it shares (``rms_norm``, rope,
 ``_mm``, ``swiglu``, ``route``, ``routed_experts``, ``head_logits``: an
-optimisation of one is measured on both).
+optimisation of one is measured on both, and on ``models/smallthinker.py``
+and ``models/sdar.py``, the third and fourth, which share pieces of this one).
 
 The step hands it the pulled rows of the one token slot unpooled, as
 ``[B, T, hidden]`` in record order, and the record's dense slot of T token

@@ -1,6 +1,7 @@
 """GLM-4.7-Flash (``glm4_moe_lite``): latent attention, routed experts beside
 a shared expert, one multi-token-prediction module; a language model trained
-through the pass path.
+through the pass path, the first of four (``models/afmoe.py``,
+``models/smallthinker.py`` and ``models/sdar.py`` import its pieces).
 
 The model is a *sequence model that owns its loss* (``models/base.py``): the
 step hands it the pulled rows of the one token slot unpooled, as

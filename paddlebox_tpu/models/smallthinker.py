@@ -7,7 +7,8 @@ path, the third ``SequenceLossModel`` (``models/base.py``) beside
 (``rms_norm``, rope, ``_mm``, ``route``, ``routed_experts``, ``head_logits``
 from the first; the scores part ``_scores``, with its kernel, its blocked form
 and its trace-time counters, and the loss with its two parts and five counters
-from the second: an optimisation of one is measured on all three).
+from the second: an optimisation of one is measured on all three, and on
+``models/sdar.py``, the fourth, which takes ``share_counters`` from here).
 
 The step hands it the pulled rows of the one token slot unpooled, as
 ``[B, T, hidden]`` in record order, and the record's dense slot of T token
@@ -136,6 +137,17 @@ def layer(p, x, c: SmallThinkerConfig, rope, sliding, scope: str = "model"):
         return h + routed.reshape(B, T, H), idx.reshape(B, T, -1), counts
 
 
+def share_counters(out, c) -> list:
+    """``unrouted_tokens`` and ``block_rows`` of one batch, from what
+    ``forward`` gave: the (token, layer) pairs none of whose chosen experts is
+    held, and the rows of the grouped product's blocks in use (padding
+    included), all layers (``sdar`` shares them)."""
+    local = out["router_choices"] - c.experts_offset
+    held = jnp.any((local >= 0) & (local < c.experts_held), axis=-1)
+    R = c.expert_block
+    return [jnp.sum(~held).astype(F32), jnp.sum(-(-out["loads"] // R) * R).astype(F32)]
+
+
 class SmallThinker:
     """``apply(params, emb [B, T, H], ids [B, T]) -> (loss, {"counters": [7]})``;
     ``forward`` gives the logit terms and expert choices behind it."""
@@ -215,17 +227,10 @@ class SmallThinker:
     def apply(self, params, emb, ids):
         """The training loss of one batch (``forward``'s arguments) and the
         one array the step carries out beside it: ``counters``, named by
-        ``counter_names``. ``unrouted_tokens``: the (token, layer) pairs none
-        of whose chosen experts is held; ``block_rows``: the rows of the
-        grouped product's blocks in use (padding included), all layers."""
-        c = self.cfg
+        ``counter_names`` (``share_counters`` has the last two)."""
         out = self.forward(params, emb, ids)
         with jax.named_scope("loss/head"):
-            local = out["router_choices"] - c.experts_offset
-            held = jnp.any((local >= 0) & (local < c.experts_held), axis=-1)
-            R = c.expert_block
-            counters = jnp.stack(afmoe.window_counters(out, emb) + [
-                jnp.sum(~held).astype(F32), jnp.sum(-(-out["loads"] // R) * R).astype(F32)])
+            counters = jnp.stack(afmoe.window_counters(out, emb) + share_counters(out, self.cfg))
         return out["loss"], {"counters": lax.stop_gradient(counters)}
 
     @staticmethod

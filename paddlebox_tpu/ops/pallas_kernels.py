@@ -1,6 +1,7 @@
 """Pallas TPU kernels on a cell's path: the fused causal attention of
 ``models/glm_moe_lite.py`` (the token cell, since PR 29) and of
-``models/afmoe.py`` (grouped-query heads, window and full layers, since PR 33).
+``models/afmoe.py`` (grouped-query heads, window and full layers, since PR 33),
+and under the block-diffusion training mask of ``models/sdar.py`` (PR 39).
 
 A kernel lives here when a call site chooses it from what it can observe
 (backend and shapes: ``models/glm_moe_lite.py::fused_scores``,
@@ -48,6 +49,19 @@ LANE = 128  # Mosaic lane width
 # query tile can see and no further (key tile i - band .. i forward, query tile
 # j .. j + band backward): the tile on the band's edge is masked (it shows what
 # the diagonal tile hides), those past an end of the sequence run empty.
+#
+# A third static form of the mask (PR 39), absent from every call that does not
+# name it. ``diffusion_block``: the sequence is two halves of L = T / 2, the
+# clean tokens and their noised copy, both at positions 0 .. L - 1 in blocks of
+# ``diffusion_block``; a clean query sees the clean keys of its own block and
+# of the earlier ones, a noisy query the clean keys of the earlier blocks and
+# the noisy keys of its own block (``diffusion_visible``). With n tiles a half:
+# forward, query tile I walks clean key tiles 0 .. I, and in the noisy half
+# then its own tile (an inner axis of n + 1; three masks inside a tile, by
+# block, where the causal form has one); backward, clean key tile J gathers
+# from the query tiles J .. n - 1 of both halves, and the last step of its
+# inner axis (2 n + 1) is noisy key tile n + J against the one query tile that
+# sees it. At n = 16: 288 tiles a head, against 528 of a causal 2 L.
 
 _MASKED = -1e30  # what a score above the diagonal is set to (exp gives 0.0)
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -70,7 +84,29 @@ def _above_diagonal(blk: int, keys_first: bool):
     return key > query
 
 
-def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale, band):
+def diffusion_visible(u, w, L: int, block: int):
+    """Whether key index w is visible to query index u under the
+    block-diffusion training mask over [clean | noisy], two halves of L
+    positions in blocks of ``block`` (arrays that broadcast, or numbers)."""
+    bu, bw = u % L // block, w % L // block
+    return (w < L) & (bw <= bu - (u >= L)) | (u >= L) & (w >= L) & (bw == bu)
+
+
+# what a tile on a half's diagonal hides, by block: of a clean query tile the later blocks, of a
+# noisy one against its clean twin its own block too, against itself every block but its own
+_HIDDEN = {"later": lambda key, query: key > query, "own_and_later": lambda key, query: key >= query,
+           "others": lambda key, query: key != query}
+
+
+def _hidden_blocks(blk: int, keys_first: bool, block: int, rule: str):
+    """[blk, blk] bool of a tile whose queries and keys start at the same position of their halves."""
+    key = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0 if keys_first else 1)
+    query = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1 if keys_first else 0)
+    return _HIDDEN[rule](jax.lax.div(key, block), jax.lax.div(query, block))
+
+
+def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale, band,
+                          diffusion=None):
     i, j = pl.program_id(2), pl.program_id(3)  # query tile, key tile
     blk, width = acc_sc.shape
 
@@ -80,13 +116,15 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_s
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    def step(diagonal: bool, edge: bool = False):
+    def step(diagonal: bool, edge: bool = False, hidden=None):
         s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
                                 preferred_element_type=jnp.float32) * scale
         if diagonal:
             s = jnp.where(_above_diagonal(blk, keys_first=False), _MASKED, s)
         if edge:  # a whole band behind: the keys still inside it are those the diagonal hides
             s = jnp.where(_above_diagonal(blk, keys_first=False), s, _MASKED)
+        if hidden:
+            s = jnp.where(_hidden_blocks(blk, False, diffusion[0], hidden), _MASKED, s)
         m_prev = m_sc[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -96,7 +134,16 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_s
         acc_sc[...] = acc_sc[...] * _lanes(alpha, width) + jnp.dot(
             p.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32)
 
-    if band is None:
+    if diffusion is not None:  # clean key tiles 0 .. i mod n, then a noisy query tile's own
+        n = diffusion[1]
+        noisy = i >= n
+        twin = i - jnp.where(noisy, n, 0)
+        pl.when(j < twin)(lambda: step(False))
+        pl.when((j == twin) & jnp.logical_not(noisy))(lambda: step(False, hidden="later"))
+        # rows of the first block see no clean key: what they gather here the last step wipes
+        pl.when((j == twin) & noisy)(lambda: step(False, hidden="own_and_later"))
+        pl.when((j == n) & noisy)(lambda: step(False, hidden="others"))
+    elif band is None:
         pl.when(j < i)(lambda: step(False))
         pl.when(j == i)(lambda: step(True))
     else:  # the inner axis is the band's: key tile i - band .. i, those before the sequence empty
@@ -112,6 +159,19 @@ def _attention_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_s
         lse_ref[...] = (m_sc[...] + jnp.log(l)).T[:1]  # the rows' statistic as one lane-dense row
 
 
+def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, scale, hide):
+    """One tile of the backward, scores transposed: (dq [queries, D], dk, dv
+    [keys, D]) float32; ``hide`` masks the scaled scores [keys, queries]."""
+    q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+    pt = jnp.exp(hide(jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale)
+                 - lse_ref[...])
+    dv = jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+    dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+    dst = (pt * (dpt - di_ref[...]) * scale).astype(q.dtype)
+    dk = jnp.dot(dst, q, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(dst, k, _TN, preferred_element_type=jnp.float32), dk, dv
+
+
 def _attention_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                           dq_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, scale, band, group):
     """Scores transposed, [keys, queries]: the rows' statistics are then rows
@@ -125,18 +185,14 @@ def _attention_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         i = j + i
 
     def step(diagonal: bool, edge: bool = False):
-        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
-        st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if diagonal:
-            st = jnp.where(_above_diagonal(blk, keys_first=True), _MASKED, st)
-        if edge:
-            st = jnp.where(_above_diagonal(blk, keys_first=True), st, _MASKED)
-        pt = jnp.exp(st - lse_ref[...])
-        dv = jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
-        dst = (pt * (dpt - di_ref[...]) * scale).astype(q.dtype)
-        dk = jnp.dot(dst, q, preferred_element_type=jnp.float32)
-        dq = jax.lax.dot_general(dst, k, _TN, preferred_element_type=jnp.float32)
+        def hide(st):
+            if diagonal:
+                st = jnp.where(_above_diagonal(blk, keys_first=True), _MASKED, st)
+            if edge:
+                st = jnp.where(_above_diagonal(blk, keys_first=True), st, _MASKED)
+            return st
+
+        dq, dk, dv = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, scale, hide)
         rows = pl.ds(pl.multiple_of(i * blk, blk), blk)
         if diagonal:  # the first query tile that sees this key tile
             dk_sc[...], dv_sc[...] = dk, dv
@@ -172,16 +228,69 @@ def _attention_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
             dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
             return
         # the key-value head's float32 dk, dv stay in VMEM while its query heads pass
-        keys = pl.ds(pl.multiple_of(j * blk, blk), blk)
+        _add_keys(dk_ref, dv_ref, j, blk, lambda: dk_sc[...], lambda: dv_sc[...], first_of_group)
 
-        @pl.when(first_of_group)
-        def _():
-            dk_ref[keys, :], dv_ref[keys, :] = dk_sc[...], dv_sc[...]
 
-        @pl.when(jnp.logical_not(first_of_group))
+def _add_keys(dk_ref, dv_ref, tile, blk: int, dk, dv, first_of_group):
+    """Key tile ``tile``'s dk(), dv() [blk, D] into the key-value head's whole-T
+    blocks: set by the group's first query head, added by the others."""
+    keys = pl.ds(pl.multiple_of(tile * blk, blk), blk)
+
+    @pl.when(first_of_group)
+    def _():
+        dk_ref[keys, :], dv_ref[keys, :] = dk(), dv()
+
+    @pl.when(jnp.logical_not(first_of_group))
+    def _():
+        dk_ref[keys, :] += dk()
+        dv_ref[keys, :] += dv()
+
+
+def _diffusion_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                          dq_ref, dk_ref, dv_ref, dk_sc, dv_sc, *, scale, group, block, n):
+    """The backward under the block-diffusion mask: clean key tile j of n
+    against query tile i of the 2 n that see it (i mod n >= j), then, the
+    inner axis' last step, noisy key tile n + j against query tile n + j. dq
+    and the key-value head's dk, dv are whole-T float32 blocks in VMEM."""
+    j, i = pl.program_id(2), pl.program_id(3)
+    blk = q_ref.shape[0]
+    first_of_group = pl.program_id(1) % group == 0
+
+    def tile(rule, query_tile):
+        hide = lambda st: st if rule is None else jnp.where(  # noqa: E731
+            _hidden_blocks(blk, True, block, rule), _MASKED, st)
+        dq, dk, dv = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, scale, hide)
+        return dq, dk, dv, pl.ds(pl.multiple_of(query_tile * blk, blk), blk)
+
+    def step(rule):
+        dq, dk, dv, rows = tile(rule, i)
+        if rule == "later":  # the first query tile that sees this key tile
+            dk_sc[...], dv_sc[...] = dk, dv
+        else:
+            dk_sc[...] += dk
+            dv_sc[...] += dv
+
+        @pl.when(j == 0)  # every query tile meets clean key tile 0 first
         def _():
-            dk_ref[keys, :] += dk_sc[...]
-            dv_ref[keys, :] += dv_sc[...]
+            dq_ref[rows, :] = dq
+
+        @pl.when(j != 0)
+        def _():
+            dq_ref[rows, :] += dq
+
+    pl.when((i < 2 * n) & (i % n > j))(lambda: step(None))
+    pl.when(i == j)(lambda: step("later"))
+    pl.when(i == n + j)(lambda: step("own_and_later"))
+
+    @pl.when(i == 2 * n - 1)  # the clean key tile is complete
+    def _():
+        _add_keys(dk_ref, dv_ref, j, blk, lambda: dk_sc[...], lambda: dv_sc[...], first_of_group)
+
+    @pl.when(i == 2 * n)  # the noisy key tile, seen by its own query tile alone
+    def _():
+        dq, dk, dv, rows = tile("others", n + j)
+        dq_ref[rows, :] += dq
+        _add_keys(dk_ref, dv_ref, n + j, blk, lambda: dk, lambda: dv, first_of_group)
 
 
 def _flat(a):
@@ -212,19 +321,34 @@ def _grouped_params(group: int):
         vmem_limit_bytes=_ATTENTION_PARAMS.vmem_limit_bytes)
 
 
-def _attention_fwd(q, k, v, scale, blk, interpret, group, window):
+def _halves(diffusion_block, T: int, blk: int, window):
+    """Tiles a half under the block-diffusion mask."""
+    if window is not None or T % (2 * blk) or blk % diffusion_block:
+        raise ValueError(f"diffusion blocks of {diffusion_block} in tiles of {blk} over {T}, "
+                         f"window {window}")
+    return T // blk // 2
+
+
+def _attention_fwd(q, k, v, scale, blk, interpret, group, window, diffusion_block=None):
     B, T, H, D = q.shape
     band, n, kv_head = _band(window, T, blk), T // blk, _kv_head(group)
     q_spec = pl.BlockSpec((None, blk, D), lambda b, h, i, j: (b, i, h))
-    if band is None:
+    kernel = functools.partial(_attention_fwd_kernel, scale=scale, band=band)
+    if diffusion_block is not None:
+        half = _halves(diffusion_block, T, blk, window)
+        kernel = functools.partial(kernel, diffusion=(diffusion_block, half))
+        # a skipped step names the tile it holds; a noisy query tile's last step its own tile
+        k_tile = lambda i, j: jnp.where(  # noqa: E731
+            (i >= half) & (j == half), i, jnp.minimum(j, i % half))
+    elif band is None:
         # a skipped step (j > i) names the tile it already holds
         k_tile = lambda i, j: jnp.minimum(j, i)  # noqa: E731
     else:  # a step before the sequence names the first tile the row needs
         k_tile = lambda i, j: jnp.maximum(i - band + j, 0)  # noqa: E731
     kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, i, j: (b, k_tile(i, j), kv_head(h)))
+    inner = half + 1 if diffusion_block is not None else n if band is None else band + 1
     o, lse = pl.pallas_call(
-        functools.partial(_attention_fwd_kernel, scale=scale, band=band),
-        grid=(B, H, n, n if band is None else band + 1),
+        kernel, grid=(B, H, n, inner),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, pl.BlockSpec((None, None, 1, blk), lambda b, h, i, j: (b, h, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((B, T, H * D), jnp.float32),
@@ -236,27 +360,38 @@ def _attention_fwd(q, k, v, scale, blk, interpret, group, window):
     return o, lse  # o as the kernel writes it: [B, T, H * D]
 
 
-def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret, group, window):
+def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret, group, window, diffusion_block=None):
     B, T, H, D = q.shape
     band, n, kv_head = _band(window, T, blk), T // blk, _kv_head(group)
     # the row term of the softmax's transpose, sum(dp * p) = sum(do * o), from the float32 output
     di = jnp.sum(do.astype(jnp.float32) * o, axis=-1).transpose(0, 2, 1).reshape(B, H, 1, T)
-    if band is None:
+    kernel = functools.partial(_attention_bwd_kernel, scale=scale, band=band, group=group)
+    k_tile = lambda j, i: j  # noqa: E731
+    if diffusion_block is not None:
+        half = _halves(diffusion_block, T, blk, window)
+        kernel = functools.partial(_diffusion_bwd_kernel, scale=scale, group=group,
+                                   block=diffusion_block, n=half)
+        n, last = half, 2 * half  # key tiles of a half; the step of the noisy key tile n + j
+        # a skipped step names the tiles the next step of its half is about to need
+        q_tile = lambda j, i: jnp.where(  # noqa: E731
+            i == last, half + j, jnp.maximum(i, j + jnp.where(i >= half, half, 0)))
+        k_tile = lambda j, i: j + jnp.where(i == last, half, 0)  # noqa: E731
+    elif band is None:
         # a skipped step (i < j) names the tiles the diagonal step is about to need
         q_tile = lambda j, i: jnp.maximum(i, j)  # noqa: E731
     else:  # a step past the sequence names the tiles it already holds
         q_tile = lambda j, i: jnp.minimum(j + i, n - 1)  # noqa: E731
     q_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, q_tile(j, i), h))
     row_spec = pl.BlockSpec((None, None, 1, blk), lambda b, h, j, i: (b, h, 0, q_tile(j, i)))
-    kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, j, kv_head(h)))
-    if group == 1:
+    kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, k_tile(j, i), kv_head(h)))
+    if group == 1 and diffusion_block is None:
         dkv_spec, dkv_dtypes = kv_spec, (k.dtype, v.dtype)
     else:  # float32 and whole, resident for the key-value head's ``group`` query heads
         dkv_spec = pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, kv_head(h)))
         dkv_dtypes = (jnp.float32, jnp.float32)
+    inner = 2 * n + 1 if diffusion_block is not None else n if band is None else band + 1
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_attention_bwd_kernel, scale=scale, band=band, group=group),
-        grid=(B, H, n, n if band is None else band + 1),
+        kernel, grid=(B, H, n, inner),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, h)), dkv_spec, dkv_spec],
         out_shape=[jax.ShapeDtypeStruct((B, T, H * D), jnp.float32),
@@ -269,16 +404,20 @@ def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret, group, window):
             dv.astype(v.dtype).reshape(v.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def causal_attention(q, k, v, scale: float, block: int, interpret: bool = False,
-                     group: int = 1, window=None):
+                     group: int = 1, window=None, diffusion_block=None):
     """q [B, T, H, D], k, v [B, T, H / group, D] bfloat16 -> softmax(q k^T *
     scale, causal) v [B, T, H, D] float32, in tiles of ``block`` queries by
     ``block`` keys; query head h attends key-value head h // ``group``, and
     with a ``window`` only to the keys less than ``window`` behind it. T and
-    ``window`` multiples of ``block``, ``block`` and D multiples of 128. The
-    cotangents of q, k and v come back in their dtype."""
-    return _attention_fwd(q, k, v, scale, block, interpret, group, window)[0].reshape(q.shape)
+    ``window`` multiples of ``block``, ``block`` and D multiples of 128. With
+    a ``diffusion_block`` the visible keys are not the causal prefix but
+    ``diffusion_visible``'s: T is two halves of whole tiles, in blocks of
+    ``diffusion_block`` that divide the tile; no window then. The cotangents
+    of q, k and v come back in their dtype."""
+    return _attention_fwd(q, k, v, scale, block, interpret, group, window,
+                          diffusion_block)[0].reshape(q.shape)
 
 
 # The two of the backward's residuals that only the forward kernel can give (q, k, v are a
@@ -288,14 +427,14 @@ SCORES_OUT, SCORES_LSE = "causal_attention_out", "causal_attention_lse"
 KEEP_SCORES = jax.checkpoint_policies.save_only_these_names(SCORES_OUT, SCORES_LSE)
 
 
-def _causal_attention_fwd(q, k, v, scale, block, interpret, group, window):
-    o, lse = _attention_fwd(q, k, v, scale, block, interpret, group, window)
+def _causal_attention_fwd(q, k, v, scale, block, interpret, group, window, diffusion_block):
+    o, lse = _attention_fwd(q, k, v, scale, block, interpret, group, window, diffusion_block)
     o, lse = checkpoint_name(o, SCORES_OUT).reshape(q.shape), checkpoint_name(lse, SCORES_LSE)
     return o, (q, k, v, o, lse)
 
 
-def _causal_attention_bwd(scale, block, interpret, group, window, res, do):
-    return _attention_bwd(*res, do, scale, block, interpret, group, window)
+def _causal_attention_bwd(scale, block, interpret, group, window, diffusion_block, res, do):
+    return _attention_bwd(*res, do, scale, block, interpret, group, window, diffusion_block)
 
 
 causal_attention.defvjp(_causal_attention_fwd, _causal_attention_bwd)

@@ -6,7 +6,9 @@ between the two, its counters, and the kernels compiled at the token cell's
 shapes for a described v5e. Since PR 33 also with grouped-query heads and a
 window (``models/afmoe.py``), against that model's blocked form, and GLM's call
 held to the kernel it had before; since PR 35 at a group that is no power of
-two (7, ``models/smallthinker.py``) and at that model's 16k shapes."""
+two (7, ``models/smallthinker.py``) and at that model's 16k shapes; since PR 39
+under the block-diffusion mask (``diffusion_block``, ``models/sdar.py``), with
+all three older calls held to the kernels they had."""
 
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 
-from paddlebox_tpu.models import afmoe  # noqa: E402
+from paddlebox_tpu.models import afmoe, sdar  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
 from paddlebox_tpu.models import (  # noqa: E402
     Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, SmallThinker, SmallThinkerConfig)
@@ -233,6 +235,30 @@ def test_glms_call_lowers_to_the_kernel_it_had_before_group_and_window():
     assert digest == "68b3e6182885371b39f558d6453dc3759b471777a6c52d6a50dc89695a33ecc5"
 
 
+# (group, window) -> digest, taken from PR 38's tree (commit c0630af) with these lines: Trinity's
+# calls (8; its window and full layers) and SmallThinker's (7), at a window of two tiles
+OLDER_CALLS = {
+    (8, 256): "1cdb572ad6df912a7b259d7f06fa32fe00563a8f3835df42e46f9bc10c5994ff",
+    (8, None): "13eaabbaf559956f4b73cea458ca9022dabc6eabc098446a4787def57ba65e10",
+    (7, 256): "bb9c3a0137f37b64805f475a0adee9e5dc80402cfd4c93e3ba6c497bba9c872e",
+    (7, None): "318257848b581a83927ad376b322ec5d5dfc291955297f17357d5e2b595eec53",
+}
+
+
+@pytest.mark.parametrize("group,window", sorted(OLDER_CALLS, key=str))
+def test_trinitys_and_smallthinkers_calls_lower_to_the_kernels_they_had_before_the_diffusion_mask(
+        group, window):
+    """A call that names no ``diffusion_block`` traces to the jaxpr it had
+    before the argument was there (kernel bodies, grids, block index maps),
+    source locations aside; GLM's call is held by the test above."""
+    q = jax.ShapeDtypeStruct((1, 512, 2 * group, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
+    f = lambda q, k, v: jnp.sum(causal_attention(q, k, v, 128 ** -0.5, 128, True, group, window))  # noqa: E731
+    text = str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, kv, kv))
+    digest = hashlib.sha256(re.sub(r"\S+\.py:\d+", "", text).encode()).hexdigest()
+    assert digest == OLDER_CALLS[group, window]
+
+
 def test_no_group_and_no_window_are_the_call_that_names_neither(qkvg):
     q, k, v, g = qkvg
     run = lambda *a: jax.value_and_grad(  # noqa: E731
@@ -332,6 +358,80 @@ def test_afmoe_attention_through_the_kernel_agrees_with_it_through_the_blocks(mo
     assert _rel(dgot, dwant) < 1e-2
 
 
+# ---- the block-diffusion mask (PR 39) ------------------------------------------------
+
+def _blocked_diffusion(q, k, v, block: int, group: int = GROUP):
+    return jnp.concatenate([sdar._attend_block(q, k, v, i, 128, SCALE, group, block)
+                            for i in range(0, q.shape[1], 128)], axis=1)
+
+
+@pytest.mark.parametrize("T,block,dblock,group,kv_heads", [
+    (768, 128, 4, 8, 1),   # three tiles a half: diagonal tiles of all three masks, whole tiles, skipped steps
+    (512, 256, 32, 8, 1),  # one tile a half: every tile is masked
+    (512, 128, 4, 1, 2),   # no group: dk, dv are whole-T float32 blocks all the same
+])
+def test_the_diffusion_mask_matches_the_blocked_form_output_and_gradients(T, block, dblock, group, kv_heads):
+    """The record twice, clean then noised, in blocks of ``dblock``: a clean
+    query sees its own and the earlier clean blocks, a noisy one the earlier
+    clean blocks and its own noisy block. Against ``sdar._attend_block`` (held
+    to a brute-force table in ``tests/test_sdar.py``)."""
+    q, k, v, g = _grouped(T, group, kv_heads)
+    fused = lambda q, k, v: causal_attention(q, k, v, SCALE, block, True, group, None, dblock)  # noqa: E731
+    want_f = lambda q, k, v: _blocked_diffusion(q, k, v, dblock, group)  # noqa: E731
+    o, want = fused(q, k, v), want_f(q, k, v)
+    assert o.dtype == jnp.float32 and o.shape == want.shape == q.shape
+    assert _rel(o, want) < 3e-3 and float(jnp.max(jnp.abs(o - want))) < 2e-2
+    grad = lambda f: jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)  # noqa: E731
+    for a, b in zip(grad(fused), grad(want_f)):
+        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
+        assert _rel(a, b) < 8e-3  # dk, dv are sums over the group's heads, each side rounds once
+    # and the mask is there: causal over the doubled record is another function
+    assert _rel(o, causal_attention(q, k, v, SCALE, block, True, group)) > 0.05
+
+
+def test_under_the_diffusion_mask_a_change_moves_the_rows_that_see_it_and_no_other():
+    L, n = 384, 4
+    q, k, v, _ = _grouped(2 * L)
+    run = lambda k, v: np.asarray(causal_attention(q, k, v, SCALE, 128, True, GROUP, None, n))  # noqa: E731
+    base = run(k, v)
+    moved = lambda at: np.flatnonzero(np.any(  # noqa: E731
+        run(k.at[:, at].set(k[:, at] * -3 + 1), v.at[:, at].add(7.0)) != base, axis=(0, 2, 3)))
+    at = 130  # a clean key of block 32: its own block's clean queries on, the noisy from block 33 on
+    first = at // n * n
+    assert np.array_equal(moved(at), np.concatenate([np.arange(first, L), np.arange(L + first + n, 2 * L)]))
+    assert np.array_equal(moved(L + at), np.arange(L + first, L + first + n))  # a noisy key: its block
+    with pytest.raises(ValueError, match="diffusion blocks"):
+        causal_attention(q, k, v, SCALE, 128, True, GROUP, 256, n)  # no window with it
+    with pytest.raises(ValueError, match="diffusion blocks"):
+        causal_attention(q, k, v, SCALE, 256, True, GROUP, None, n)  # 768 is not two halves of tiles of 256
+
+
+@pytest.mark.parametrize("backend,t,d,block,dblock,fused", [
+    ("tpu", 16384, 128, 512, 4, True),    # the SDAR cell: 8,192 tokens twice, 16 tiles a half
+    ("cpu", 16384, 128, 512, 4, False),   # tier-1, whatever the shape
+    ("tpu", 12288, 128, 512, 4, True),    # the record the issue falls back to
+    ("tpu", 8192 + 512, 128, 512, 4, False),  # a half that is no whole number of tiles
+    ("tpu", 16384, 128, 512, 96, False),  # blocks the tile does not hold whole
+    ("tpu", 64, 16, 8, 4, False),         # the toy cell's widths
+])
+def test_sdars_path_is_chosen_from_backend_and_shapes(backend, t, d, block, dblock, fused, monkeypatch):
+    assert sdar.fused_scores(backend, t, d, block, dblock) is fused
+    if t != 16384 or dblock != 4:
+        return
+    names = ("model.attn.fused_diffusion_scores", "model.attn.blocked_scores")
+    before = [STAT_GET(n) for n in names]
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    c = sdar.SdarConfig(hidden_size=64, num_attention_heads=8, num_key_value_heads=1,
+                        seq_len=512, attn_block=128)
+    p = {"q": jnp.zeros((64, 8 * 128)), "k": jnp.zeros((64, 128)), "v": jnp.zeros((64, 128)),
+         "o": jnp.zeros((8 * 128, 64)), "q_norm": jnp.ones((128,)), "k_norm": jnp.ones((128,))}
+    rope = tuple(jnp.tile(a, (2, 1)) for a in glm.rope_tables(c.data_len, c.head_dim, c.rope_theta))
+    text = str(jax.make_jaxpr(lambda x: sdar.attention(p, x, jnp.ones((64,)), c, rope))(
+        jax.ShapeDtypeStruct((1, c.seq_len, 64), jnp.float32)))
+    assert text.count("pallas_call") == (1 if fused else 0)
+    assert [STAT_GET(n) - b for n, b in zip(names, before)] == ([1, 0] if fused else [0, 1])
+
+
 # ---- o and the logsumexp kept across a layer's checkpoint, by name (PR 36) -------------
 
 def _kernel_calls(text: str):
@@ -408,7 +508,8 @@ _SMALL = dict(hidden_size=64, seq_len=256, attn_block=128, loss_block=128, exper
               moe_intermediate_size=16, num_experts_per_tok=2, experts_held=4, vocab_size=64)
 # name -> (the model at shapes the kernel tiles, its call sites of the kernel, its checkpoint
 # sites, the counter of the latter): GLM's dense layer, scanned expert layer and MTP module;
-# Trinity's dense layer (its kind static) and a scan body of two branches; SmallThinker's body
+# Trinity's dense layer (its kind static) and a scan body of two branches; SmallThinker's body;
+# SDAR's, whose layers are all alike: one call site
 KEEPERS = {
     "glm": (lambda: GlmMoeLite(GlmMoeLiteConfig(
         **_SMALL, num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=64,
@@ -422,12 +523,15 @@ KEEPERS = {
     "smallthinker": (lambda: SmallThinker(SmallThinkerConfig(
         **_SMALL, num_attention_heads=7, num_key_value_heads=1, sliding_window=128,
         layer_kinds=(0, 1, 1), num_experts=8)), 2, 1, "model.attn.keep_scores_sites"),
+    "sdar": (lambda: sdar.Sdar(sdar.SdarConfig(
+        **_SMALL, num_attention_heads=8, num_key_value_heads=1, num_hidden_layers=3, num_experts=8,
+        mask_id=63)), 1, 1, "model.attn.keep_scores_sites"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(KEEPERS))
 def test_every_token_models_checkpoints_keep_the_scores_and_count_themselves(monkeypatch, name):
-    """All three models took the policy (PR 36: each cell's superstep still
+    """All four models took the policy (PR 36; SDAR's with its model, PR 39: each cell's superstep still
     fits the chip and its step is shorter, ``PERF.md`` section 6): on a TPU the gradient of
     ``apply`` holds each call site's forward kernel once, every checkpoint of
     a layer carries ``KEEP_SCORES``, and the trace-time counter says how many."""
@@ -559,3 +663,53 @@ def test_smallthinkers_loss_and_gradient_compile_for_a_v5e_with_each_forward_ker
         params, emb, ids).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == (4 if kept else 6)
     assert compiled.memory_analysis().temp_size_in_bytes < (6.1e9 if kept else 3.9e9)  # 5.99 / 3.84 today
+
+
+def test_diffusion_kernels_compile_for_a_v5e_at_the_sdar_cells_shapes(one_chip):
+    """1 x 16,384 (8,192 tokens twice) x 32 query heads over 4 key-value heads
+    of 128, tiles of 512, blocks of 4: 16 tiles a half, a forward inner axis of
+    17 and a backward one of 33; the backward holds three whole-T float32
+    blocks of [16384, 128] (dq; dk, dv over a group's 8 heads) in VMEM, as
+    SmallThinker's call does."""
+    from paddlebox_tpu.obs.program_scopes import scope_map
+
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.float32, sharding=one_chip)
+
+    def step(q, k, v, g):
+        with jax.named_scope("model/attn/scores_diffusion"):
+            return jax.grad(lambda q, k, v: jnp.sum(
+                causal_attention(q, k, v, 128 ** -0.5, 512, False, 8, None, 4) * g),
+                argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(step).lower(q, kv, kv, g).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    kernels = {n: s for n, s in scope_map(text).items() if "causal_attention" in n}
+    assert len(kernels) == 2 and set(kernels.values()) == {"model/attn/scores_diffusion"}, kernels
+    # no score block and no 8-fold k, v, dk or dv in HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 16384 * 32 * 128 * 4
+
+
+def test_sdars_loss_and_gradient_compile_for_a_v5e_with_one_kernel_pair(one_chip, monkeypatch):
+    """The cell's model (``benchmark/configs/sdar_30b_a3b_ep8.json``: one
+    record of 8,192 tokens twice, 4 layers alike), loss and gradient of every
+    leaf and of the rows, the fused path forced: the scan body's one forward
+    and one backward kernel (the layer's checkpoint keeps o and the
+    logsumexp), 7.96 GB at the peak today."""
+    from benchmark.models import sdar as build
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "sdar_30b_a3b_ep8.json")) as f:
+        cfg = json.load(f)
+    model = build.build(cfg, 3 + cfg["embedx_dim"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, T, H = cfg["batch_size"], cfg["seq_len"], cfg["hidden_size"]
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(on, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    emb, ids = (jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in ((B, T, H), (B, T)))
+    compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
+        params, emb, ids).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.6e9  # 6.31 today
