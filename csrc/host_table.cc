@@ -19,6 +19,13 @@
 //     shard files and lazily promoted (with catch-up decay) when a later
 //     pass touches them — LoadSSD2Mem semantics inverted for the host side.
 //
+// Beside the store, one entry point that needs no table handle:
+// pbx_lookup_rows, the pass working set's key -> row search
+// (PassWorkingSet.lookup / DistributedWorkingSet.lookup): a pass's keys
+// against its sorted unique keys, threaded over slices of the queries,
+// each thread running kLookupLanes binary searches in lock step so their
+// cache misses overlap.
+//
 // ABI: plain C, handle-based, ctypes-bound (utils/native.py); all calls are
 // thread-safe via per-shard mutexes.
 
@@ -297,6 +304,14 @@ int64_t promote(Table* t, Shard* s, uint64_t j, bool seek_end = true) {
 //
 // `shard_ns`, when non-null, receives per-shard wall nanoseconds spent in
 // fn (length n_shards; written by the owning worker only).
+// A batch call's pool size: `threads` > 0 is taken as asked; otherwise
+// hardware concurrency capped at 16, and one below 64k keys.
+inline int pool_size(int threads, int64_t n_keys) {
+  if (threads > 0) return threads;
+  if (n_keys < 65536) return 1;
+  return (int)std::min(16u, std::thread::hardware_concurrency());
+}
+
 template <typename Fn>
 int for_shards_ex(const Table* t, const uint64_t* keys, int64_t n,
                   int threads, int64_t* shard_ns, Fn fn) {
@@ -316,14 +331,7 @@ int for_shards_ex(const Table* t, const uint64_t* keys, int64_t n,
   if (shard_ns)
     for (int s = 0; s < ns; ++s) shard_ns[s] = 0;
 
-  int nt;
-  if (threads > 0) {
-    nt = threads;
-  } else {
-    nt = (int)std::thread::hardware_concurrency();
-    if (nt > 16) nt = 16;
-    if (n < 65536) nt = 1;
-  }
+  int nt = pool_size(threads, n);
   if (nt > ns) nt = ns;
   if (nt < 1) nt = 1;
   std::vector<int> rc(nt, 0);
@@ -350,6 +358,63 @@ int for_shards_ex(const Table* t, const uint64_t* keys, int64_t n,
 template <typename Fn>
 int for_shards(const Table* t, const uint64_t* keys, int64_t n, Fn fn) {
   return for_shards_ex(t, keys, n, /*threads=*/0, /*shard_ns=*/nullptr, fn);
+}
+
+// ---- pass working set lookup -------------------------------------------
+//
+// kLookupLanes binary searches side by side: every round halves all of
+// them, and each lane's next probe is prefetched before any lane reads
+// its own, so a core keeps that many cache misses in flight where one
+// search has one. All lanes of a call share the halving sequence (it
+// depends on n alone), so the rounds are one loop. The width is timed
+// (a v5e host's 13 cores, 56.9M queries against 47.6M sorted keys, us a
+// key at 4 / 8 / 16 / 32 / 64 lanes: 0.029 / 0.016 / 0.0105 / 0.017 /
+// 0.018; one thread 0.33 / 0.18 / 0.125 / 0.21 / 0.21; PERF.md, PR 41).
+constexpr int kLookupLanes = 16;
+constexpr int kLookupMissing = 5;  // first missing query indices reported
+
+struct LookupMiss {
+  int64_t n = 0;
+  int64_t first[kLookupMissing];
+  void note(int64_t i) {
+    if (n < kLookupMissing) first[n] = i;
+    ++n;
+  }
+};
+
+// out[i] = (int32) row_of_sorted[pos(keys[i])] for i in [lo, hi), where
+// pos is the numpy body's: the first position whose sorted key is not
+// below the query, clipped to n - 1. A query whose key is not at its
+// position is noted in `miss`. n >= 1.
+void lookup_slice(const uint64_t* sorted, const int64_t* row_of_sorted,
+                  int64_t n, const uint64_t* keys, int32_t* out, int64_t lo,
+                  int64_t hi, LookupMiss* miss) {
+  for (int64_t i0 = lo; i0 < hi; i0 += kLookupLanes) {
+    const int w = (int)std::min<int64_t>(kLookupLanes, hi - i0);
+    const uint64_t* k = keys + i0;
+    int64_t base[kLookupLanes];
+    for (int j = 0; j < w; ++j) base[j] = 0;
+    // the position lies in [base, base + len] before and after a round
+    for (int64_t len = n; len > 1;) {
+      const int64_t half = len >> 1;
+      for (int j = 0; j < w; ++j)
+        __builtin_prefetch(sorted + base[j] + half - 1, 0, 0);
+      for (int j = 0; j < w; ++j)
+        base[j] += sorted[base[j] + half - 1] < k[j] ? half : 0;
+      len -= half;
+    }
+    for (int j = 0; j < w; ++j) {
+      const int64_t pos = base[j] + (sorted[base[j]] < k[j] ? 1 : 0);
+      base[j] = pos < n ? pos : n - 1;
+      __builtin_prefetch(row_of_sorted + base[j], 0, 0);
+    }
+    // the search's last cache line is still hot: the proof that the key
+    // is there and the row read happen at this one position
+    for (int j = 0; j < w; ++j) {
+      if (sorted[base[j]] != k[j]) miss->note(i0 + j);
+      out[i0 + j] = (int32_t)row_of_sorted[base[j]];
+    }
+  }
 }
 
 // Rewrite one shard's spill file with only the LIVE records (hash entries
@@ -927,6 +992,52 @@ int push_shard_batch(Table* t, int si, const uint64_t* keys,
 }
 
 }  // namespace
+
+// Pass working set lookup: out[i] = (int32) row_of_sorted[position of
+// keys[i] in sorted], one pass over the queries, nothing allocated per key.
+// `sorted` is strictly ascending, n >= 1 (the caller answers an empty
+// working set itself). The queries are cut into contiguous slices over a
+// pool of for_shards_ex's size (pool_size: `threads` <= 0 = hardware
+// concurrency capped at 16, serial below 64k keys), capped at one block of
+// kLookupLanes keys a thread. Every
+// out[i] is a function of keys[i] alone, so the result is the same at
+// every thread count. Returns the number of queries whose key is not in
+// `sorted`; the first min(that, 5) of their indices, in query order, are
+// written to first_missing[0..5); *threads_used receives the pool size.
+int64_t pbx_lookup_rows(const uint64_t* sorted, const int64_t* row_of_sorted,
+                        int64_t n, const uint64_t* keys, int64_t m,
+                        int32_t* out, int threads, int64_t* first_missing,
+                        int* threads_used) {
+  int nt = pool_size(threads, m);
+  const int64_t blocks = (m + kLookupLanes - 1) / kLookupLanes;
+  if (nt > blocks) nt = (int)blocks;
+  if (nt < 1) nt = 1;
+  *threads_used = nt;
+  // slices are whole blocks of lanes, so a key's lane neighbours do not
+  // depend on the thread count either
+  const int64_t per = (blocks + nt - 1) / nt * kLookupLanes;
+  std::vector<LookupMiss> miss(nt);
+  auto work = [&](int w) {
+    const int64_t lo = std::min<int64_t>(m, (int64_t)w * per);
+    const int64_t hi = std::min<int64_t>(m, lo + per);
+    lookup_slice(sorted, row_of_sorted, n, keys, out, lo, hi, &miss[w]);
+  };
+  if (nt == 1) {
+    work(0);
+  } else {
+    std::vector<std::thread> th;
+    for (int w = 0; w < nt; ++w) th.emplace_back(work, w);
+    for (auto& x : th) x.join();
+  }
+  int64_t total = 0;
+  int filled = 0;
+  for (const LookupMiss& ms : miss) {  // slices in query order
+    for (int q = 0; q < ms.n && q < kLookupMissing && filled < kLookupMissing; ++q)
+      first_missing[filled++] = ms.first[q];
+    total += ms.n;
+  }
+  return total;
+}
 
 // Batch push (upsert full rows) + mark touched. Returns 0 or negative.
 int pbx_table_push(void* h, const uint64_t* keys, const float* rows,

@@ -12,9 +12,12 @@ paths (pv merge, AucRunner, cross-node routing).
 
 Key→row resolution is pass-scoped: after ``PassWorkingSet.finalize`` the
 mapping key->table row is frozen, so ``resolve_rows`` translates the whole
-store ONCE (vectorized searchsorted); batches then gather int32 rows and
-never touch uint64 keys again (the host analog of the reference's device
-CopyKeys + DedupKeysAndFillIdx, box_wrapper_impl.h:25-162).
+store ONCE (``ws.lookup`` over ``u64_values`` as it lies, no copy:
+``table/sparse_table.py::lookup_rows``, one threaded native search where
+the library loaded, numpy's searchsorted otherwise); batches then gather
+int32 rows and never touch uint64 keys again (the host analog of the
+reference's device CopyKeys + DedupKeysAndFillIdx,
+box_wrapper_impl.h:25-162).
 """
 
 from __future__ import annotations
@@ -347,8 +350,12 @@ class ColumnarRecords:
     def resolve_rows(self, ws) -> np.ndarray:
         """int32 pass-local row per key, whole store at once (cached).
 
-        One vectorized lookup per pass replaces a per-batch key search —
-        the decisive host-side win over re-resolving every batch.
+        One lookup per pass replaces a per-batch key search — the decisive
+        host-side win over re-resolving every batch. The keys are resolved
+        by the working set's ``lookup`` (``lookup_rows``: every key proven
+        present, ``KeyError`` otherwise; at a pass's size the threaded
+        native search ``pbx_lookup_rows``, counted in
+        ``table.lookup.native_keys``).
         """
         if self._rows is not None and self._rows_ws_id == id(ws):
             return self._rows

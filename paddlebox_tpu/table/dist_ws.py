@@ -42,6 +42,7 @@ from paddlebox_tpu.parallel.membership import OwnershipMap
 from paddlebox_tpu.table.sparse_table import (
     HostSparseTable,
     key_to_shard,
+    lookup_rows,
     merge_unique_keys,
 )
 from paddlebox_tpu.utils.monitor import STAT_ADD
@@ -383,20 +384,7 @@ class DistributedWorkingSet:
 
     def lookup(self, keys: np.ndarray) -> np.ndarray:
         """Batch keys -> GLOBAL row ids (int32); keys must be in the pass."""
-        if len(self.sorted_keys) == 0:
-            if len(keys):
-                raise KeyError(
-                    f"{len(keys)} batch keys but the pass working set is empty"
-                )
-            return np.zeros(0, np.int32)
-        pos = np.searchsorted(self.sorted_keys, keys.astype(np.uint64))
-        pos = np.minimum(pos, len(self.sorted_keys) - 1)
-        if not np.all(self.sorted_keys[pos] == keys):
-            missing = keys[self.sorted_keys[pos] != keys]
-            raise KeyError(
-                f"{len(missing)} batch keys not in pass working set (e.g. {missing[:5]})"
-            )
-        return self.row_of_sorted[pos].astype(np.int32)
+        return lookup_rows(self.sorted_keys, self.row_of_sorted, keys)
 
     @property
     def padding_row(self) -> int:

@@ -153,6 +153,72 @@ def merge_unique_keys(
     return np.concatenate(ranges)
 
 
+# below this many query keys the numpy body is the only body and the call
+# the program it always was. On a v5e host a native call costs 0.2 ms
+# before its first key: 1,024 keys take numpy 0.14-0.66 ms (a 20k-key set,
+# a 47.6M-key one) and the native body 0.20-0.29; at 4,096 it is ahead
+# against both (0.24 against 0.44 ms, 0.80 against 6.4; PERF.md, PR 41)
+_LOOKUP_NATIVE_FLOOR = 4_096
+
+
+def _lookup_rows_numpy(
+    sorted_keys: np.ndarray, row_of_sorted: np.ndarray, keys: np.ndarray
+) -> np.ndarray:
+    """The oracle: three numpy passes (search, proof, row read)."""
+    pos = np.searchsorted(sorted_keys, keys.astype(np.uint64))
+    pos = np.minimum(pos, len(sorted_keys) - 1)
+    if not np.all(sorted_keys[pos] == keys):
+        missing = keys[sorted_keys[pos] != keys]
+        raise KeyError(
+            f"{len(missing)} batch keys not in pass working set (e.g. {missing[:5]})"
+        )
+    return row_of_sorted[pos].astype(np.int32)
+
+
+def lookup_rows(
+    sorted_keys: np.ndarray, row_of_sorted: np.ndarray, keys: np.ndarray
+) -> np.ndarray:
+    """Batch keys -> row ids (int32) of a finalized pass working set.
+
+    ``sorted_keys`` (sorted unique uint64) and ``row_of_sorted`` (int64) are
+    the working set's; every key must be among them, else ``KeyError`` names
+    how many are not and the first five in query order. The one body of
+    ``PassWorkingSet.lookup`` and ``DistributedWorkingSet.lookup``.
+
+    Bitwise-identical to :func:`_lookup_rows_numpy` (the tests assert this),
+    but a call of ``_LOOKUP_NATIVE_FLOOR`` keys or more, where the native
+    library loaded, is one threaded native pass (``pbx_lookup_rows``,
+    csrc/host_table.cc): the search, the proof and the row read at one
+    position, many searches' cache misses in flight at once. Which body ran
+    is counted: ``table.lookup.native_keys`` / ``table.lookup.numpy_keys``,
+    and ``table.lookup.threads`` is the last native call's pool.
+    """
+    if len(sorted_keys) == 0:
+        if len(keys):
+            raise KeyError(
+                f"{len(keys)} batch keys but the pass working set is empty"
+            )
+        return np.zeros(0, np.int32)
+    if len(keys) >= _LOOKUP_NATIVE_FLOOR:
+        from paddlebox_tpu.utils import native
+
+        if native.available():
+            rows, n_missing, first, threads = native.lookup_rows(
+                sorted_keys, row_of_sorted, keys
+            )
+            if n_missing:
+                raise KeyError(
+                    f"{n_missing} batch keys not in pass working set "
+                    f"(e.g. {keys[first]})"
+                )
+            STAT_ADD("table.lookup.native_keys", len(keys))
+            STAT_SET("table.lookup.threads", threads)
+            return rows
+    rows = _lookup_rows_numpy(sorted_keys, row_of_sorted, keys)
+    STAT_ADD("table.lookup.numpy_keys", len(keys))
+    return rows
+
+
 @functools.lru_cache(maxsize=8)
 def _sharded_zeros_fn(rows: int, width: int, sharding):
     """Compiled born-sharded zeros builder, cached by (shape, sharding) —
@@ -1236,20 +1302,7 @@ class PassWorkingSet:
 
     def lookup(self, keys: np.ndarray) -> np.ndarray:
         """Batch keys -> global row ids (int32). Keys must be in the pass."""
-        if len(self.sorted_keys) == 0:
-            if len(keys):
-                raise KeyError(
-                    f"{len(keys)} batch keys but the pass working set is empty"
-                )
-            return np.zeros(0, np.int32)
-        pos = np.searchsorted(self.sorted_keys, keys.astype(np.uint64))
-        pos = np.minimum(pos, len(self.sorted_keys) - 1)
-        if not np.all(self.sorted_keys[pos] == keys):
-            missing = keys[self.sorted_keys[pos] != keys]
-            raise KeyError(
-                f"{len(missing)} batch keys not in pass working set (e.g. {missing[:5]})"
-            )
-        return self.row_of_sorted[pos].astype(np.int32)
+        return lookup_rows(self.sorted_keys, self.row_of_sorted, keys)
 
     @property
     def padding_row(self) -> int:

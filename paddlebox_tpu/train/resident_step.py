@@ -38,6 +38,7 @@ from paddlebox_tpu import config
 from paddlebox_tpu.data.device_pack import _round_bucket
 from paddlebox_tpu.train.table_format import TableFormatProgram
 from paddlebox_tpu.train.train_step import TrainStepConfig, make_train_step
+from paddlebox_tpu.utils.monitor import STAT_SET
 from paddlebox_tpu.utils.trace import record_event
 
 config.define_flag(
@@ -81,8 +82,11 @@ class ResidentPass:
         self.bucket = bucket or config.get_flag("batch_bucket_rounding")
         self.n_table_rows = ws.n_mesh_shards * ws.capacity
         self.pad_row = self.n_table_rows - 1
-        with record_event("resident.resolve_rows", "pass"):
+        with record_event("resident.resolve_rows", "pass") as span:
             rows = store.resolve_rows(ws)
+        STAT_SET(
+            "resident.resolve_keys_per_s", len(rows) / max(span.seconds, 1e-9)
+        )
         if len(store.u64_values) >= (1 << 31):  # int32 src indexing
             raise ValueError("pass too large for resident feed (>=2^31 keys)")
         self._host_rows = rows

@@ -208,6 +208,11 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_void_p, _u64p, _f32p, ctypes.c_int64,
             ctypes.c_int, _i64p,
         ]
+        lib.pbx_lookup_rows.restype = ctypes.c_int64
+        lib.pbx_lookup_rows.argtypes = [
+            _u64p, _i64p, ctypes.c_int64, _u64p, ctypes.c_int64, _i32p,
+            ctypes.c_int, _i64p, ctypes.POINTER(ctypes.c_int),
+        ]
         lib.pbx_table_io_stats.restype = None
         lib.pbx_table_io_stats.argtypes = [ctypes.c_void_p, _i64p]
         lib.pbx_table_decay_shrink.restype = ctypes.c_int64
@@ -326,6 +331,45 @@ def block_stats(
         raise ValueError(
             "block_stats: record index, row or key span out of range")
     return L_out, bmax_out
+
+
+def lookup_rows(
+    sorted_keys: np.ndarray,
+    row_of_sorted: np.ndarray,
+    keys: np.ndarray,
+    threads: int = 0,
+) -> tuple:
+    """``int32 [m]`` rows of ``keys`` in a pass working set, by one threaded
+    native search (``pbx_lookup_rows``): the native body of
+    ``table/sparse_table.py::lookup_rows``, which holds it bit-equal to its
+    numpy body. ``sorted_keys`` must be non-empty; queries that are not
+    contiguous uint64 are converted as ``astype`` converts them, and only
+    then (a pass's ``u64_values`` goes in as it lies). ``threads <= 0`` is
+    the native side's own choice; the result is the same at every value.
+    Returns ``(rows, n_missing, first_missing, threads_used)``:
+    ``first_missing`` are the query indices of the first five keys that are
+    not in ``sorted_keys``, in query order."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native tier unavailable (g++ build failed?)")
+    sorted_keys = np.ascontiguousarray(sorted_keys, dtype=np.uint64)
+    row_of_sorted = np.ascontiguousarray(row_of_sorted, dtype=np.int64)
+    keys = np.ascontiguousarray(keys, dtype=np.uint64)
+    out = np.empty(len(keys), np.int32)
+    first = np.zeros(5, np.int64)
+    used = ctypes.c_int(0)
+    n_missing = int(lib.pbx_lookup_rows(
+        _as_ptr(sorted_keys, ctypes.c_uint64),
+        _as_ptr(row_of_sorted, ctypes.c_int64),
+        len(sorted_keys),
+        _as_ptr(keys, ctypes.c_uint64),
+        len(keys),
+        _as_ptr(out, ctypes.c_int32),
+        int(threads),
+        _as_ptr(first, ctypes.c_int64),
+        ctypes.byref(used),
+    ))
+    return out, n_missing, first[: min(n_missing, 5)], int(used.value)
 
 
 class NativePacker:

@@ -415,6 +415,52 @@ def test_prepare_pass_prefreezes_shapes(tmp_path):
     assert len(tr._sstep_cache) == 1  # one train superstep, no regrowth
 
 
+def test_resident_rows_by_the_native_lookup_equal_the_numpy_bodys(
+    tmp_path, monkeypatch
+):
+    """A store large enough to take the native body resolves to the rows
+    the numpy body gives, and says how fast and by which body."""
+    from paddlebox_tpu.table import sparse_table
+    from paddlebox_tpu.train.resident_step import ResidentPass
+    from paddlebox_tpu.utils import native
+    from paddlebox_tpu.utils.monitor import STAT_GET, STAT_RESET
+
+    if not native.available():
+        pytest.skip("native tier unavailable")
+    schema = _schema()
+    table = HostSparseTable(
+        ValueLayout(embedx_dim=4), SparseOptimizerConfig(), n_shards=2, seed=0
+    )
+    ds = BoxPSDataset(schema, table, batch_size=B, shuffle_mode="none")
+    ds.set_filelist(_write_files(tmp_path, n=500, vocab=5000))
+    ds.load_into_memory()
+    ds.begin_pass(round_to=8)
+    n_keys = len(ds.store.u64_values)
+    assert n_keys >= sparse_table._LOOKUP_NATIVE_FLOOR
+
+    def build():
+        ds.store.invalidate_rows()
+        STAT_RESET("resident.resolve_keys_per_s")
+        before = (
+            STAT_GET("table.lookup.native_keys"),
+            STAT_GET("table.lookup.numpy_keys"),
+        )
+        rp = ResidentPass(ds.store, ds.ws, schema, label_slot="label")
+        assert STAT_GET("resident.resolve_keys_per_s") > 0
+        return rp._host_rows, (
+            STAT_GET("table.lookup.native_keys") - before[0],
+            STAT_GET("table.lookup.numpy_keys") - before[1],
+        )
+
+    rows_native, counted = build()
+    assert counted == (n_keys, 0)
+    monkeypatch.setattr(native, "_load", lambda: None)
+    rows_numpy, counted = build()
+    assert counted == (0, n_keys)
+    assert rows_native.dtype == np.int32
+    assert np.array_equal(rows_native, rows_numpy)
+
+
 # ---- _ragged_rows alone, against a plain loop over the segments ------------
 
 
