@@ -8,6 +8,7 @@ from paddlebox_tpu.models.glm_moe_lite import GlmMoeLite, GlmMoeLiteConfig
 from paddlebox_tpu.models.afmoe import Afmoe, AfmoeConfig
 from paddlebox_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 from paddlebox_tpu.models.sdar import Sdar, SdarConfig
+from paddlebox_tpu.models.xing4 import Xing4, Xing4Config
 
 __all__ = [
     "mlp_init",
@@ -29,4 +30,6 @@ __all__ = [
     "SmallThinkerConfig",
     "Sdar",
     "SdarConfig",
+    "Xing4",
+    "Xing4Config",
 ]

@@ -34,7 +34,8 @@ class CTRModel(Protocol):
 class SequenceLossModel(Protocol):
     """A model that takes one sparse slot's pulled rows as a sequence and owns
     its loss (a language model over token ids: ``models/glm_moe_lite.py``,
-    ``models/afmoe.py``, ``models/smallthinker.py``, ``models/sdar.py``).
+    ``models/afmoe.py``, ``models/smallthinker.py``, ``models/sdar.py``,
+    ``models/xing4.py``).
 
     ``sequence_feed = True`` on the model object is the declaration:
     ``CTRTrainer`` carries it to the step builders as

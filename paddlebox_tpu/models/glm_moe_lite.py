@@ -1,7 +1,10 @@
 """GLM-4.7-Flash (``glm4_moe_lite``): latent attention, routed experts beside
 a shared expert, one multi-token-prediction module; a language model trained
-through the pass path, the first of four (``models/afmoe.py``,
-``models/smallthinker.py`` and ``models/sdar.py`` import its pieces).
+through the pass path, the first of five (``models/afmoe.py``,
+``models/smallthinker.py``, ``models/sdar.py`` and ``models/xing4.py`` import
+its pieces; the last takes each block's branch, ``mla_branch``,
+``dense_branch`` and ``moe_branch``, without the sum that the layers here put
+around it).
 
 The model is a *sequence model that owns its loss* (``models/base.py``): the
 step hands it the pulled rows of the one token slot unpooled, as
@@ -154,9 +157,38 @@ def rms_norm(x, w, eps):
 def rope_tables(T: int, dim: int, theta: float):
     # the frequencies on the host in float64: a device's float32 pow is a few
     # ulps off, and position 4,095 multiplies that into the angle
-    inv = np.asarray(float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim), np.float32)
-    ang = jnp.arange(T, dtype=F32)[:, None] * inv[None, :]
-    return jnp.cos(ang), jnp.sin(ang)  # [T, dim/2]
+    return _tables(T, float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim))
+
+
+def _tables(T: int, inv_freq):
+    """(cos, sin) [T, dim/2] of position x frequency, the frequencies float64 from the host."""
+    ang = jnp.arange(T, dtype=F32)[:, None] * np.asarray(inv_freq, np.float32)[None, :]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_rope_tables(T: int, dim: int, theta: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float):
+    """``rope_tables`` under YaRN (Peng et al., arXiv:2309.00071, as DeepSeek-V3's
+    ``rope_scaling`` states it): the frequencies that turn more than
+    ``beta_fast`` times over the ``original`` positions stay, those that turn
+    less than ``beta_slow`` times are divided by ``factor``, a linear ramp over
+    the pair index between. The cos/sin factor ``mscale / mscale_all_dim`` is
+    the caller's (1 where the two are equal); ``yarn_mscale`` is the softmax
+    scale's."""
+    f = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)  # host, float64: as above
+
+    def turns_at(r):  # the (fractional) pair index whose frequency turns r times over ``original``
+        return dim * np.log(original / (r * 2 * np.pi)) / (2 * np.log(float(theta)))
+
+    low = max(np.floor(turns_at(beta_fast)), 0)
+    high = min(np.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return _tables(T, f / factor * ramp + f * (1.0 - ramp))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor; the softmax scale takes its square at ``mscale_all_dim``."""
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
 
 
 def apply_rope(x, cos, sin):
@@ -182,17 +214,22 @@ def _attend_block(q, k, v, q0: int, n_q: int, scale: float):
 
 
 def fused_scores(backend: str, T: int, qk_dim: int, v_dim: int, block: int) -> bool:
-    """Whether a call site of ``mla`` takes the fused kernel: on a TPU, at
-    shapes the kernel tiles. Everything else runs the blocked form."""
-    return (backend == "tpu" and qk_dim == v_dim and qk_dim % LANE == 0
+    """Whether a call site of ``mla_branch`` takes the fused kernel: on a
+    TPU, at shapes the kernel tiles (value heads of whole lane rows;
+    query/key heads of half lane rows, which the kernel fills up with zero
+    columns). Everything else runs the blocked form."""
+    return (backend == "tpu" and v_dim % LANE == 0 and qk_dim % (LANE // 2) == 0
             and block % LANE == 0 and T % block == 0)
 
 
-def mla(p, x, norm_w, c: GlmMoeLiteConfig, rope, scope: str):
-    """x + attention(norm(x)): latent attention in its uncompressed (training)
-    form. x [B, T, H]. ``scope`` is the block's absolute scope path: every
-    leaf scope here is named in full, so that an instruction's scope reads the
-    same in the forward pass, under ``checkpoint`` and inside the layer scan."""
+def mla_branch(p, x, norm_w, c: GlmMoeLiteConfig, rope, scope: str, scale=None):
+    """attention(norm(x)), without the residual sum: latent attention in its
+    uncompressed (training) form. x [B, T, H]. ``rope`` is the caller's
+    (cos, sin) tables, ``scale`` its softmax scale (default ``(nope + rope) **
+    -0.5``; YaRN's is larger). ``scope`` is the block's absolute scope path:
+    every leaf scope here is named in full, so that an instruction's scope
+    reads the same in the forward pass, under ``checkpoint`` and inside the
+    layer scan."""
     B, T, _ = x.shape
     nh, dn, dr, dv = c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
     cos, sin = rope
@@ -214,7 +251,7 @@ def mla(p, x, norm_w, c: GlmMoeLiteConfig, rope, scope: str):
         Q = min(c.attn_block, T)
         if T % Q:
             raise ValueError(f"seq_len {T} is not a multiple of attn_block {Q}")
-        scale = float(dn + dr) ** -0.5
+        scale = float(dn + dr) ** -0.5 if scale is None else float(scale)
         if fused_scores(jax.default_backend(), T, dn + dr, dv, Q):
             STAT_ADD("model.mla.fused_scores")  # call sites lowered each way, at trace time
             o = causal_attention(q, k, v, scale, Q)
@@ -223,7 +260,14 @@ def mla(p, x, norm_w, c: GlmMoeLiteConfig, rope, scope: str):
             o = jnp.concatenate(
                 [_attend_block(q, k, v, i, Q, scale) for i in range(0, T, Q)], axis=1)
     with jax.named_scope(f"{scope}/mla/out_proj"):
-        return x + _mm(o.reshape(B, T, nh * dv), p["o"])
+        return _mm(o.reshape(B, T, nh * dv), p["o"])
+
+
+def mla(p, x, norm_w, c: GlmMoeLiteConfig, rope, scope: str):
+    """x + attention(norm(x)): ``mla_branch`` in a pre-norm residual block."""
+    y = mla_branch(p, x, norm_w, c, rope, scope)
+    with jax.named_scope(f"{scope}/mla/out_proj"):
+        return x + y
 
 
 def swiglu(p, x):
@@ -420,23 +464,43 @@ def routed_experts(p, x, idx, g, c: GlmMoeLiteConfig, scope: str, act: str = "si
 # ---- layers -------------------------------------------------------------------
 
 
-def dense_layer(p, x, c: GlmMoeLiteConfig, rope, scope: str = "model"):
-    h = mla(p["attn"], x, p["ln1"], c, rope, scope)
+# A block is its branch F(norm(h)) and the sum around it; ``models/xing4.py`` puts
+# hyper-connections around the same branches instead.
+
+
+def dense_branch(p, h, c: GlmMoeLiteConfig, scope: str = "model"):
+    """swiglu(norm(h)), the dense layer's feed-forward without the residual sum."""
     with jax.named_scope(f"{scope}/dense_mlp"):
-        return h + swiglu(p["mlp"], rms_norm(h, p["ln2"], c.rms_norm_eps))
+        return swiglu(p["mlp"], rms_norm(h, p["ln2"], c.rms_norm_eps))
 
 
-def moe_layer(p, x, c: GlmMoeLiteConfig, rope, scope: str = "model"):
-    """-> (stream, chosen experts [B, T, k], held experts' loads)."""
-    B, T, H = x.shape
-    h = mla(p["attn"], x, p["ln1"], c, rope, scope)
+def moe_branch(p, h, c: GlmMoeLiteConfig, scope: str = "model"):
+    """shared(norm(h)) + the held experts' part, without the residual sum
+    -> (branch [B, T, H], chosen experts [B * T, k], held experts' loads)."""
+    B, T, H = h.shape
     with jax.named_scope(f"{scope}/moe/router"):
         flat = rms_norm(h, p["ln2"], c.rms_norm_eps).reshape(B * T, H)
         idx, g = route(p["router"], flat, c)
     routed, counts = routed_experts(p["experts"], flat, idx, g, c, scope)
     with jax.named_scope(f"{scope}/moe/shared"):
-        out = h + (swiglu(p["shared"], flat) + routed).reshape(B, T, H)
-    return out, idx.reshape(B, T, -1), counts
+        y = (swiglu(p["shared"], flat) + routed).reshape(B, T, H)
+    return y, idx, counts
+
+
+def dense_layer(p, x, c: GlmMoeLiteConfig, rope, scope: str = "model"):
+    h = mla(p["attn"], x, p["ln1"], c, rope, scope)
+    y = dense_branch(p, h, c, scope)
+    with jax.named_scope(f"{scope}/dense_mlp"):
+        return h + y
+
+
+def moe_layer(p, x, c: GlmMoeLiteConfig, rope, scope: str = "model"):
+    """-> (stream, chosen experts [B, T, k], held experts' loads)."""
+    h = mla(p["attn"], x, p["ln1"], c, rope, scope)
+    y, idx, counts = moe_branch(p, h, c, scope)
+    with jax.named_scope(f"{scope}/moe/shared"):
+        out = h + y
+    return out, idx.reshape(*h.shape[:2], -1), counts
 
 
 def head_logits(head, h, targets, block: int):

@@ -1,7 +1,9 @@
 """Pallas TPU kernels on a cell's path: the fused causal attention of
 ``models/glm_moe_lite.py`` (the token cell, since PR 29) and of
 ``models/afmoe.py`` (grouped-query heads, window and full layers, since PR 33),
-and under the block-diffusion training mask of ``models/sdar.py`` (PR 39).
+under the block-diffusion training mask of ``models/sdar.py`` (PR 39), and at
+query/key heads of another width than the value heads (192 and 128,
+``models/xing4.py``, PR 42).
 
 A kernel lives here when a call site chooses it from what it can observe
 (backend and shapes: ``models/glm_moe_lite.py::fused_scores``,
@@ -62,6 +64,11 @@ LANE = 128  # Mosaic lane width
 # from the query tiles J .. n - 1 of both halves, and the last step of its
 # inner axis (2 n + 1) is noisy key tile n + J against the one query tile that
 # sees it. At n = 16: 288 tiles a head, against 528 of a causal 2 L.
+#
+# Two widths (PR 42), absent from every call whose q, k and v have one. q and k
+# of Dqk, v and the output of Dv: the score products contract Dqk, the output
+# block, its accumulator, do and dv are Dv wide, dq and dk Dqk. A Dqk that is not
+# whole lane rows (192) goes in behind zero columns up to the next (``_widths``).
 
 _MASKED = -1e30  # what a score above the diagonal is set to (exp gives 0.0)
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -329,10 +336,27 @@ def _halves(diffusion_block, T: int, blk: int, window):
     return T // blk // 2
 
 
+def _widths(q, k, v):
+    """(q and k with zero columns up to whole lane rows, their width then, v's).
+    A head is a column block of [B, T, H * D], so its width has to be whole lane
+    rows; zero columns add nothing to a score. The MXU contracts 128 at a time, so
+    192 columns cost it the two passes 256 do: what the zeros cost is their bytes
+    (a third more of q and k read, of dq and dk written, and the pad and the cut
+    themselves). Nothing is traced where the width is whole lane rows already."""
+    pad = -q.shape[-1] % LANE
+    if pad:
+        q, k = (jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, pad))) for a in (q, k))
+    if v.shape[-1] % LANE:
+        raise ValueError(f"value heads of {v.shape[-1]} are not whole lane rows of {LANE}")
+    return q, k, q.shape[-1], v.shape[-1]
+
+
 def _attention_fwd(q, k, v, scale, blk, interpret, group, window, diffusion_block=None):
-    B, T, H, D = q.shape
+    B, T, H, _ = q.shape
+    q, k, D, Dv = _widths(q, k, v)
     band, n, kv_head = _band(window, T, blk), T // blk, _kv_head(group)
     q_spec = pl.BlockSpec((None, blk, D), lambda b, h, i, j: (b, i, h))
+    o_spec = pl.BlockSpec((None, blk, Dv), lambda b, h, i, j: (b, i, h))
     kernel = functools.partial(_attention_fwd_kernel, scale=scale, band=band)
     if diffusion_block is not None:
         half = _halves(diffusion_block, T, blk, window)
@@ -346,22 +370,24 @@ def _attention_fwd(q, k, v, scale, blk, interpret, group, window, diffusion_bloc
     else:  # a step before the sequence names the first tile the row needs
         k_tile = lambda i, j: jnp.maximum(i - band + j, 0)  # noqa: E731
     kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, i, j: (b, k_tile(i, j), kv_head(h)))
+    v_spec = pl.BlockSpec((None, blk, Dv), lambda b, h, i, j: (b, k_tile(i, j), kv_head(h)))
     inner = half + 1 if diffusion_block is not None else n if band is None else band + 1
     o, lse = pl.pallas_call(
         kernel, grid=(B, H, n, inner),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, pl.BlockSpec((None, None, 1, blk), lambda b, h, i, j: (b, h, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct((B, T, H * D), jnp.float32),
+        in_specs=[q_spec, kv_spec, v_spec],
+        out_specs=[o_spec, pl.BlockSpec((None, None, 1, blk), lambda b, h, i, j: (b, h, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * Dv), jnp.float32),
                    jax.ShapeDtypeStruct((B, H, 1, T), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((blk, LANE), jnp.float32), pltpu.VMEM((blk, LANE), jnp.float32),
-                        pltpu.VMEM((blk, D), jnp.float32)],
+                        pltpu.VMEM((blk, Dv), jnp.float32)],
         compiler_params=_ATTENTION_PARAMS, interpret=interpret, name="causal_attention_fwd",
     )(_flat(q), _flat(k), _flat(v))
-    return o, lse  # o as the kernel writes it: [B, T, H * D]
+    return o, lse  # o as the kernel writes it: [B, T, H * Dv]
 
 
 def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret, group, window, diffusion_block=None):
-    B, T, H, D = q.shape
+    B, T, H, Dqk = q.shape
+    q, k, D, Dv = _widths(q, k, v)
     band, n, kv_head = _band(window, T, blk), T // blk, _kv_head(group)
     # the row term of the softmax's transpose, sum(dp * p) = sum(do * o), from the float32 output
     di = jnp.sum(do.astype(jnp.float32) * o, axis=-1).transpose(0, 2, 1).reshape(B, H, 1, T)
@@ -384,40 +410,48 @@ def _attention_bwd(q, k, v, o, lse, do, scale, blk, interpret, group, window, di
     q_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, q_tile(j, i), h))
     row_spec = pl.BlockSpec((None, None, 1, blk), lambda b, h, j, i: (b, h, 0, q_tile(j, i)))
     kv_spec = pl.BlockSpec((None, blk, D), lambda b, h, j, i: (b, k_tile(j, i), kv_head(h)))
+    do_spec = pl.BlockSpec((None, blk, Dv), lambda b, h, j, i: (b, q_tile(j, i), h))
+    v_spec = pl.BlockSpec((None, blk, Dv), lambda b, h, j, i: (b, k_tile(j, i), kv_head(h)))
     if group == 1 and diffusion_block is None:
-        dkv_spec, dkv_dtypes = kv_spec, (k.dtype, v.dtype)
+        dk_spec, dv_spec, dkv_dtypes = kv_spec, v_spec, (k.dtype, v.dtype)
     else:  # float32 and whole, resident for the key-value head's ``group`` query heads
-        dkv_spec = pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, kv_head(h)))
+        dk_spec = pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, kv_head(h)))
+        dv_spec = pl.BlockSpec((None, T, Dv), lambda b, h, j, i: (b, 0, kv_head(h)))
         dkv_dtypes = (jnp.float32, jnp.float32)
     inner = 2 * n + 1 if diffusion_block is not None else n if band is None else band + 1
     dq, dk, dv = pl.pallas_call(
         kernel, grid=(B, H, n, inner),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, h)), dkv_spec, dkv_spec],
+        in_specs=[q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[pl.BlockSpec((None, T, D), lambda b, h, j, i: (b, 0, h)), dk_spec, dv_spec],
         out_shape=[jax.ShapeDtypeStruct((B, T, H * D), jnp.float32),
                    jax.ShapeDtypeStruct((B, T, H // group * D), dkv_dtypes[0]),
-                   jax.ShapeDtypeStruct((B, T, H // group * D), dkv_dtypes[1])],
-        scratch_shapes=[pltpu.VMEM((blk, D), jnp.float32), pltpu.VMEM((blk, D), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, T, H // group * Dv), dkv_dtypes[1])],
+        scratch_shapes=[pltpu.VMEM((blk, D), jnp.float32), pltpu.VMEM((blk, Dv), jnp.float32)],
         compiler_params=_grouped_params(group), interpret=interpret, name="causal_attention_bwd",
     )(_flat(q), _flat(k), _flat(v), _flat(do.astype(q.dtype)), lse, di)
-    return (dq.astype(q.dtype).reshape(q.shape), dk.astype(k.dtype).reshape(k.shape),
-            dv.astype(v.dtype).reshape(v.shape))
+    dq, dk = dq.astype(q.dtype).reshape(q.shape), dk.astype(k.dtype).reshape(k.shape)
+    if D != Dqk:  # the zero columns' cotangents go nowhere
+        dq, dk = dq[..., :Dqk], dk[..., :Dqk]
+    return dq, dk, dv.astype(v.dtype).reshape(v.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def causal_attention(q, k, v, scale: float, block: int, interpret: bool = False,
                      group: int = 1, window=None, diffusion_block=None):
-    """q [B, T, H, D], k, v [B, T, H / group, D] bfloat16 -> softmax(q k^T *
-    scale, causal) v [B, T, H, D] float32, in tiles of ``block`` queries by
-    ``block`` keys; query head h attends key-value head h // ``group``, and
-    with a ``window`` only to the keys less than ``window`` behind it. T and
-    ``window`` multiples of ``block``, ``block`` and D multiples of 128. With
+    """q [B, T, H, Dqk], k [B, T, H / group, Dqk], v [B, T, H / group, Dv]
+    bfloat16 -> softmax(q k^T * scale, causal) v [B, T, H, Dv] float32, in
+    tiles of ``block`` queries by ``block`` keys; query head h attends
+    key-value head h // ``group``, and with a ``window`` only to the keys less
+    than ``window`` behind it. T and ``window`` multiples of ``block``,
+    ``block`` and Dv multiples of 128; Dqk any width, whole lane rows of 128
+    as it stands and any other (192: DeepSeek-V3's own head, ``models/xing4.py``)
+    behind zero columns up to the next (``_widths`` says what they cost). With
     a ``diffusion_block`` the visible keys are not the causal prefix but
     ``diffusion_visible``'s: T is two halves of whole tiles, in blocks of
     ``diffusion_block`` that divide the tile; no window then. The cotangents
     of q, k and v come back in their dtype."""
     return _attention_fwd(q, k, v, scale, block, interpret, group, window,
-                          diffusion_block)[0].reshape(q.shape)
+                          diffusion_block)[0].reshape(*q.shape[:3], v.shape[-1])
 
 
 # The two of the backward's residuals that only the forward kernel can give (q, k, v are a
@@ -429,7 +463,8 @@ KEEP_SCORES = jax.checkpoint_policies.save_only_these_names(SCORES_OUT, SCORES_L
 
 def _causal_attention_fwd(q, k, v, scale, block, interpret, group, window, diffusion_block):
     o, lse = _attention_fwd(q, k, v, scale, block, interpret, group, window, diffusion_block)
-    o, lse = checkpoint_name(o, SCORES_OUT).reshape(q.shape), checkpoint_name(lse, SCORES_LSE)
+    o = checkpoint_name(o, SCORES_OUT).reshape(*q.shape[:3], v.shape[-1])
+    lse = checkpoint_name(lse, SCORES_LSE)
     return o, (q, k, v, o, lse)
 
 

@@ -8,7 +8,9 @@ window (``models/afmoe.py``), against that model's blocked form, and GLM's call
 held to the kernel it had before; since PR 35 at a group that is no power of
 two (7, ``models/smallthinker.py``) and at that model's 16k shapes; since PR 39
 under the block-diffusion mask (``diffusion_block``, ``models/sdar.py``), with
-all three older calls held to the kernels they had."""
+all three older calls held to the kernels they had; since PR 42 at query/key
+heads of another width than the value heads (192 and 128, ``models/xing4.py``),
+every call of one width still held to the kernel it had."""
 
 from __future__ import annotations
 
@@ -29,7 +31,8 @@ from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 from paddlebox_tpu.models import afmoe, sdar  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
 from paddlebox_tpu.models import (  # noqa: E402
-    Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, SmallThinker, SmallThinkerConfig)
+    Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, SmallThinker, SmallThinkerConfig, Xing4,
+    Xing4Config)
 from paddlebox_tpu.ops.pallas_kernels import (  # noqa: E402
     KEEP_SCORES, SCORES_LSE, SCORES_OUT, causal_attention)
 from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
@@ -93,7 +96,9 @@ def test_a_later_key_leaves_an_earlier_querys_output_bit_equal(qkvg, block):
     ("cpu", 4096, 256, 256, 512, False),   # tier-1, whatever the shape
     ("gpu", 4096, 256, 256, 512, False),
     ("tpu", 64, 16, 16, 8, False),         # the toy token cell's widths
-    ("tpu", 4096, 192, 128, 512, False),   # the published inference widths: q/k and v differ
+    ("tpu", 4096, 192, 128, 512, True),    # Xing4's cell: q/k of 192 behind zero columns, v of 128
+    ("tpu", 4096, 192, 64, 512, False),    # value heads that are no whole lane rows
+    ("tpu", 4096, 96, 128, 512, False),    # query/key heads that are no half lane rows
     ("tpu", 4096, 256, 256, 64, False),    # a query block the kernel does not tile
     ("tpu", 4096, 320, 320, 512, False),   # a head that is no multiple of a lane row
 ])
@@ -146,6 +151,88 @@ def test_mla_through_the_kernel_agrees_with_mla_through_the_blocks(monkeypatch):
     monkeypatch.setattr(glm, "causal_attention",
                         lambda q, k, v, s, block: causal_attention(q, k, v, s, block, True))
     got, dgot = run()
+    assert float(got) == pytest.approx(float(want), rel=1e-3)
+    assert _rel(dgot, dwant) < 1e-2
+
+
+# ---- query/key heads of one width, value heads of another (PR 42) -----------------------
+
+# (Dqk, Dv, group): Xing4's own pair, a toy pair below a lane row, the pair grouped, and one
+# whose query/key heads are wider than two lane rows
+WIDTHS = [(192, 128, 1), (64, 128, 1), (192, 128, 2), (320, 256, 1)]
+
+
+def _two_widths(dqk: int, dv: int, group: int):
+    ks = jax.random.split(jax.random.PRNGKey(42 + dqk), 4)
+    q = jax.random.normal(ks[0], (1, T, 2 * group, dqk)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, T, 2, dqk)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, T, 2, dv)).astype(jnp.bfloat16)
+    return q, k, v, jax.random.normal(ks[3], (1, T, 2 * group, dv))
+
+
+def _blocked_two_widths(q, k, v, group: int):
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    return jnp.concatenate([glm._attend_block(q, k, v, i, 128, q.shape[-1] ** -0.5)
+                            for i in range(0, T, 128)], axis=1)
+
+
+@pytest.mark.parametrize("dqk,dv,group", WIDTHS)
+def test_two_widths_output_and_all_three_cotangents_match_the_blocked_form(dqk, dv, group):
+    q, k, v, g = _two_widths(dqk, dv, group)
+    fused = lambda q, k, v: causal_attention(q, k, v, dqk ** -0.5, 128, True, group)  # noqa: E731
+    blocked_ = partial(_blocked_two_widths, group=group)
+    o, want = fused(q, k, v), blocked_(q, k, v)
+    assert o.dtype == jnp.float32 and o.shape == want.shape == q.shape[:3] + (dv,)
+    assert _rel(o, want) < 2e-3
+    grad = lambda f: jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)  # noqa: E731
+    for a, b, like in zip(grad(fused), grad(blocked_), (q, k, v)):
+        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape == like.shape
+        assert _rel(a, b) < 6e-3
+
+
+def test_the_zero_columns_are_traced_only_where_the_width_needs_them():
+    """192 goes in behind 64 zero columns (one ``pad`` each of q and k a kernel
+    call, the cotangents cut back); 128 and 256 trace no pad at all, so the
+    digests below hold."""
+    def text(dqk):
+        q = jax.ShapeDtypeStruct((1, 256, 2, dqk), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+        f = lambda q, k, v: jnp.sum(causal_attention(q, k, v, 0.1, 128, True))  # noqa: E731
+        return str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, q, v))
+
+    assert "pad" in text(192) and "bf16[1,256,512]" in text(192)  # the kernel sees 2 heads of 256
+    for whole in (128, 256):
+        assert "pad" not in text(whole)
+    with pytest.raises(ValueError, match="whole lane rows"):
+        causal_attention(*(jnp.zeros((1, 256, 2, d), jnp.bfloat16) for d in (128, 128, 64)), 0.1, 128, True)
+
+
+XING4_TILED = Xing4Config(hidden_size=64, num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16,
+                          qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, seq_len=256,
+                          attn_block=128)
+
+
+def test_xing4s_attention_through_the_kernel_agrees_with_the_blocks(monkeypatch):
+    """The attention branch at 192 / 128 both ways (the kernel interpreted),
+    under YaRN's tables and scale: the layout in and out, the zero columns,
+    the head split."""
+    cfg = XING4_TILED
+    p = GlmMoeLite(cfg)._attn_init(jax.random.PRNGKey(1))
+    p = jax.tree.map(lambda a: a * 20 if a.ndim == 2 else a, p)  # scores of order 1, not 1e-3
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, cfg.seq_len, cfg.hidden_size))
+    rope = glm.yarn_rope_tables(cfg.seq_len, cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+                                64, cfg.beta_fast, cfg.beta_slow)
+    stats = lambda: (STAT_GET("model.mla.fused_scores"), STAT_GET("model.mla.blocked_scores"))  # noqa: E731
+    run = lambda: jax.value_and_grad(lambda x: jnp.sum(glm.mla_branch(  # noqa: E731
+        p, x, jnp.ones((cfg.hidden_size,)), cfg, rope, "model", cfg.softmax_scale) ** 2))(x)
+    f0, b0 = stats()
+    want, dwant = run()
+    assert stats() == (f0, b0 + 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(glm, "causal_attention",
+                        lambda q, k, v, s, block: causal_attention(q, k, v, s, block, True))
+    got, dgot = run()
+    assert stats() == (f0 + 1, b0 + 1)
     assert float(got) == pytest.approx(float(want), rel=1e-3)
     assert _rel(dgot, dwant) < 1e-2
 
@@ -526,12 +613,16 @@ KEEPERS = {
     "sdar": (lambda: sdar.Sdar(sdar.SdarConfig(
         **_SMALL, num_attention_heads=8, num_key_value_heads=1, num_hidden_layers=3, num_experts=8,
         mask_id=63)), 1, 1, "model.attn.keep_scores_sites"),
+    "xing4": (lambda: Xing4(Xing4Config(
+        **_SMALL, num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, num_hidden_layers=3, first_k_dense_replace=1,
+        intermediate_size=32, n_routed_experts=8)), 2, 2, "model.mla.keep_scores_sites"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(KEEPERS))
 def test_every_token_models_checkpoints_keep_the_scores_and_count_themselves(monkeypatch, name):
-    """All four models took the policy (PR 36; SDAR's with its model, PR 39: each cell's superstep still
+    """All five models took the policy (Xing4's with its model, PR 42: a dense layer and a scan body, 192 / 128 wide). The four before it took the policy (PR 36; SDAR's with its model, PR 39: each cell's superstep still
     fits the chip and its step is shorter, ``PERF.md`` section 6): on a TPU the gradient of
     ``apply`` holds each call site's forward kernel once, every checkpoint of
     a layer carries ``KEEP_SCORES``, and the trace-time counter says how many."""
@@ -713,3 +804,53 @@ def test_sdars_loss_and_gradient_compile_for_a_v5e_with_one_kernel_pair(one_chip
         params, emb, ids).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 6.6e9  # 6.31 today
+
+
+def test_two_width_kernels_compile_for_a_v5e_at_the_xing4_cells_shapes(one_chip):
+    """1 x 4,096 x 32 heads, q and k 192 wide (256 behind the zero columns), v and
+    the output 128, tiles of 512: the backward holds dq as a whole-T float32 block
+    of [4096, 256] in VMEM."""
+    from paddlebox_tpu.obs.program_scopes import scope_map
+
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.float32, sharding=one_chip)
+
+    def step(q, k, v, g):
+        with jax.named_scope("model/mla/scores"):
+            return jax.grad(lambda q, k, v: jnp.sum(causal_attention(q, k, v, 0.14468, 512) * g),
+                            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(step).lower(q, q, v, g).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    kernels = {n: s for n, s in scope_map(text).items() if "causal_attention" in n}
+    assert len(kernels) == 2 and set(kernels.values()) == {"model/mla/scores"}, kernels
+    # no score block in HBM: the padded q and k, the padded dq and dk, the statistics
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 4096 * 32 * 256 * 4
+
+
+def test_xing4s_loss_and_gradient_compile_for_a_v5e_inside_the_memory_4096_tokens_leave(
+        one_chip, monkeypatch):
+    """The cell's model (``benchmark/configs/xing4_29b_a4b_ep8.json``: one
+    record of 4,096 tokens, a dense layer and a scan body of 4 expert layers,
+    four streams), loss and gradient of every leaf and of the rows, the fused
+    path forced: two forward and two backward kernels, and 9.54 GB at the peak
+    today (2.86 of parameters, 2.86 of gradients, 3.8 of temporaries). The
+    superstep adds Adam's two moments (5.6 GB) and the table to that; with
+    more than 10.2 GB here it would not fit the chip's 17.18 at this seq_len."""
+    from benchmark.models import xing4 as build
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "xing4_29b_a4b_ep8.json")) as f:
+        cfg = json.load(f)
+    model = build.build(cfg, 3 + cfg["embedx_dim"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, T, H = cfg["batch_size"], cfg["seq_len"], cfg["hidden_size"]
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(on, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    emb, ids = (jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in ((B, T, H), (B, T)))
+    compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
+        params, emb, ids).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 4
+    assert compiled.memory_analysis().peak_memory_in_bytes < 9.8e9  # 9.54 today
