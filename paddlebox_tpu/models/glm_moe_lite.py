@@ -56,7 +56,7 @@ import numpy as np
 from jax import lax
 
 from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES, LANE, causal_attention
-from paddlebox_tpu.utils.monitor import STAT_ADD
+from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 COUNTERS = ("loss_main", "loss_mtp", "tokens", "held_assignments", "expert_load_max_over_mean")
@@ -337,22 +337,47 @@ def _expert_block(xb, wg, wu, wd, act: str):
 COMBINE_ROWS = 1024  # the most rows one scatter-add joins to the token sum
 
 
+def combine_piece_rows(acc_rows: int) -> int:
+    """The rows of one piece of a block's scatter-add into a token sum of
+    ``acc_rows`` rows: an eighth of the sum's rows, in whole sublanes of 8, at
+    most ``COMBINE_ROWS`` and at least 8. The TPU compiler's form of a scatter
+    turns on the update's rows against the rows it adds into and on nothing
+    else of the shape: up to an eighth (8 R <= N) the scatter runs as written;
+    above it the indices are sorted and the updates read through the
+    permutation (a ``sort`` and a ``gather`` beside the scatter in the
+    optimised HLO), at 2,048, 2,560 and 3,584 columns, float32 and bfloat16
+    alike (compiled for a described v5e: 512 rows into 4,096 as written, 520
+    sorted; 1,024 / 1,032 into 8,192; 2,048 / 2,056 into 16,384). On the chip a
+    float32 piece as written costs 13-20 ns/KB into 4,096 or 8,192 rows at any
+    height from 256 up; sorted, a call costs 0.22-0.25 ms at 2,048 columns,
+    0.84-0.87 at 3,584 and 1.61-1.65 at 2,560 whatever its rows (PERF.md
+    section 6, PR 43). PR 34's 1,024 was that eighth of Trinity's 8,192
+    tokens; Xing4's 896-row blocks into 4,096 tokens stood over theirs. Past
+    1,024 rows a taller piece into 16,384 buys nothing (2 x 2,048 and
+    4 x 1,024 rows: 1.18 ms both)."""
+    return max(8, min(COMBINE_ROWS, acc_rows // 8) // 8 * 8)
+
+
 def _add_rows(acc, tb, rows):
     """acc[tb[r]] += rows[r] over one block's rows (tb == N: padding,
-    dropped), ``COMBINE_ROWS`` rows a scatter-add, cut at static offsets
+    dropped), ``combine_piece_rows`` rows a scatter-add, cut at static offsets
     whatever the rows hold. A block's tokens are distinct (``group_layout``),
     so no two of its rows meet and a token receives the one addition a block
     that a whole block's scatter-add gave it, bit for bit; what the cut buys is
-    the price of a row: above 1,024 update rows the TPU compiler sorts a
-    scatter's indices and reads its updates through the permutation, four
-    times the time a row (PERF.md section 6, PR 34). The pieces a block took
-    are counted at trace time (``model.moe.combine_pieces`` over
-    ``model.moe.combine_calls``)."""
+    the price of a row: a piece over an eighth of the sum's rows takes the
+    compiler's sorted form, twice to twelve times the time (PERF.md section 6,
+    PRs 34 and 43). The pieces a block took are counted at trace time
+    (``model.moe.combine_pieces`` over ``model.moe.combine_calls``), the last
+    call site's piece beside them (``model.moe.combine_piece_rows``,
+    ``model.moe.combine_piece_bytes``)."""
     R = tb.shape[0]
+    P = min(R, combine_piece_rows(acc.shape[0]))
     STAT_ADD("model.moe.combine_calls")
-    STAT_ADD("model.moe.combine_pieces", -(-R // COMBINE_ROWS))
-    for r in range(0, R, COMBINE_ROWS):
-        acc = acc.at[tb[r:r + COMBINE_ROWS]].add(rows[r:r + COMBINE_ROWS], mode="drop")
+    STAT_ADD("model.moe.combine_pieces", -(-R // P))
+    STAT_SET("model.moe.combine_piece_rows", P)
+    STAT_SET("model.moe.combine_piece_bytes", P * rows.shape[1] * rows.dtype.itemsize)
+    for r in range(0, R, P):
+        acc = acc.at[tb[r:r + P]].add(rows[r:r + P], mode="drop")
     return acc
 
 

@@ -852,5 +852,38 @@ def test_xing4s_loss_and_gradient_compile_for_a_v5e_inside_the_memory_4096_token
     emb, ids = (jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in ((B, T, H), (B, T)))
     compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
         params, emb, ids).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 4
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert compiled.memory_analysis().peak_memory_in_bytes < 9.8e9  # 9.54 today
+    # no block's rows join the token sum in the sorted form (PR 43): y, dx and the recomputation's y
+    assert len(re.findall(r" scatter\(.*moe/combine", text)) == 3 * 2  # a block of 896 in two pieces
+    assert not re.findall(r" sort\(.*moe/combine", text)
+
+
+def test_xing4s_piece_joins_the_token_sum_as_written_and_its_whole_block_sorted(one_chip):
+    """What ``glm_moe_lite.combine_piece_rows`` stays under, read from the
+    optimised HLO: a block of 896 rows x 3,584 float32 columns into 4,096
+    tokens, in a ``fori_loop`` as ``_grouped_fwd`` calls it. Cut by
+    ``_add_rows`` (512 + 384 rows, an eighth of the tokens at most) both
+    scatters run as written; whole, the compiler sorts the block's indices
+    and gathers its updates through the permutation (``indices_are_sorted``
+    on the scatter, a ``sort`` and a ``gather`` beside it): 1.00 ms a call on
+    the chip against 0.066 for 256 rows (PERF.md section 6, PRs 42-43)."""
+    N, R, C = 4096, 896, 3584
+
+    def joined(add):
+        def f(tok, upd):
+            def body(j, acc):
+                return add(acc, lax.dynamic_slice_in_dim(tok, j * R, R),
+                           lax.dynamic_slice_in_dim(upd, j * R, R))
+            return lax.fori_loop(0, 4, body, jnp.zeros((N, C), jnp.float32))
+        tok = jax.ShapeDtypeStruct((4 * R,), jnp.int32, sharding=one_chip)
+        upd = jax.ShapeDtypeStruct((4 * R, C), jnp.float32, sharding=one_chip)
+        return jax.jit(f).lower(tok, upd).compile().as_text()
+
+    assert glm.combine_piece_rows(N) == 512
+    cut = joined(glm._add_rows)
+    assert cut.count(" scatter(") == 2 and " sort(" not in cut and "indices_are_sorted=true" not in cut
+    whole = joined(lambda acc, tb, rows: acc.at[tb].add(rows, mode="drop"))
+    assert whole.count(" scatter(") == 1 and " sort(" in whole and " gather(" in whole
+    assert "indices_are_sorted=true" in whole

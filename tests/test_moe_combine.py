@@ -6,7 +6,8 @@ expert layer.
 over block heights below, at and above ``COMBINE_ROWS``; (b) value and ``dx``
 bit for bit what one scatter-add of a whole block gave (the form PR 34
 deleted, kept here as the oracle); (c) the order of a block's rows, which the
-cut relies on; (d) the trace-time counters say how a call site was lowered.
+cut relies on; (d) the trace-time counters say how a call site was lowered;
+(e) the height of a piece, by the rows of the token sum (PR 43).
 """
 
 from __future__ import annotations
@@ -71,6 +72,7 @@ CASES = {
     "every_assignment_held": (8, 40, 3, 3, 2, 0, None),
     "every_assignment_held_tall": (P + P // 2, 2 * P, 2, 2, 2, 0, None),
     "none_held": (8, 40, 6, 2, 2, 6, None),
+    "xing4s_block_into_4096_tokens": (896, 4096, 2, 2, 1, 0, None),
 }
 
 
@@ -101,7 +103,9 @@ def test_routed_experts_give_the_dense_sum_and_its_gradients(case):
     if case == "an_expert_holds_nothing":
         assert held[never] == 0 and not np.any(np.asarray(got_grads[0]["down"][never]))
     if case == "three_pieces_the_last_all_padding":
-        assert P < held.max() <= 2 * P  # real rows in two pieces of a block, none in the third
+        assert P < held.max() <= 2 * P  # real rows in two thirds of a block, none in the last
+    if case == "xing4s_block_into_4096_tokens":
+        assert glm.combine_piece_rows(N) == 512 < R < held.min()  # cut blocks, full and part full
     assert _rel(y, want) < 1e-5
     for name, a, b in [("x", got_grads[1], want_grads[1]), ("g", got_grads[2], want_grads[2])] + [
             (n, got_grads[0][n], want_grads[0][n]) for n in ("gate", "up", "down")]:
@@ -115,9 +119,11 @@ def _whole_block(acc, tb, rows):
     return acc.at[tb].add(rows, mode="drop")
 
 
-@pytest.mark.parametrize("R", [8, 3 * P])
+@pytest.mark.parametrize("R", [8, 3 * P, 896])
 def test_y_and_dx_are_bit_for_bit_what_one_scatter_add_a_block_gave(R, monkeypatch):
-    N, E, G, k = (40, 6, 3, 2) if R == 8 else (3 * P, 2, 2, 1)
+    """896: Xing4's block into 4,096 tokens, pieces of 512 + 384 under ``COMBINE_ROWS``."""
+    N, E, G, k = {8: (40, 6, 3, 2), 3 * P: (3 * P, 2, 2, 1), 896: (4096, 2, 2, 1)}[R]
+    assert glm.combine_piece_rows(N) < R or R == 8
     c = _config(R, E, G, k, 0)
     p, x, idx, g, dy = _inputs(R, N, E, G, k)
 
@@ -165,16 +171,59 @@ def test_a_blocks_real_rows_come_first_their_tokens_ascending_and_distinct(seed)
 
 # ---- (d) the counters -----------------------------------------------------------
 
-@pytest.mark.parametrize("R,pieces", [(8, 1), (P, 1), (P + 8, 2), (3 * P, 3)])
-def test_the_counters_say_how_many_pieces_a_call_sites_block_took(R, pieces):
+@pytest.mark.parametrize("R,N,pieces,piece_rows", [
+    (8, 8 * P, 1, 8), (P, 8 * P, 1, P), (P + 8, 8 * P, 2, P), (3 * P, 8 * P, 3, P),  # an eighth of N is P
+    (896, 4096, 2, 512), (P, 4096, 2, 512), (256, 4096, 1, 256),  # Xing4's tokens: pieces of 512
+    (4 * P, 16 * P, 4, P), (8, 24, 1, 8)])  # never over P, never under 8
+def test_the_counters_say_how_many_pieces_a_call_sites_block_took(R, N, pieces, piece_rows):
     c = _config(R, 4, 2, 2, 0)
-    p, x, idx, g, dy = _inputs(1, 24, 4, 2, 2)
+    p, x, idx, g, dy = _inputs(1, N, 4, 2, 2)
     stats = lambda: np.array([STAT_GET("model.moe.combine_calls"),  # noqa: E731
                               STAT_GET("model.moe.combine_pieces")])
+    piece = lambda: [STAT_GET("model.moe.combine_piece_rows"),  # noqa: E731
+                     STAT_GET("model.moe.combine_piece_bytes")]
     before = stats()
     text = str(jax.make_jaxpr(lambda x: glm.routed_experts(p, x, idx, g, c, "model")[0])(x))
     assert (stats() - before).tolist() == [1, pieces]  # the forward's y
     assert text.count("scatter-add") == pieces
+    assert piece() == [piece_rows, piece_rows * H * 4]  # float32 rows of H columns
     before = stats()
     jax.make_jaxpr(jax.grad(lambda x: jnp.sum(glm.routed_experts(p, x, idx, g, c, "model")[0] * dy)))(x)
     assert (stats() - before).tolist() == [2, 2 * pieces]  # the forward's y and the backward's dx
+    assert piece() == [piece_rows, piece_rows * H * 4]
+
+
+# ---- (e) the height of a piece -------------------------------------------------------
+
+CELLS = {
+    # cell: (expert_block, tokens a step, columns, the pieces a block takes)
+    "glm47_flash": (512, 8192, 2048, [512]),
+    "trinity_mini": (1536, 8192, 2048, [1024, 512]),
+    "smallthinker_21b": (4096, 16384, 2560, [1024] * 4),
+    "sdar_30b_a3b": (7168, 16384, 2048, [1024] * 7),
+    "xing4_29b_a4b": (896, 4096, 3584, [512, 384]),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_a_cells_block_is_cut_an_eighth_of_its_tokens_tall_and_never_over_1024(cell):
+    """The five token cells' blocks (``benchmark/configs/*.json``: ``expert_block``,
+    ``batch_size`` x ``seq_len``, ``hidden_size``): four are cut as PR 34 cut
+    them, Xing4's 896 rows into 4,096 tokens in two. The columns take no part."""
+    R, N, C, want = CELLS[cell]
+    acc = jax.ShapeDtypeStruct((N, C), jnp.float32)
+    tb, rows = jax.ShapeDtypeStruct((R,), jnp.int32), jax.ShapeDtypeStruct((R, C), jnp.float32)
+    eqns = jax.make_jaxpr(glm._add_rows)(acc, tb, rows).jaxpr.eqns
+    got = [e.invars[2].aval.shape[0] for e in eqns if e.primitive.name == "scatter-add"]
+    assert got == want and all(8 * h <= N for h in got)
+    assert STAT_GET("model.moe.combine_piece_rows") == want[0]
+    assert STAT_GET("model.moe.combine_piece_bytes") == want[0] * C * 4
+
+
+@pytest.mark.parametrize("acc_rows", [1, 8, 24, 63, 64, 72, 1000, 4095, 4096, 4104, 8191, 8192, 8200,
+                                      16384, 1 << 20])
+def test_a_piece_is_whole_sublanes_never_none_never_over_1024_never_over_an_eighth(acc_rows):
+    h = glm.combine_piece_rows(acc_rows)
+    assert h % 8 == 0 and 8 <= h <= P
+    assert 8 * h <= acc_rows or h == 8  # the as-written form's side of the line, where 8 rows can be
+    assert h == P or 8 * (h + 8) > acc_rows  # and the tallest such
