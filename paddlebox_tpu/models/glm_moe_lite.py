@@ -328,28 +328,54 @@ def group_layout(expert_of, G: int, R: int):
     return src, blk_expert, ends[-1] // R, counts
 
 
-def _expert_block(xb, wg, wu, wd, act: str):
+def _gate_up(xb, wg, wu, act: str):
     hg, hu = _mm(xb, wg), _mm(xb, wu)
-    h = (jax.nn.silu(hg) if act == "silu" else jax.nn.relu(hg)) * hu
+    return hg, hu, (jax.nn.silu(hg) if act == "silu" else jax.nn.relu(hg)) * hu
+
+
+def _expert_block(xb, wg, wu, wd, act: str):
+    hg, hu, h = _gate_up(xb, wg, wu, act)
     return hg, hu, h, _mm(h, wd)
 
 
 COMBINE_ROWS = 1024  # the most rows one scatter-add joins to the token sum
+COMBINE_BYTES = 96 << 20  # the most bytes of float32 token sum one block loop adds into
+
+
+def combine_parts(acc_rows: int, cols: int) -> Tuple[int, ...]:
+    """The column widths a float32 token sum of ``acc_rows`` x ``cols`` is cut
+    into, each part joined by a block loop of its own: ceil(bytes /
+    ``COMBINE_BYTES``) parts of whole lane tiles, the last taking what is
+    left (one part up to 96 MiB). The bytes decide where the TPU compiler
+    keeps a scatter's accumulator, not its rows: in a ``fori_loop`` whose
+    carry is the sum, ``[8192, 2048]``, ``[16384, 1024]``, ``[16384, 1280]``,
+    ``[12288, 2048]`` (96 MiB), ``[8192, 3584]`` and ``[4096, 3584]`` stay in
+    fast memory (``S(1)`` in the optimised HLO's layouts), ``[16384, 2048]``
+    (128 MiB) and ``[16384, 2560]`` go to HBM, and a piece into HBM costs
+    twice a KB (31-40 ns/KB against 13-18: PERF.md section 6, PRs 43-44;
+    compiled for a described v5e). Two parts carried by one loop are one
+    accumulator of their sum's bytes."""
+    p = -(-acc_rows * cols * 4 // COMBINE_BYTES)
+    w = -(-cols // (p * LANE)) * LANE
+    return tuple(min(w, cols - c) for c in range(0, cols, w))
 
 
 def combine_piece_rows(acc_rows: int) -> int:
     """The rows of one piece of a block's scatter-add into a token sum of
     ``acc_rows`` rows: an eighth of the sum's rows, in whole sublanes of 8, at
-    most ``COMBINE_ROWS`` and at least 8. The TPU compiler's form of a scatter
-    turns on the update's rows against the rows it adds into and on nothing
-    else of the shape: up to an eighth (8 R <= N) the scatter runs as written;
+    most ``COMBINE_ROWS`` and at least 8. The rows decide the scatter's form,
+    the bytes where its sum lives (``combine_parts``). The TPU compiler's form
+    of a scatter turns on the update's rows against the rows it adds into and
+    on nothing else of the shape: up to an eighth (8 R <= N) the scatter runs as written;
     above it the indices are sorted and the updates read through the
     permutation (a ``sort`` and a ``gather`` beside the scatter in the
     optimised HLO), at 2,048, 2,560 and 3,584 columns, float32 and bfloat16
     alike (compiled for a described v5e: 512 rows into 4,096 as written, 520
     sorted; 1,024 / 1,032 into 8,192; 2,048 / 2,056 into 16,384). On the chip a
-    float32 piece as written costs 13-20 ns/KB into 4,096 or 8,192 rows at any
-    height from 256 up; sorted, a call costs 0.22-0.25 ms at 2,048 columns,
+    float32 piece as written costs 13-20 ns/KB at any height from 256 up into
+    a sum in fast memory (4,096 or 8,192 rows in PR 43's grid), 31-40 into one
+    in HBM (its 16,384 rows: 128 MiB and more, ``combine_parts``); sorted, a
+    call costs 0.22-0.25 ms at 2,048 columns,
     0.84-0.87 at 3,584 and 1.61-1.65 at 2,560 whatever its rows (PERF.md
     section 6, PR 43). PR 34's 1,024 was that eighth of Trinity's 8,192
     tokens; Xing4's 896-row blocks into 4,096 tokens stood over theirs. Past
@@ -366,7 +392,9 @@ def _add_rows(acc, tb, rows):
     that a whole block's scatter-add gave it, bit for bit; what the cut buys is
     the price of a row: a piece over an eighth of the sum's rows takes the
     compiler's sorted form, twice to twelve times the time (PERF.md section 6,
-    PRs 34 and 43). The pieces a block took are counted at trace time
+    PRs 34 and 43). The rows decide that form; the bytes of ``acc`` decide
+    whether it stays in fast memory, and the caller cuts it by columns for that
+    (``combine_parts``, PR 44). The pieces a block took are counted at trace time
     (``model.moe.combine_pieces`` over ``model.moe.combine_calls``), the last
     call site's piece beside them (``model.moe.combine_piece_rows``,
     ``model.moe.combine_piece_bytes``)."""
@@ -395,8 +423,50 @@ def grouped_experts(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R: int, scop
     distinct and ascending, its padding after. One pass over the ``n_blocks``
     blocks in use: gather the block's tokens, the expert's three products,
     add the weighted rows to their tokens (``_add_rows``, which depends on
-    that order: a token at most once a block; the backward's ``dx`` likewise)."""
+    that order: a token at most once a block; the backward's ``dx`` likewise).
+    Where the float32 sum is cut by columns (``combine_parts``), that pass
+    keeps what the last product reads (the forward's ``h``, the backward's
+    ``dhg`` and ``dhu``: bfloat16 ``[M, I]``, as the products read them) and
+    adds nothing; one more pass over the blocks a part then runs that
+    product for the part's columns and joins them (``_join_parts``): every
+    element receives the additions it did in one pass, in the same order,
+    bit for bit."""
     return _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope, act)[0]
+
+
+def _column_parts(N: int, H: int):
+    """``combine_parts`` as (first, end) column bounds, counted at trace time
+    (``model.moe.combine_parts``, ``model.moe.combine_part_bytes``: the last
+    call site's)."""
+    widths = combine_parts(N, H)
+    STAT_SET("model.moe.combine_parts", len(widths))
+    STAT_SET("model.moe.combine_part_bytes", N * widths[0] * 4)
+    ends = np.cumsum(widths).tolist()
+    return [(b - w, b) for w, b in zip(widths, ends)]
+
+
+def _join_parts(N, cols, stash, n_blocks, R, tok, blk_expert, scope, block_rows):
+    """The token sum [N, H] joined one column part a loop over the blocks,
+    from what the blocks' pass kept (``stash``, rows j R .. j R + R a block):
+    ``block_rows(j, e, kept, a, b)`` gives block j's rows of columns a .. b.
+    Each loop carries its part alone, behind a barrier, so that the compiler
+    keeps it in fast memory (``combine_parts``)."""
+    parts = []
+    for a, b in cols:
+        def body(j, acc, a=a, b=b):
+            e = blk_expert[j]
+            tb = lax.dynamic_slice_in_dim(tok, j * R, R)
+            with jax.named_scope(f"{scope}/moe/combine"):
+                kept = tuple(lax.dynamic_slice_in_dim(s, j * R, R) for s in stash)
+            with jax.named_scope(f"{scope}/moe/experts"):
+                rows = block_rows(j, e, kept, a, b)
+            with jax.named_scope(f"{scope}/moe/combine"):
+                return _add_rows(acc, tb, rows)
+
+        parts, stash = lax.optimization_barrier((parts, stash))
+        parts.append(lax.fori_loop(0, n_blocks, body, jnp.zeros((N, b - a), F32)))
+    with jax.named_scope(f"{scope}/moe/combine"):
+        return jnp.concatenate(parts, axis=1)
 
 
 def _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope, act):
@@ -406,6 +476,8 @@ def _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope, act):
     xe = jnp.concatenate([x.astype(BF16), jnp.zeros((1, H), BF16)])
     res = (x, wg, wu, wd, gate, tok, blk_expert, n_blocks)
     wg, wu, wd = (w.astype(BF16) for w in (wg, wu, wd))  # once, not once a block
+    cols = _column_parts(N, H)
+    cut = len(cols) > 1
 
     def body(j, y):
         e = blk_expert[j]
@@ -413,12 +485,24 @@ def _grouped_fwd(x, wg, wu, wd, gate, tok, blk_expert, n_blocks, R, scope, act):
         gb = lax.dynamic_slice_in_dim(gate, j * R, R)
         with jax.named_scope(f"{scope}/moe/dispatch"):
             xb = xe[tb]
+        if cut:  # y is the kept h
+            with jax.named_scope(f"{scope}/moe/experts"):
+                h = _gate_up(xb, wg[e], wu[e], act)[2]
+            with jax.named_scope(f"{scope}/moe/combine"):
+                return lax.dynamic_update_slice_in_dim(y, h.astype(BF16), j * R, 0)
         with jax.named_scope(f"{scope}/moe/experts"):
             yb = _expert_block(xb, wg[e], wu[e], wd[e], act)[3]
         with jax.named_scope(f"{scope}/moe/combine"):
             return _add_rows(y, tb, yb * gb[:, None])
 
-    return lax.fori_loop(0, n_blocks, body, jnp.zeros((N, H), F32)), res
+    if not cut:
+        return lax.fori_loop(0, n_blocks, body, jnp.zeros((N, H), F32)), res
+    hs = lax.fori_loop(0, n_blocks, body, jnp.zeros((tok.shape[0], wg.shape[2]), BF16))
+
+    def down(j, e, kept, a, b):
+        return _mm(kept[0], wd[e][:, a:b]) * lax.dynamic_slice_in_dim(gate, j * R, R)[:, None]
+
+    return _join_parts(N, cols, (hs,), n_blocks, R, tok, blk_expert, scope, down), res
 
 
 def _grouped_bwd(R, scope, act, res, dy):
@@ -429,12 +513,14 @@ def _grouped_bwd(R, scope, act, res, dy):
     shapes = (x, wg, wu, wd, gate)
     wg, wu, wd = (w.astype(BF16) for w in (wg, wu, wd))
     wgT, wuT, wdT = (jnp.swapaxes(w, 1, 2) for w in (wg, wu, wd))
+    cols = _column_parts(N, H)
+    cut = len(cols) > 1
 
     def add_at(acc, e, upd):
         return lax.dynamic_update_index_in_dim(acc, acc[e] + upd, e, 0)
 
     def body(j, carry):
-        dx, dwg, dwu, dwd, dgate = carry
+        dx, dwg, dwu, dwd, dgate = carry  # where the sum is cut, dx is the kept (dhg, dhu)
         e = blk_expert[j]
         tb = lax.dynamic_slice_in_dim(tok, j * R, R)
         gb = lax.dynamic_slice_in_dim(gate, j * R, R)
@@ -455,14 +541,27 @@ def _grouped_bwd(R, scope, act, res, dy):
             dwd = add_at(dwd, e, jnp.dot(h.astype(BF16).T, dyb, preferred_element_type=F32))
             dwg = add_at(dwg, e, jnp.dot(xb.T, dhg, preferred_element_type=F32))
             dwu = add_at(dwu, e, jnp.dot(xb.T, dhu, preferred_element_type=F32))
-            dxb = (jnp.dot(dhg, wgT[e], preferred_element_type=F32)
-                   + jnp.dot(dhu, wuT[e], preferred_element_type=F32))
+            if not cut:
+                dxb = (jnp.dot(dhg, wgT[e], preferred_element_type=F32)
+                       + jnp.dot(dhu, wuT[e], preferred_element_type=F32))
         with jax.named_scope(f"{scope}/moe/combine"):
-            dx = _add_rows(dx, tb, dxb)
+            if cut:
+                dx = tuple(lax.dynamic_update_slice_in_dim(s, d, j * R, 0)
+                           for s, d in zip(dx, (dhg, dhu)))
+            else:
+                dx = _add_rows(dx, tb, dxb)
             dgate = lax.dynamic_update_slice_in_dim(dgate, dgb, j * R, 0)
         return dx, dwg, dwu, dwd, dgate
 
-    grads = lax.fori_loop(0, n_blocks, body, tuple(jnp.zeros(a.shape, F32) for a in shapes))
+    dx = (tuple(jnp.zeros((tok.shape[0], wg.shape[2]), BF16) for _ in range(2)) if cut
+          else jnp.zeros(x.shape, F32))
+    grads = lax.fori_loop(0, n_blocks, body, (dx,) + tuple(jnp.zeros(a.shape, F32) for a in shapes[1:]))
+    if cut:
+        def dx_rows(j, e, kept, a, b):
+            return (jnp.dot(kept[0], wgT[e][:, a:b], preferred_element_type=F32)
+                    + jnp.dot(kept[1], wuT[e][:, a:b], preferred_element_type=F32))
+
+        grads = (_join_parts(N, cols, grads[0], n_blocks, R, tok, blk_expert, scope, dx_rows),) + grads[1:]
     return tuple(g.astype(a.dtype) for g, a in zip(grads, shapes)) + (None, None, None)
 
 

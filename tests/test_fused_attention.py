@@ -753,7 +753,13 @@ def test_smallthinkers_loss_and_gradient_compile_for_a_v5e_with_each_forward_ker
     compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
         params, emb, ids).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == (4 if kept else 6)
-    assert compiled.memory_analysis().temp_size_in_bytes < (6.1e9 if kept else 3.9e9)  # 5.99 / 3.84 today
+    # since PR 44 the token sum is cut in two parts of 1,280 columns (``glm.combine_parts``): the
+    # backward keeps dhg and dhu, bfloat16 [M, 768] each with M = 32 blocks of 4,096 rows (0.40 GB),
+    # where the checkpoint recomputes the forward it also keeps h (0.20 GB), and a part's 84 MB
+    # stands beside the joined sum once (6.19 / 4.52 GB; 5.99 / 3.84 before)
+    stash = 2 * 32 * 4096 * 768 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        6.1e9 + stash if kept else 3.9e9 + 1.5 * stash + 16384 * 1280 * 4)
 
 
 def test_diffusion_kernels_compile_for_a_v5e_at_the_sdar_cells_shapes(one_chip):
@@ -803,7 +809,9 @@ def test_sdars_loss_and_gradient_compile_for_a_v5e_with_one_kernel_pair(one_chip
     compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
         params, emb, ids).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
-    assert compiled.memory_analysis().temp_size_in_bytes < 6.6e9  # 6.31 today
+    # 6.31 before PR 44 and 6.30 with the token sum cut in two: the backward's kept dhg and dhu
+    # (bfloat16 [250,880, 768] each, 0.77 GB) do not stand at the temporaries' peak
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.6e9
 
 
 def test_two_width_kernels_compile_for_a_v5e_at_the_xing4_cells_shapes(one_chip):
@@ -887,3 +895,38 @@ def test_xing4s_piece_joins_the_token_sum_as_written_and_its_whole_block_sorted(
     whole = joined(lambda acc, tb, rows: acc.at[tb].add(rows, mode="drop"))
     assert whole.count(" scatter(") == 1 and " sort(" in whole and " gather(" in whole
     assert "indices_are_sorted=true" in whole
+
+
+@pytest.mark.parametrize("cell", ["sdar", "xing4"])
+def test_the_combines_token_sum_stays_in_fast_memory_cut_where_it_is_over_96_mib(one_chip, cell):
+    """``grouped_experts``, forward and gradient through ``routed_experts``, at
+    a cell's token sum, compiled for a described v5e (fewer held experts than
+    the cell's: the sum's shape is what decides). SDAR's float32 sum of
+    16,384 x 2,048 (128 MiB) took every scatter into HBM before PR 44; cut in
+    two parts of 1,024 columns, each joined by a loop of its own, every
+    ``moe/combine`` scatter's output is in ``S(1)``: the cell's block of
+    7,168 rows as 7 pieces of 1,024 into each part, forward and backward (at
+    blocks of 2,048 the compiler leaves one of the backward's two loops in
+    HBM: PERF.md section 7). Xing4's
+    4,096 x 3,584 (56 MiB) stays one part, with the four scatters it had
+    (PR 43: a block of 896 as 512 + 384, forward and backward)."""
+    N, H, I, R, k, parts, scatters = {"sdar": (16384, 2048, 768, 7168, 2, (1024, 1024), 7 * 2 * 2),
+                                      "xing4": (4096, 3584, 1024, 896, 2, (3584,), 2 * 2)}[cell]
+    G = 4
+    c = GlmMoeLiteConfig(hidden_size=H, moe_intermediate_size=I, n_routed_experts=4 * G,
+                         num_experts_per_tok=k, experts_held=G, expert_block=R)
+    on = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    p = {"gate": on(G, H, I), "up": on(G, H, I), "down": on(G, I, H)}
+
+    def loss(p, x, g, idx, dy):
+        return jnp.sum(glm.routed_experts(p, x, idx, g, c, "model")[0] * dy)
+
+    assert glm.combine_parts(N, H) == parts
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        p, on(N, H), on(N, k), on(N, k, dt=jnp.int32), on(N, H)).compile().as_text()
+    assert STAT_GET("model.moe.combine_parts") == len(parts)
+    # each moe/combine scatter fusion: is its output (the sum, or a part of it) in fast memory
+    in_fast = [("S(1)" in line.split(" fusion(")[0]) for line in text.splitlines()
+               if " fusion(" in line and "moe/combine/scatter-add" in line]
+    assert len(in_fast) == scatters and all(in_fast), in_fast
+    assert not re.findall(r" sort\(.*moe/combine", text)

@@ -7,7 +7,9 @@ over block heights below, at and above ``COMBINE_ROWS``; (b) value and ``dx``
 bit for bit what one scatter-add of a whole block gave (the form PR 34
 deleted, kept here as the oracle); (c) the order of a block's rows, which the
 cut relies on; (d) the trace-time counters say how a call site was lowered;
-(e) the height of a piece, by the rows of the token sum (PR 43).
+(e) the height of a piece, by the rows of the token sum (PR 43); (f) the
+token sum cut by columns into parts that fit fast memory, bit for bit the
+one-part result (PR 44).
 """
 
 from __future__ import annotations
@@ -227,3 +229,65 @@ def test_a_piece_is_whole_sublanes_never_none_never_over_1024_never_over_an_eigh
     assert h % 8 == 0 and 8 <= h <= P
     assert 8 * h <= acc_rows or h == 8  # the as-written form's side of the line, where 8 rows can be
     assert h == P or 8 * (h + 8) > acc_rows  # and the tallest such
+
+
+# ---- (f) the token sum cut by columns (PR 44) -------------------------------------
+
+WIDE = 3 * 128  # three lane tiles: a sum of it can be cut in one, two or three parts
+
+
+@pytest.mark.parametrize("parts,widths", [(2, (256, 128)), (3, (128, 128, 128))])
+def test_a_sum_cut_by_columns_gives_the_one_part_result_bit_for_bit(parts, widths, monkeypatch):
+    """Output and the five gradients (x, the gate, up and down products, the
+    routing weights) of ``routed_experts`` with its token sum cut into 2 and 3
+    parts by a smaller budget, against one part: several blocks an expert,
+    each expert's last block real rows then padding, one held expert chosen by
+    no token."""
+    N, E, G, k, R = 60, 4, 3, 2, 8
+    c = GlmMoeLiteConfig(hidden_size=WIDE, moe_intermediate_size=I, n_routed_experts=E,
+                         num_experts_per_tok=k, experts_held=G, experts_offset=0, expert_block=R)
+    rng = np.random.default_rng(44)
+    w = lambda *s: jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)  # noqa: E731
+    p = {"gate": w(G, WIDE, I), "up": w(G, WIDE, I), "down": w(G, I, WIDE)}
+    score = rng.random((N, E))
+    score[:, 1] = -1.0
+    idx = jnp.asarray(np.argsort(-score, axis=1)[:, :k], jnp.int32)
+    g = jnp.asarray(rng.random((N, k)) + 0.1, jnp.float32)
+    x, dy = w(N, WIDE), w(N, WIDE)
+    held = np.bincount(np.asarray(idx).ravel(), minlength=E)[:G]
+    assert held[1] == 0 and (held[[0, 2]] > 2 * R).all() and (held[[0, 2]] % R).all()
+
+    def run():
+        def loss(p, x, g):
+            return jnp.sum(glm.routed_experts(p, x, idx, g, c, "model")[0] * dy)
+        y = jax.jit(lambda p, x, g: glm.routed_experts(p, x, idx, g, c, "model")[0])(p, x, g)
+        return [np.asarray(a) for a in [y] + jax.tree.leaves(jax.jit(jax.grad(loss, (0, 1, 2)))(p, x, g))]
+
+    assert glm.combine_parts(N, WIDE) == (WIDE,)
+    want = run()
+    monkeypatch.setattr(glm, "COMBINE_BYTES", N * WIDE * 4 // parts)
+    assert glm.combine_parts(N, WIDE) == widths
+    got = run()
+    assert STAT_GET("model.moe.combine_parts") == parts
+    assert STAT_GET("model.moe.combine_part_bytes") == N * widths[0] * 4
+    assert all(np.any(a) for a in want)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("rows,cols,widths", [
+    (16384, 2048, (1024, 1024)),  # SDAR's cell: 128 MiB
+    (16384, 2560, (1280, 1280)),  # SmallThinker's: 160 MiB
+    (8192, 2048, (2048,)),  # GLM's and Trinity's: 64 MiB
+    (8192, 3584, (1792, 1792)),  # 112 MiB: over the budget, though no cell has it
+    (4096, 3584, (3584,)),  # Xing4's: 56 MiB
+    (12288, 2048, (2048,)),  # 96 MiB: the budget itself
+    (12296, 2048, (1024, 1024)),
+    (16384, 3584, (1280, 1280, 1024)),
+    (65536, 2560, (384,) * 6 + (256,)),
+])
+def test_a_token_sum_is_cut_in_parts_of_whole_lane_tiles_under_the_budget(rows, cols, widths):
+    got = glm.combine_parts(rows, cols)
+    assert got == widths and sum(got) == cols
+    assert all(w % 128 == 0 for w in got[:-1]) and 0 < got[-1] <= got[0]
+    assert len(got) <= -(-rows * cols * 4 // glm.COMBINE_BYTES)  # never more parts than the bytes ask
