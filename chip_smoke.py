@@ -14,7 +14,7 @@ weights and data made from a seed:
 1. the Pallas kernel that is on a cell's path, the fused causal attention of
    ``ops/pallas_kernels.py``, compiled WITHOUT interpret at the token cell's
    shape, forward and backward, and checked against the blocked XLA form it
-   replaces on a TPU (``models/glm_moe_lite.py::_attend_block``);
+   replaces on a TPU (``models/attention.py::_attend_block``);
 2. a three-pass day: ``BoxWrapper.make_dataset`` -> ``load_into_memory`` ->
    ``begin_pass`` -> ``CTRTrainer.prepare_pass`` / ``train_pass`` ->
    ``end_pass(trained_table_device())``, with ``save_base`` after pass 1 and
@@ -63,7 +63,7 @@ from paddlebox_tpu import BoxWrapper, config
 from paddlebox_tpu.data import SlotInfo, SlotSchema
 from paddlebox_tpu.data.parser import parse_line
 from paddlebox_tpu.models import DeepFM
-from paddlebox_tpu.models.glm_moe_lite import _attend_block
+from paddlebox_tpu.models.attention import _attend_block
 from paddlebox_tpu.ops.pallas_kernels import causal_attention
 from paddlebox_tpu.parallel import make_mesh
 from paddlebox_tpu.serve import Follower, Scorer, ScoreServer, table_source
@@ -212,7 +212,8 @@ def check_pallas_kernels(batch: int, seq: int, heads: int, head_dim: int, block:
 
     def blocked(q, k, v):
         return jnp.concatenate(
-            [_attend_block(q, k, v, i, block, scale) for i in range(0, seq, block)], axis=1)
+            [_attend_block(q, k, v, i, block, scale, 1, None, None) for i in range(0, seq, block)],
+            axis=1)
 
     def both_ways(f, q, k, v, g):
         o, back = jax.vjp(f, q, k, v)

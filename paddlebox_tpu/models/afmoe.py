@@ -1,11 +1,9 @@
 """Trinity (``afmoe``): grouped-query attention with QK-norm and an output
 gate, window layers with rope beside full layers without positions in one
 stack, routed experts beside a shared one; a language model trained through
-the pass path, the second ``SequenceLossModel`` (``models/base.py``) beside
-``models/glm_moe_lite.py``, whose pieces it shares (``rms_norm``, rope,
-``_mm``, ``swiglu``, ``route``, ``routed_experts``, ``head_logits``: an
-optimisation of one is measured on both, and on ``models/smallthinker.py``
-and ``models/sdar.py``, the third and fourth, which share pieces of this one).
+the pass path (``models/base.py::SequenceLossModel``). Its scores are
+``models/attention.py``'s, its expert layer ``models/moe.py``'s, its norms,
+rope, head, loss and counters ``models/lm_layers.py``'s.
 
 The step hands it the pulled rows of the one token slot unpooled, as
 ``[B, T, hidden]`` in record order, and the record's dense slot of T token
@@ -22,46 +20,42 @@ only ``sliding_window`` keys back; ``x += norm((o * sigmoid(gate)) W_o)``;
 ``x += norm(F(norm(x)))``, F a SwiGLU or the experts. The input is scaled by
 sqrt(hidden) (``mup_enabled``).
 
-Precision as ``glm_moe_lite``: float32 but for the bfloat16 operands of the
-matrix products. Memory: every layer recomputed in the backward from its
-input, but for the fused scores' float32 output and logsumexp, which each
-layer's checkpoint keeps by name (``ops/pallas_kernels.py::KEEP_SCORES``; 0.67
-GB over the cell's five layers of one 8k record): q, k, v are the
-recomputation's anyway, so the backward kernel is fed without the forward
-kernel's second run. The expert layers are one stacked body under
+Precision: float32 but for the bfloat16 operands of the matrix products.
+Memory: every layer recomputed in the backward from its input, but for the
+fused scores' float32 output and logsumexp (``models/attention.py``; 0.67 GB
+over the cell's five layers of one 8k record). The expert layers are one stacked body under
 ``lax.scan`` **whose step is told its kind** (a traced flag: ``lax.cond``
 picks rope and window or neither, so both kinds compile once whatever their
-order); the scores take the fused kernel
-(``ops/pallas_kernels.py::causal_attention`` with ``group`` and ``window``) on
-a TPU at shapes it tiles and query blocks against their visible keys
-(``_attend_block``) everywhere else, chosen and counted at trace time
-(``fused_scores``; ``model.attn.fused_window_scores`` /
-``model.attn.fused_full_scores`` / ``model.attn.blocked_scores``).
+order); the scores take the fused kernel with ``group`` and ``window`` on a
+TPU at shapes it tiles and query blocks elsewhere, counted at trace time
+under ``model.attn.fused_window_scores`` / ``model.attn.fused_full_scores`` /
+``model.attn.blocked_scores``.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, fields
+from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddlebox_tpu.models.glm_moe_lite import (
-    BF16, F32, _mm, _product, apply_rope, head_logits, rms_norm, rope_tables, route,
-    routed_experts, swiglu)
-from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES, LANE, causal_attention
+from paddlebox_tpu.models.attention import window_or_full
+from paddlebox_tpu.models.lm_layers import (
+    F32, WINDOW_COUNTERS, GroupedQueryConfig, TokenModel, _mm, feed_ids, record_window_counters,
+    rms_norm, rope_tables, step_counters, swiglu, window_loss)
+from paddlebox_tpu.models.moe import route, routed_experts
+from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
 from paddlebox_tpu.utils.monitor import STAT_ADD
 
 SLIDING, FULL = "sliding_attention", "full_attention"
-COUNTERS = ("loss_in_window", "loss_past_window", "tokens", "held_assignments",
-            "expert_load_max_over_mean")
+COUNTERS = WINDOW_COUNTERS
 
 
 @dataclass(frozen=True)
-class AfmoeConfig:
+class AfmoeConfig(GroupedQueryConfig):
     """Keys as in the published ``config.json``; ``layer_types`` (with
     ``num_dense_layers`` of them dense, leading) and ``vocab_size`` are what
     this instance holds, ``num_experts`` what the router scores."""
@@ -94,84 +88,16 @@ class AfmoeConfig:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if not set(self.layer_types) <= {SLIDING, FULL}:
             raise ValueError(f"layer_types {self.layer_types}")
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError("query heads are not a multiple of the key-value heads")
-
-    @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "AfmoeConfig":
-        names = {f.name for f in fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
-
-    @property
-    def routed_scaling_factor(self) -> float:  # the name ``glm_moe_lite.route`` reads
-        return self.route_scale
-
-    @property
-    def group(self) -> int:
-        return self.num_attention_heads // self.num_key_value_heads
+        super().__post_init__()
 
 
 # ---- attention ------------------------------------------------------------------
 
 
-@partial(jax.checkpoint, static_argnums=(3, 4, 5, 6, 7))
-def _attend_block(q, k, v, q0: int, n_q: int, scale: float, group: int, window: Optional[int]):
-    """Queries q0 .. q0 + n_q against the keys they may see: their causal
-    prefix, with a window its last ``window`` keys. q [B, T, H, D], k and v
-    [B, T, H / group, D], whole (see ``glm_moe_lite._attend_block``). A
-    group's query heads are folded into the query axis: the two products are
-    then those of equal head counts."""
-    B, _, nh, d = q.shape
-    k0 = 0 if window is None else max(0, q0 - window + 1)
-    q, k, v = q[:, q0:q0 + n_q], k[:, k0:q0 + n_q], v[:, k0:q0 + n_q]
-    q = q.reshape(B, n_q, nh // group, group, d).transpose(0, 3, 1, 2, 4).reshape(
-        B, group * n_q, nh // group, d)
-    s = _product("bqhd,bkhd->bhqk")(q, k) * scale
-    qi = q0 + jnp.tile(jnp.arange(n_q), group)[:, None]
-    kj = k0 + jnp.arange(k.shape[1])[None, :]
-    seen = kj <= qi if window is None else (kj <= qi) & (qi - kj < window)
-    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
-    o = _product("bhqk,bkhd->bqhd")(p, v)
-    return o.reshape(B, group, n_q, nh // group, d).transpose(0, 2, 3, 1, 4).reshape(B, n_q, nh, d)
-
-
-def fused_scores(backend: str, T: int, head_dim: int, block: int, window: Optional[int]) -> bool:
-    """Whether a call site of ``attention`` takes the fused kernel: on a TPU,
-    at shapes the kernel tiles. Everything else runs the blocked form."""
-    return (backend == "tpu" and head_dim % LANE == 0 and block % LANE == 0 and T % block == 0
-            and (window is None or window >= T or window % block == 0))
-
-
-def _scores(q, k, v, c: AfmoeConfig, rope, sliding: bool, scope: str):
-    """One kind's part of the block: rope (sliding layers alone), the casts,
-    the scores. q [B, T, H, D], k, v [B, T, H / group, D] float32."""
-    T = q.shape[1]
-    with jax.named_scope(f"{scope}/attn/qk_norm_rope"):
-        if sliding:
-            q, k = apply_rope(q, *rope), apply_rope(k, *rope)
-        q, k, v = q.astype(BF16), k.astype(BF16), v.astype(BF16)
-    window = c.sliding_window if sliding and c.sliding_window < T else None
-    with jax.named_scope(f"{scope}/attn/scores_window" if sliding else f"{scope}/attn/scores_full"):
-        Q = min(c.attn_block, T)
-        if T % Q:
-            raise ValueError(f"seq_len {T} is not a multiple of attn_block {Q}")
-        scale = float(c.head_dim) ** -0.5
-        if fused_scores(jax.default_backend(), T, c.head_dim, Q, window):
-            # call sites lowered each way, at trace time
-            if sliding:
-                STAT_ADD("model.attn.fused_window_scores")
-            else:
-                STAT_ADD("model.attn.fused_full_scores")
-            return causal_attention(q, k, v, scale, Q, False, c.group, window)
-        STAT_ADD("model.attn.blocked_scores")
-        return jnp.concatenate(
-            [_attend_block(q, k, v, i, Q, scale, c.group, window) for i in range(0, T, Q)], axis=1)
-
-
 def attention(p, x, w_in, w_post, c: AfmoeConfig, rope, sliding, scope: str = "model"):
     """x + norm(attention(norm(x))). x [B, T, H]. ``sliding`` is the layer's
     kind: a bool, or a traced flag where a scan's step is told it. Every leaf
-    scope is named in full (see ``glm_moe_lite.mla``)."""
+    scope is named in full (see ``glm_moe_lite.mla_branch``)."""
     B, T, _ = x.shape
     nh, nkv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     with jax.named_scope(f"{scope}/attn/qkvg_proj"):
@@ -183,11 +109,8 @@ def attention(p, x, w_in, w_post, c: AfmoeConfig, rope, sliding, scope: str = "m
     with jax.named_scope(f"{scope}/attn/qk_norm_rope"):
         q = rms_norm(q, p["q_norm"], c.rms_norm_eps)
         k = rms_norm(k, p["k_norm"], c.rms_norm_eps)
-    window, full = (partial(_scores, c=c, rope=rope, sliding=s, scope=scope) for s in (True, False))
-    if isinstance(sliding, bool):
-        o = (window if sliding else full)(q, k, v)
-    else:
-        o = lax.cond(sliding, window, full, q, k, v)
+    o = window_or_full(q, k, v, rope, sliding, sliding_window=c.sliding_window,
+                       block=c.attn_block, group=c.group, scope=scope)
     with jax.named_scope(f"{scope}/attn/out_proj"):
         y = _mm(o.reshape(B, T, nh * d) * jax.nn.sigmoid(gate), p["o"])
         return x + rms_norm(y, w_post, c.rms_norm_eps)
@@ -209,81 +132,23 @@ def moe_layer(p, x, c: AfmoeConfig, rope, sliding, scope: str = "model"):
     h = attention(p["attn"], x, p["ln_in"], p["ln_post_attn"], c, rope, sliding, scope)
     with jax.named_scope(f"{scope}/moe/router"):
         flat = rms_norm(h, p["ln_pre_mlp"], c.rms_norm_eps).reshape(B * T, H)
-        idx, g = route(p["router"], flat, c)
-    routed, counts = routed_experts(p["experts"], flat, idx, g, c, scope)
+        idx, g = route(p["router"], flat, c.num_experts_per_tok, scale=c.route_scale)
+    routed, counts = routed_experts(p["experts"], flat, idx, g, c.experts_held, c.experts_offset,
+                                    c.expert_block, scope)
     with jax.named_scope(f"{scope}/moe/shared"):
         y = (swiglu(p["shared"], flat) + routed).reshape(B, T, H)
         out = h + rms_norm(y, p["ln_post_mlp"], c.rms_norm_eps)
     return out, idx.reshape(B, T, -1), counts
 
 
-# ---- the loss and the counters of a window-and-full model (``smallthinker`` shares them) ----
-
-
-def feed_ids(emb, ids, c):
-    """The record's token ids as int32, once the feed fits the model."""
-    B, T, _ = emb.shape
-    if T != c.seq_len or ids.shape != (B, T):
-        raise ValueError(f"sequence feed of {emb.shape} / {ids.shape}, seq_len {c.seq_len}")
-    return ids.astype(jnp.int32)
-
-
-def window_loss(params, x, ids, c) -> Dict[str, Any]:
-    """The last hidden state x [B, T, H] through the final norm and the head
-    (``params["final_norm"]``, ``params["head"]``) against the next token:
-    ``parts`` [2], ``token_logits`` [2, B, T] and ``loss`` as ``Afmoe.forward``
-    describes them."""
-    B, T, H = x.shape
-    with jax.named_scope("loss/head"):
-        pos = jnp.arange(T)
-        targets = jnp.concatenate([ids[:, 1:], jnp.zeros((B, 1), jnp.int32)], axis=1)
-        h = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-        tl, lse = head_logits(params["head"], h.reshape(B * T, H), targets.reshape(-1),
-                              c.loss_block)
-        tl, lse = tl.reshape(1, B, T), lse.reshape(1, B, T)
-        has = pos < T - 1
-        mask = jnp.stack([has & (pos < c.sliding_window),
-                          has & (pos >= c.sliding_window)]).astype(F32)[:, None, :]
-        sums = jnp.sum((lse - tl) * mask, axis=(1, 2))
-        parts = sums / jnp.maximum(B * jnp.sum(mask, axis=(1, 2)), 1.0)
-        loss = jnp.sum(sums) / (B * (T - 1))
-    return {"parts": parts, "token_logits": jnp.concatenate([tl, lse]), "loss": loss}
-
-
-def window_counters(out, emb) -> list:
-    """``COUNTERS`` of one batch, from what ``forward`` gave."""
-    parts, loads = out["parts"], out["loads"].astype(F32)
-    return [parts[0], parts[1], jnp.asarray(float(emb.shape[0] * emb.shape[1])),
-            jnp.sum(loads), jnp.max(loads) / jnp.maximum(jnp.mean(loads), 1e-9)]
-
-
-def record_window_counters(means) -> None:
-    """A pass's mean ``COUNTERS`` into the monitor registry (literal names)."""
-    from paddlebox_tpu.utils.monitor import STAT_SET
-
-    STAT_SET("model.loss_in_window", float(means[0]))
-    STAT_SET("model.loss_past_window", float(means[1]))
-    STAT_SET("model.tokens_per_step", float(means[2]))
-    STAT_SET("model.held_assignments_per_step", float(means[3]))
-    STAT_SET("model.expert_load_max_over_mean", float(means[4]))
-
-
 # ---- the model ------------------------------------------------------------------
 
 
-class Afmoe:
+class Afmoe(TokenModel):
     """``apply(params, emb [B, T, H], ids [B, T]) -> (loss, {"counters": [5]})``;
     ``forward`` gives the logit terms and expert choices behind it."""
 
-    sequence_feed = True  # the step feeds the slot's rows unpooled and takes the loss from here
     counter_names = COUNTERS
-
-    def __init__(self, cfg: AfmoeConfig):
-        self.cfg = cfg
-        self.num_slots = 1
-        self.seq_len = cfg.seq_len
-        self.dense_dim = cfg.seq_len  # the record's dense slot: its T token ids
-        self.feat_width = 3 + cfg.hidden_size
 
     # -- parameters
 
@@ -295,13 +160,6 @@ class Afmoe:
         return {"q": w(ks[0], H, nh * d), "k": w(ks[1], H, nkv * d), "v": w(ks[2], H, nkv * d),
                 "gate": w(ks[3], H, nh * d), "o": w(ks[4], nh * d, H),
                 "q_norm": jnp.ones((d,)), "k_norm": jnp.ones((d,))}
-
-    def _mlp_init(self, key, width, lead=()):
-        c = self.cfg
-        ks = jax.random.split(key, 3)
-        w = lambda k, *s: jax.random.normal(k, lead + s, F32) * c.initializer_range  # noqa: E731
-        return {"gate": w(ks[0], c.hidden_size, width), "up": w(ks[1], c.hidden_size, width),
-                "down": w(ks[2], width, c.hidden_size)}
 
     def _layer_init(self, key, moe: bool):
         c = self.cfg
@@ -372,9 +230,11 @@ class Afmoe:
         positions that have a target. emb [B, T, H]: the token slot's pulled
         rows, CVM columns dropped; ids [B, T]: the record's token ids (whole
         numbers in float32 or int32), relative to the held slice."""
-        ids = feed_ids(emb, ids, self.cfg)
+        c = self.cfg
+        ids = feed_ids(emb, ids, c.seq_len)
         x, choices, loads = self.hidden_states(params, emb)
-        return {**window_loss(params, x, ids, self.cfg), "router_choices": choices, "loads": loads}
+        return {**window_loss(params, x, ids, c.sliding_window, c.rms_norm_eps, c.loss_block),
+                "router_choices": choices, "loads": loads}
 
     def apply(self, params, emb, ids):
         """The training loss of one batch (``forward``'s arguments) and the
@@ -382,7 +242,8 @@ class Afmoe:
         ``counter_names``."""
         out = self.forward(params, emb, ids)
         with jax.named_scope("loss/head"):
-            counters = jnp.stack(window_counters(out, emb))
+            counters = jnp.stack(step_counters(out["parts"], out["loads"].astype(F32),
+                                               emb.shape[0] * emb.shape[1]))
         return out["loss"], {"counters": lax.stop_gradient(counters)}
 
     record_counters = staticmethod(record_window_counters)

@@ -1,11 +1,9 @@
 """SDAR (``sdar_moe``): **block diffusion** over a grouped-query expert model
-with QK-norm; a language model trained through the pass path, the fourth
-``SequenceLossModel`` (``models/base.py``) beside ``models/glm_moe_lite.py``,
-``models/afmoe.py`` and ``models/smallthinker.py``, whose pieces it shares
-(``rms_norm``, rope, ``_mm``, ``route`` in its form ``softmax_of_chosen``,
-``routed_experts`` with the gate's ``silu``, ``head_logits`` from the first;
-the counters of a share with no shared expert from the third: an optimisation
-of one is measured on all four).
+with QK-norm; a language model trained through the pass path
+(``models/base.py::SequenceLossModel``). Its scores are
+``models/attention.py``'s, its expert layer ``models/moe.py``'s (the router
+in its form ``softmax_of_chosen``, the gate's ``silu``), its norms, rope,
+head and counters ``models/lm_layers.py``'s.
 
 What is trained is not next-token prediction. A record is ``seq_len`` = 2 L
 keys: the clean tokens c_0 .. c_{L-1}, then their noised copy n_0 .. n_{L-1},
@@ -31,21 +29,18 @@ chosen; ``x += sum_k w_k E_k(m)`` over the chosen experts held,
 of whose chosen experts is held gets no feed-forward output here
 (``test_sdar`` adds the shares up to the uncut layer).
 
-Precision as ``glm_moe_lite``: float32 but for the bfloat16 operands of the
-matrix products. Memory: every layer recomputed in the backward from its
-input, but for the fused scores' float32 output and logsumexp, which the
-layer's checkpoint keeps by name (``ops/pallas_kernels.py::KEEP_SCORES``). The
+Precision: float32 but for the bfloat16 operands of the matrix products.
+Memory: every layer recomputed in the backward from its input, but for the
+fused scores' float32 output and logsumexp (``models/attention.py``). The
 stack is one body under ``lax.scan`` over layers that are all alike; the
-scores take the fused kernel (``causal_attention`` with ``diffusion_block``)
-on a TPU at shapes it tiles and query blocks against their visible keys
-(``_attend_block``) everywhere else, chosen and counted at trace time
-(``fused_scores``; ``model.attn.fused_diffusion_scores`` /
-``model.attn.blocked_scores``).
+scores take the fused kernel with ``diffusion_block`` on a TPU at shapes it
+tiles and query blocks elsewhere, counted at trace time under
+``model.attn.fused_diffusion_scores`` / ``model.attn.blocked_scores``.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, fields
+from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict
 
@@ -53,18 +48,21 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddlebox_tpu.models import afmoe, smallthinker
-from paddlebox_tpu.models.glm_moe_lite import (
-    BF16, F32, _mm, _product, apply_rope, head_logits, rms_norm, rope_tables, route,
-    routed_experts)
-from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES, LANE, causal_attention, diffusion_visible
-from paddlebox_tpu.utils.monitor import STAT_ADD
+from paddlebox_tpu.models.attention import scores
+from paddlebox_tpu.models.lm_layers import (
+    BF16, F32, WINDOW_COUNTERS, GroupedQueryConfig, TokenModel, _mm, apply_rope, feed_ids,
+    head_logits, record_load_counters, rms_norm, rope_tables, step_counters)
+from paddlebox_tpu.models.moe import (
+    SHARE_COUNTERS, record_share_counters, route, routed_experts, share_counters)
+from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
+from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
-COUNTERS = ("loss_first_half", "loss_second_half") + smallthinker.COUNTERS[2:] + ("masked_positions",)
+COUNTERS = (("loss_first_half", "loss_second_half") + WINDOW_COUNTERS[2:] + SHARE_COUNTERS
+            + ("masked_positions",))
 
 
 @dataclass(frozen=True)
-class SdarConfig:
+class SdarConfig(GroupedQueryConfig):
     """Keys as in the published ``config.json``; ``num_hidden_layers`` and
     ``vocab_size`` are what this instance holds, ``num_experts`` what the
     router scores. ``seq_len`` counts a record's keys: twice the tokens it
@@ -92,19 +90,9 @@ class SdarConfig:
     expert_block: int = 512  # rows of one grouped product
 
     def __post_init__(self):
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError("query heads are not a multiple of the key-value heads")
+        super().__post_init__()
         if self.seq_len % (2 * self.block_length):
             raise ValueError(f"seq_len {self.seq_len} is not two halves of blocks of {self.block_length}")
-
-    @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "SdarConfig":
-        names = {f.name for f in fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
-
-    @property
-    def group(self) -> int:
-        return self.num_attention_heads // self.num_key_value_heads
 
     @property
     def data_len(self) -> int:  # L: the tokens a record trains
@@ -114,38 +102,10 @@ class SdarConfig:
 # ---- attention ------------------------------------------------------------------
 
 
-@partial(jax.checkpoint, static_argnums=(3, 4, 5, 6, 7))
-def _attend_block(q, k, v, q0: int, n_q: int, scale: float, group: int, block: int):
-    """Queries q0 .. q0 + n_q, inside one half, against the keys they may
-    see: the clean ones through their own blocks and, in the noisy half,
-    their own noisy positions. q [B, 2 L, H, D], k and v [B, 2 L, H / group,
-    D], whole; a group's query heads folded into the query axis
-    (``afmoe._attend_block``)."""
-    B, T, nh, d = q.shape
-    L = T // 2
-    spans = [(0, q0 % L + n_q)] + ([(q0, q0 + n_q)] if q0 >= L else [])
-    k, v = (jnp.concatenate([a[:, lo:hi] for lo, hi in spans], axis=1) for a in (k, v))
-    q = q[:, q0:q0 + n_q].reshape(B, n_q, nh // group, group, d).transpose(0, 3, 1, 2, 4).reshape(
-        B, group * n_q, nh // group, d)
-    s = _product("bqhd,bkhd->bhqk")(q, k) * scale
-    qi = q0 + jnp.tile(jnp.arange(n_q), group)[:, None]
-    kj = jnp.concatenate([jnp.arange(lo, hi) for lo, hi in spans])[None, :]
-    p = jax.nn.softmax(jnp.where(diffusion_visible(qi, kj, L, block), s, -1e30), axis=-1)
-    o = _product("bhqk,bkhd->bqhd")(p, v)
-    return o.reshape(B, group, n_q, nh // group, d).transpose(0, 2, 3, 1, 4).reshape(B, n_q, nh, d)
-
-
-def fused_scores(backend: str, T: int, head_dim: int, block: int, diffusion_block: int) -> bool:
-    """Whether a call site of ``attention`` takes the fused kernel: on a TPU,
-    at shapes the kernel tiles. Everything else runs the blocked form."""
-    return (backend == "tpu" and head_dim % LANE == 0 and block % LANE == 0
-            and T % (2 * block) == 0 and block % diffusion_block == 0)
-
-
 def attention(p, x, w_in, c: SdarConfig, rope, scope: str = "model"):
     """x + attention(norm(x)) W_o under the block-diffusion mask. x [B, 2 L,
     H]; ``rope`` the tables of both halves' positions. No biases, no gate.
-    Every leaf scope is named in full (see ``glm_moe_lite.mla``)."""
+    Every leaf scope is named in full (see ``glm_moe_lite.mla_branch``)."""
     B, T, _ = x.shape
     nh, nkv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     with jax.named_scope(f"{scope}/attn/qkv_proj"):
@@ -158,17 +118,8 @@ def attention(p, x, w_in, c: SdarConfig, rope, scope: str = "model"):
         k = apply_rope(rms_norm(k, p["k_norm"], c.rms_norm_eps), *rope).astype(BF16)
         v = v.astype(BF16)
     with jax.named_scope(f"{scope}/attn/scores_diffusion"):
-        Q = min(c.attn_block, T // 2)
-        if (T // 2) % Q or Q % c.block_length:
-            raise ValueError(f"a half of {T // 2} in query blocks of {Q}, blocks of {c.block_length}")
-        scale = float(d) ** -0.5
-        if fused_scores(jax.default_backend(), T, d, Q, c.block_length):
-            STAT_ADD("model.attn.fused_diffusion_scores")  # call sites lowered each way, at trace time
-            o = causal_attention(q, k, v, scale, Q, False, c.group, None, c.block_length)
-        else:
-            STAT_ADD("model.attn.blocked_scores")
-            o = jnp.concatenate([_attend_block(q, k, v, i, Q, scale, c.group, c.block_length)
-                                 for i in range(0, T, Q)], axis=1)
+        o = scores(q, k, v, scale=float(d) ** -0.5, block=c.attn_block, group=c.group,
+                   diffusion_block=c.block_length, kind="diffusion")
     with jax.named_scope(f"{scope}/attn/out_proj"):
         return x + _mm(o.reshape(B, T, nh * d), p["o"])
 
@@ -179,8 +130,9 @@ def layer(p, x, c: SdarConfig, rope, scope: str = "model"):
     h = attention(p["attn"], x, p["ln_in"], c, rope, scope)
     with jax.named_scope(f"{scope}/moe/router"):
         flat = rms_norm(h, p["ln_post_attn"], c.rms_norm_eps).reshape(B * T, H)
-        idx, g = route(p["router"], flat, c, "softmax_of_chosen")
-    routed, counts = routed_experts(p["experts"], flat, idx, g, c, scope)
+        idx, g = route(p["router"], flat, c.num_experts_per_tok, form="softmax_of_chosen")
+    routed, counts = routed_experts(p["experts"], flat, idx, g, c.experts_held, c.experts_offset,
+                                    c.expert_block, scope)
     with jax.named_scope(f"{scope}/moe/combine"):
         return h + routed.reshape(B, T, H), idx.reshape(B, T, -1), counts
 
@@ -209,19 +161,11 @@ def diffusion_loss(params, x, ids, c: SdarConfig) -> Dict[str, Any]:
 # ---- the model ------------------------------------------------------------------
 
 
-class Sdar:
+class Sdar(TokenModel):
     """``apply(params, emb [B, 2 L, H], ids [B, 2 L]) -> (loss, {"counters":
     [8]})``; ``forward`` gives the logit terms and expert choices behind it."""
 
-    sequence_feed = True  # the step feeds the slot's rows unpooled and takes the loss from here
     counter_names = COUNTERS
-
-    def __init__(self, cfg: SdarConfig):
-        self.cfg = cfg
-        self.num_slots = 1
-        self.seq_len = cfg.seq_len
-        self.dense_dim = cfg.seq_len  # the record's dense slot: its 2 L ids
-        self.feat_width = 3 + cfg.hidden_size
 
     # -- parameters
 
@@ -240,15 +184,7 @@ class Sdar:
         }
 
     def init(self, rng) -> Dict[str, Any]:
-        c = self.cfg
-        n = c.num_hidden_layers
-        ks = jax.random.split(rng, n + 1)
-        return {
-            "layers": jax.tree.map(lambda *a: jnp.stack(a), *[self._layer_init(k) for k in ks[:n]]),
-            "final_norm": jnp.ones((c.hidden_size,)),
-            "head": jax.random.normal(ks[n], (c.hidden_size, c.vocab_size), F32)
-            * c.initializer_range,
-        }
+        return self._stack_init(rng, self.cfg.num_hidden_layers)
 
     # -- forward and loss
 
@@ -281,7 +217,7 @@ class Sdar:
         rows, CVM columns dropped; ids [B, 2 L]: the record's ids, clean then
         noised (whole numbers in float32 or int32), relative to the held
         slice."""
-        ids = afmoe.feed_ids(emb, ids, self.cfg)
+        ids = feed_ids(emb, ids, self.cfg.seq_len)
         x, choices, loads = self.hidden_states(params, emb)
         return {**diffusion_loss(params, x, ids, self.cfg), "router_choices": choices, "loads": loads}
 
@@ -290,22 +226,20 @@ class Sdar:
         one array the step carries out beside it: ``counters``, named by
         ``counter_names`` (``tokens`` counts a record's 2 L rows,
         ``masked_positions`` those of them that carry loss)."""
+        c = self.cfg
         out = self.forward(params, emb, ids)
         with jax.named_scope("loss/head"):
-            counters = jnp.stack(afmoe.window_counters(out, emb)
-                                 + smallthinker.share_counters(out, self.cfg) + [out["masked"]])
+            counters = jnp.stack(
+                step_counters(out["parts"], out["loads"].astype(F32), emb.shape[0] * emb.shape[1])
+                + share_counters(out["router_choices"], out["loads"], c.experts_held,
+                                 c.experts_offset, c.expert_block) + [out["masked"]])
         return out["loss"], {"counters": lax.stop_gradient(counters)}
 
     @staticmethod
     def record_counters(means) -> None:
         """A pass's mean counters into the monitor registry (literal names)."""
-        from paddlebox_tpu.utils.monitor import STAT_SET
-
         STAT_SET("model.loss_first_half", float(means[0]))
         STAT_SET("model.loss_second_half", float(means[1]))
-        STAT_SET("model.tokens_per_step", float(means[2]))
-        STAT_SET("model.held_assignments_per_step", float(means[3]))
-        STAT_SET("model.expert_load_max_over_mean", float(means[4]))
-        STAT_SET("model.unrouted_tokens_per_step", float(means[5]))
-        STAT_SET("model.block_rows_per_step", float(means[6]))
+        record_load_counters(*means[2:5])
+        record_share_counters(*means[5:7])
         STAT_SET("model.masked_positions_per_step", float(means[7]))

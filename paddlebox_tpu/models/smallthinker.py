@@ -2,13 +2,9 @@
 stream *ahead of attention*, ReLU-gated routed experts with no shared expert
 and no dense layer, a full layer without positions to three window layers
 with rope, grouped-query heads; a language model trained through the pass
-path, the third ``SequenceLossModel`` (``models/base.py``) beside
-``models/glm_moe_lite.py`` and ``models/afmoe.py``, whose pieces it shares
-(``rms_norm``, rope, ``_mm``, ``route``, ``routed_experts``, ``head_logits``
-from the first; the scores part ``_scores``, with its kernel, its blocked form
-and its trace-time counters, and the loss with its two parts and five counters
-from the second: an optimisation of one is measured on all three, and on
-``models/sdar.py``, the fourth, which takes ``share_counters`` from here).
+path (``models/base.py::SequenceLossModel``). Its scores are
+``models/attention.py``'s, its expert layer ``models/moe.py``'s, its norms,
+rope, head, loss and counters ``models/lm_layers.py``'s.
 
 The step hands it the pulled rows of the one token slot unpooled, as
 ``[B, T, hidden]`` in record order, and the record's dense slot of T token
@@ -30,19 +26,16 @@ causally, on a sliding layer only ``sliding_window`` keys back;
 ``E(m) = (m W_up * relu(m W_gate)) W_down``: the experts read the
 post-attention stream, by the choice made before it.
 
-Precision as ``glm_moe_lite``: float32 but for the bfloat16 operands of the
-matrix products. Memory: every layer recomputed in the backward from its
-input, but for the fused scores' float32 output and logsumexp, which the
-layer's checkpoint keeps by name (``ops/pallas_kernels.py::KEEP_SCORES``: 0.94
-GB over the cell's four layers of one 16k record): q, k, v are the
-recomputation's anyway, so the backward kernel is fed without the forward
-kernel's second run. The stack is one body under ``lax.scan`` whose step is
-told its kind, as ``afmoe``'s.
+Precision: float32 but for the bfloat16 operands of the matrix products.
+Memory: every layer recomputed in the backward from its input, but for the
+fused scores' float32 output and logsumexp (``models/attention.py``; 0.94 GB
+over the cell's four layers of one 16k record). The stack is one body under ``lax.scan`` whose step is
+told its kind.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, fields
+from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Tuple
 
@@ -50,17 +43,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddlebox_tpu.models import afmoe
-from paddlebox_tpu.models.afmoe import _scores
-from paddlebox_tpu.models.glm_moe_lite import F32, _mm, rms_norm, rope_tables, route, routed_experts
+from paddlebox_tpu.models.attention import window_or_full
+from paddlebox_tpu.models.lm_layers import (
+    F32, WINDOW_COUNTERS, GroupedQueryConfig, TokenModel, _mm, feed_ids, record_window_counters,
+    rms_norm, rope_tables, step_counters, window_loss)
+from paddlebox_tpu.models.moe import (
+    SHARE_COUNTERS, record_share_counters, route, routed_experts, share_counters)
 from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
 from paddlebox_tpu.utils.monitor import STAT_ADD
 
-COUNTERS = afmoe.COUNTERS + ("unrouted_tokens", "block_rows")
+COUNTERS = WINDOW_COUNTERS + SHARE_COUNTERS
 
 
 @dataclass(frozen=True)
-class SmallThinkerConfig:
+class SmallThinkerConfig(GroupedQueryConfig):
     """Sizes as in the published ``config.json`` (under the names the shared
     pieces read); ``layer_kinds`` (1 = sliding with rope, 0 = full without
     positions) and ``vocab_size`` are what this instance holds, ``num_experts``
@@ -90,24 +86,14 @@ class SmallThinkerConfig:
         object.__setattr__(self, "layer_kinds", tuple(int(k) for k in self.layer_kinds))
         if not set(self.layer_kinds) <= {0, 1}:
             raise ValueError(f"layer_kinds {self.layer_kinds}")
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError("query heads are not a multiple of the key-value heads")
-
-    @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "SmallThinkerConfig":
-        names = {f.name for f in fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
-
-    @property
-    def group(self) -> int:
-        return self.num_attention_heads // self.num_key_value_heads
+        super().__post_init__()
 
 
 def attention(p, x, w_in, c: SmallThinkerConfig, rope, sliding, scope: str = "model"):
     """x + attention(norm(x)) W_o. x [B, T, H]. ``sliding`` is the layer's
     kind: a bool, or a traced flag where a scan's step is told it. No biases,
-    no QK-norm, no gate: ``qk_norm_rope`` (``afmoe._scores``'s own name) holds
-    rope and the casts here."""
+    no QK-norm, no gate: ``qk_norm_rope`` (``attention.window_or_full``'s own
+    name) holds rope and the casts here."""
     B, T, _ = x.shape
     nh, nkv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     with jax.named_scope(f"{scope}/attn/qkv_proj"):
@@ -115,11 +101,8 @@ def attention(p, x, w_in, c: SmallThinkerConfig, rope, sliding, scope: str = "mo
         q = _mm(a, p["q"]).reshape(B, T, nh, d)
         k = _mm(a, p["k"]).reshape(B, T, nkv, d)
         v = _mm(a, p["v"]).reshape(B, T, nkv, d)
-    window, full = (partial(_scores, c=c, rope=rope, sliding=s, scope=scope) for s in (True, False))
-    if isinstance(sliding, bool):
-        o = (window if sliding else full)(q, k, v)
-    else:
-        o = lax.cond(sliding, window, full, q, k, v)
+    o = window_or_full(q, k, v, rope, sliding, sliding_window=c.sliding_window,
+                       block=c.attn_block, group=c.group, scope=scope)
     with jax.named_scope(f"{scope}/attn/out_proj"):
         return x + _mm(o.reshape(B, T, nh * d), p["o"])
 
@@ -128,39 +111,22 @@ def layer(p, x, c: SmallThinkerConfig, rope, sliding, scope: str = "model"):
     """-> (stream, chosen experts [B, T, k], held experts' loads)."""
     B, T, H = x.shape
     with jax.named_scope(f"{scope}/moe/router"):  # ahead of attention, from the input itself
-        idx, g = route(p["router"], x.reshape(B * T, H), c, "softmax_of_chosen")
+        idx, g = route(p["router"], x.reshape(B * T, H), c.num_experts_per_tok,
+                       form="softmax_of_chosen")
     h = attention(p["attn"], x, p["ln_in"], c, rope, sliding, scope)
     with jax.named_scope(f"{scope}/moe/experts"):
         flat = rms_norm(h, p["ln_post_attn"], c.rms_norm_eps).reshape(B * T, H)
-    routed, counts = routed_experts(p["experts"], flat, idx, g, c, scope, "relu")
+    routed, counts = routed_experts(p["experts"], flat, idx, g, c.experts_held, c.experts_offset,
+                                    c.expert_block, scope, "relu")
     with jax.named_scope(f"{scope}/moe/combine"):
         return h + routed.reshape(B, T, H), idx.reshape(B, T, -1), counts
 
 
-def share_counters(out, c) -> list:
-    """``unrouted_tokens`` and ``block_rows`` of one batch, from what
-    ``forward`` gave: the (token, layer) pairs none of whose chosen experts is
-    held, and the rows of the grouped product's blocks in use (padding
-    included), all layers (``sdar`` shares them)."""
-    local = out["router_choices"] - c.experts_offset
-    held = jnp.any((local >= 0) & (local < c.experts_held), axis=-1)
-    R = c.expert_block
-    return [jnp.sum(~held).astype(F32), jnp.sum(-(-out["loads"] // R) * R).astype(F32)]
-
-
-class SmallThinker:
+class SmallThinker(TokenModel):
     """``apply(params, emb [B, T, H], ids [B, T]) -> (loss, {"counters": [7]})``;
     ``forward`` gives the logit terms and expert choices behind it."""
 
-    sequence_feed = True  # the step feeds the slot's rows unpooled and takes the loss from here
     counter_names = COUNTERS
-
-    def __init__(self, cfg: SmallThinkerConfig):
-        self.cfg = cfg
-        self.num_slots = 1
-        self.seq_len = cfg.seq_len
-        self.dense_dim = cfg.seq_len  # the record's dense slot: its T token ids
-        self.feat_width = 3 + cfg.hidden_size
 
     # -- parameters
 
@@ -179,15 +145,7 @@ class SmallThinker:
         }
 
     def init(self, rng) -> Dict[str, Any]:
-        c = self.cfg
-        n = len(c.layer_kinds)
-        ks = jax.random.split(rng, n + 1)
-        return {
-            "layers": jax.tree.map(lambda *a: jnp.stack(a), *[self._layer_init(k) for k in ks[:n]]),
-            "final_norm": jnp.ones((c.hidden_size,)),
-            "head": jax.random.normal(ks[n], (c.hidden_size, c.vocab_size), F32)
-            * c.initializer_range,
-        }
+        return self._stack_init(rng, len(self.cfg.layer_kinds))
 
     # -- forward and loss
 
@@ -219,25 +177,27 @@ class SmallThinker:
         over the T - 1 positions that have a target. emb [B, T, H]: the token
         slot's pulled rows, CVM columns dropped; ids [B, T]: the record's token
         ids (whole numbers in float32 or int32), relative to the held slice."""
-        ids = afmoe.feed_ids(emb, ids, self.cfg)
+        c = self.cfg
+        ids = feed_ids(emb, ids, c.seq_len)
         x, choices, loads = self.hidden_states(params, emb)
-        return {**afmoe.window_loss(params, x, ids, self.cfg),
+        return {**window_loss(params, x, ids, c.sliding_window, c.rms_norm_eps, c.loss_block),
                 "router_choices": choices, "loads": loads}
 
     def apply(self, params, emb, ids):
         """The training loss of one batch (``forward``'s arguments) and the
         one array the step carries out beside it: ``counters``, named by
-        ``counter_names`` (``share_counters`` has the last two)."""
+        ``counter_names`` (``moe.share_counters`` has the last two)."""
+        c = self.cfg
         out = self.forward(params, emb, ids)
         with jax.named_scope("loss/head"):
-            counters = jnp.stack(afmoe.window_counters(out, emb) + share_counters(out, self.cfg))
+            counters = jnp.stack(
+                step_counters(out["parts"], out["loads"].astype(F32), emb.shape[0] * emb.shape[1])
+                + share_counters(out["router_choices"], out["loads"], c.experts_held,
+                                 c.experts_offset, c.expert_block))
         return out["loss"], {"counters": lax.stop_gradient(counters)}
 
     @staticmethod
     def record_counters(means) -> None:
         """A pass's mean counters into the monitor registry (literal names)."""
-        from paddlebox_tpu.utils.monitor import STAT_SET
-
-        afmoe.record_window_counters(means)
-        STAT_SET("model.unrouted_tokens_per_step", float(means[5]))
-        STAT_SET("model.block_rows_per_step", float(means[6]))
+        record_window_counters(means)
+        record_share_counters(*means[5:7])

@@ -2,12 +2,11 @@
 DeepSeek-AI, arXiv:2512.24880, over Hyper-Connections, arXiv:2409.19606)
 around the DeepSeek-V3 family's latent attention (query/key heads of 192, value
 heads of 128, rope under YaRN) and its sigmoid-routed experts beside a shared
-one; a language model trained through the pass path, the fifth
-``SequenceLossModel`` (``models/base.py``). Every branch is
-``models/glm_moe_lite.py``'s (``mla_branch``, ``dense_branch``, ``moe_branch``,
-with their kernel, their blocked form and their counters; ``head_logits``): an
-optimisation of one is measured on all five. What is this model's own is the
-residual path: **no layer adds its output to its input**.
+one; a language model trained through the pass path
+(``models/base.py::SequenceLossModel``). Every branch is
+``models/glm_moe_lite.py``'s (``mla_branch``, ``dense_branch``, ``moe_branch``),
+the rope tables, head and counters ``models/lm_layers.py``'s. What is this
+model's own is the residual path: **no layer adds its output to its input**.
 
 The state is ``hc_mult`` = n streams, X a tuple of n arrays [B, T, C] float32
 (one buffer a stream: no stream is ever sliced out of, or stacked into, a
@@ -48,12 +47,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddlebox_tpu.models.afmoe import feed_ids
 from paddlebox_tpu.models.glm_moe_lite import (
-    F32, GlmMoeLite, GlmMoeLiteConfig, dense_branch, head_logits, mla_branch, moe_branch, rms_norm,
-    yarn_mscale, yarn_rope_tables)
+    GlmMoeLite, GlmMoeLiteConfig, dense_branch, mla_branch, moe_branch)
+from paddlebox_tpu.models.lm_layers import (
+    F32, feed_ids, load_counters, next_token_logits, record_load_counters, yarn_mscale,
+    yarn_rope_tables)
 from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
-from paddlebox_tpu.utils.monitor import STAT_ADD
+from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
 COUNTERS = ("loss_main", "tokens", "held_assignments", "expert_load_max_over_mean",
             "hc_res_offdiag", "hc_sinkhorn_residual")
@@ -273,15 +273,11 @@ class Xing4(GlmMoeLite):
         [B, T]: the record's token ids (whole numbers in float32 or int32),
         relative to the held slice."""
         c = self.cfg
-        B, T, H = emb.shape
-        ids = feed_ids(emb, ids, c)
+        B, T, _ = emb.shape
+        ids = feed_ids(emb, ids, c.seq_len)
         x, choices, loads, readings = self.hidden_states(params, emb)
         with jax.named_scope("loss/head"):
-            targets = jnp.concatenate([ids[:, 1:], jnp.zeros((B, 1), jnp.int32)], axis=1)
-            h = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-            tl, lse = head_logits(params["head"], h.reshape(B * T, H), targets.reshape(-1),
-                                  c.loss_block)
-            tl, lse = tl.reshape(1, B, T), lse.reshape(1, B, T)
+            tl, lse = next_token_logits(params, x, ids, c.rms_norm_eps, c.loss_block)
             has = (jnp.arange(T) < T - 1).astype(F32)
             loss = jnp.sum((lse - tl) * has) / (B * (T - 1))
         return {"parts": jnp.stack([loss, jnp.asarray(float(B * T))]),
@@ -295,21 +291,14 @@ class Xing4(GlmMoeLite):
         ``counter_names``."""
         out = self.forward(params, emb, ids)
         with jax.named_scope("loss/head"):
-            loads = out["loads"].astype(F32)
             counters = jnp.concatenate([
-                out["parts"], jnp.stack([jnp.sum(loads),
-                                         jnp.max(loads) / jnp.maximum(jnp.mean(loads), 1e-9)]),
-                out["hc"]])
+                out["parts"], jnp.stack(load_counters(out["loads"].astype(F32))), out["hc"]])
         return out["loss"], {"counters": lax.stop_gradient(counters)}
 
     @staticmethod
     def record_counters(means) -> None:
         """A pass's mean counters into the monitor registry (literal names)."""
-        from paddlebox_tpu.utils.monitor import STAT_SET
-
         STAT_SET("model.loss_main", float(means[0]))
-        STAT_SET("model.tokens_per_step", float(means[1]))
-        STAT_SET("model.held_assignments_per_step", float(means[2]))
-        STAT_SET("model.expert_load_max_over_mean", float(means[3]))
+        record_load_counters(*means[1:4])
         STAT_SET("model.hc_res_offdiag", float(means[4]))
         STAT_SET("model.hc_sinkhorn_residual", float(means[5]))

@@ -6,8 +6,7 @@ query/key heads of another width than the value heads (192 and 128,
 ``models/xing4.py``, PR 42).
 
 A kernel lives here when a call site chooses it from what it can observe
-(backend and shapes: ``models/glm_moe_lite.py::fused_scores``,
-``models/afmoe.py::fused_scores``), its XLA form
+(backend and shapes: ``models/attention.py::fused``), its XLA form
 stays as every other backend's path and as its oracle
 (``tests/test_fused_attention.py``), a counter says which form was lowered,
 and a benchmark cell runs it. The table gather and scatter of

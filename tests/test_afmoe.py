@@ -24,7 +24,7 @@ from benchmark.reference import token_step  # noqa: E402
 from paddlebox_tpu import BoxWrapper  # noqa: E402
 from paddlebox_tpu.data import SlotInfo, SlotSchema  # noqa: E402
 from paddlebox_tpu.models import afmoe  # noqa: E402
-from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
+from paddlebox_tpu.models import lm_layers, moe  # noqa: E402
 from paddlebox_tpu.models import Afmoe, AfmoeConfig  # noqa: E402
 from paddlebox_tpu.table import SparseOptimizerConfig  # noqa: E402
 from paddlebox_tpu.train import CTRTrainer, TrainStepConfig  # noqa: E402
@@ -104,7 +104,7 @@ def test_a_scan_step_told_its_kind_is_the_layer_of_that_kind(seeded):
     kind given as a plain bool is the same layer."""
     params, emb, _ = seeded
     c = program_config()
-    rope = glm.rope_tables(T, c.head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(T, c.head_dim, c.rope_theta)
     p = jax.tree.map(lambda a: a[0], params["moe"])
     outs = {}
     for sliding in (True, False):
@@ -172,7 +172,7 @@ def block(seeded):
 def test_attention_block_against_a_per_head_per_position_loop(block, sliding):
     p, emb, (ln_in, ln_post) = block
     c = program_config()
-    rope = glm.rope_tables(T, c.head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(T, c.head_dim, c.rope_theta)
     got = np.asarray(afmoe.attention(p, emb, ln_in, ln_post, c, rope, sliding), np.float64)
     want = _attention_loop(p, emb, ln_in, ln_post, c, sliding)
     x = np.asarray(emb, np.float64)
@@ -190,7 +190,7 @@ def test_a_key_is_seen_at_window_minus_one_behind_and_not_at_window(block):
     """i - j = 2,047 is seen and 2,048 is not, at the toy's window of 16."""
     p, emb, (ln_in, ln_post) = block
     c = program_config()
-    rope = glm.rope_tables(T, c.head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(T, c.head_dim, c.rope_theta)
     run = lambda e, s: np.asarray(afmoe.attention(p, e, ln_in, ln_post, c, rope, s) - e)  # noqa: E731
     j = 5
     emb2 = emb.at[:, j].add(1.0)
@@ -219,9 +219,10 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(seeded
     for off in range(0, 8, 2):  # four chips of two experts each
         c = program_config(experts_offset=off)
         part = {**layer, "experts": jax.tree.map(lambda a: a[off:off + 2], layer["experts"])}
-        idx, g = glm.route(part["router"], x, c)
+        idx, g = moe.route(part["router"], x, c.num_experts_per_tok, scale=c.route_scale)
         assert np.array_equal(np.sort(idx, -1), np.sort(chosen, -1))  # every chip routes alike
-        routed, counts = glm.routed_experts(part["experts"], x, idx, g, c, "model")
+        routed, counts = moe.routed_experts(part["experts"], x, idx, g, c.experts_held,
+                                            c.experts_offset, c.expert_block, "model")
         with jax.default_matmul_precision("highest"):  # and the reference is given the same share
             ref_share = ref.experts_part(part, x, {**TINY, "experts_offset": off}, m)[0] - shared
         assert _rel(routed, ref_share) < 1e-5
@@ -237,13 +238,14 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(seeded
 def test_routing_picks_8_by_score_plus_bias_weighs_by_normalised_score_and_drops_no_token():
     c = AfmoeConfig(hidden_size=H, num_experts=128, num_experts_per_tok=8, experts_held=16,
                     experts_offset=32, moe_intermediate_size=48, expert_block=8)
-    assert c.routed_scaling_factor == 2.826  # route_scale, under the name ``route`` reads
+    assert c.route_scale == 2.826  # the published scale, the one ``moe.route`` is handed
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.normal(size=(40, H)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(H, 128)) * 0.3, jnp.float32)
     bias = np.zeros(128, np.float32)
     bias[32], bias[127] = 3.0, -3.0  # held expert 32 always chosen, 127 never
-    idx, g = glm.route({"w": w, "bias": jnp.asarray(bias)}, x, c)
+    idx, g = moe.route({"w": w, "bias": jnp.asarray(bias)}, x, c.num_experts_per_tok,
+                       scale=c.route_scale)
     s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64) @ np.asarray(w, np.float64))))
     assert idx.shape == (40, 8)
     assert np.array_equal(np.sort(idx, -1), np.sort(np.argsort(-(s + bias), axis=1)[:, :8], -1))
@@ -253,10 +255,11 @@ def test_routing_picks_8_by_score_plus_bias_weighs_by_normalised_score_and_drops
     assert np.asarray(g).sum(1) == pytest.approx(2.826, rel=1e-5)
     # every token on held expert 32 (local 0): 40 rows in blocks of 8, no capacity, none dropped
     experts = Afmoe(c)._mlp_init(jax.random.PRNGKey(0), 48, lead=(16,))
-    y, counts = glm.routed_experts(experts, x, idx, g, c, "model")
+    y, counts = moe.routed_experts(experts, x, idx, g, c.experts_held, c.experts_offset,
+                                   c.expert_block, "model")
     held = (np.asarray(idx) >= 32) & (np.asarray(idx) < 48)
     assert counts[0] == 40 and counts.sum() == held.sum()
-    want = sum(glm.swiglu(jax.tree.map(lambda a, e=e: a[e], experts), x)
+    want = sum(lm_layers.swiglu(jax.tree.map(lambda a, e=e: a[e], experts), x)
                * jnp.sum(jnp.where(idx == 32 + e, g, 0.0), axis=1, keepdims=True) for e in range(16))
     assert _rel(y, want) < 1e-5
 

@@ -1,16 +1,14 @@
 """The fused causal attention kernel (``ops/pallas_kernels.py::causal_attention``)
-against the blocked form it replaces on a TPU (``models/glm_moe_lite.py::
+against the blocked form it replaces on a TPU (``models/attention.py::
 _attend_block``, which stays as every other backend's path and is the oracle
-here): interpret mode on the CPU at a small tiled shape, the rule that chooses
-between the two, its counters, and the kernels compiled at the token cell's
-shapes for a described v5e. Since PR 33 also with grouped-query heads and a
-window (``models/afmoe.py``), against that model's blocked form, and GLM's call
-held to the kernel it had before; since PR 35 at a group that is no power of
-two (7, ``models/smallthinker.py``) and at that model's 16k shapes; since PR 39
-under the block-diffusion mask (``diffusion_block``, ``models/sdar.py``), with
-all three older calls held to the kernels they had; since PR 42 at query/key
-heads of another width than the value heads (192 and 128, ``models/xing4.py``),
-every call of one width still held to the kernel it had."""
+here): interpret mode on the CPU at a small tiled shape, the one rule that
+chooses between the two for every model (``attention.fused``), its counters,
+and the kernels compiled at the token cells' shapes for a described v5e: with
+grouped-query heads and a window (``models/afmoe.py``), at a group that is no
+power of two (7, ``models/smallthinker.py``) and that model's 16k shapes, under
+the block-diffusion mask (``diffusion_block``, ``models/sdar.py``), and at
+query/key heads of another width than the value heads (192 and 128,
+``models/xing4.py``); every older call held to the kernel it had."""
 
 from __future__ import annotations
 
@@ -28,7 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 
-from paddlebox_tpu.models import afmoe, sdar  # noqa: E402
+from paddlebox_tpu.models import afmoe, attention, lm_layers, moe, sdar  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
 from paddlebox_tpu.models import (  # noqa: E402
     Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, SmallThinker, SmallThinkerConfig, Xing4,
@@ -51,7 +49,13 @@ def qkvg():
 
 def blocked(q, k, v):
     return jnp.concatenate(
-        [glm._attend_block(q, k, v, i, 128, SCALE) for i in range(0, T, 128)], axis=1)
+        [attention._attend_block(q, k, v, i, 128, SCALE, 1, None, None) for i in range(0, T, 128)],
+        axis=1)
+
+
+def _interpreted(q, k, v, scale, block, interpret, *rest):
+    """``causal_attention`` as ``attention.scores`` calls it, in interpret mode."""
+    return causal_attention(q, k, v, scale, block, True, *rest)
 
 
 def _rel(a, b) -> float:
@@ -90,26 +94,43 @@ def test_a_later_key_leaves_an_earlier_querys_output_bit_equal(qkvg, block):
     assert not np.array_equal(o[:, at], o2[:, at])
 
 
-@pytest.mark.parametrize("backend,t,qk,vd,block,fused", [
-    ("tpu", 4096, 256, 256, 512, True),    # the token cell
-    ("tpu", 256, 128, 128, 128, True),
-    ("cpu", 4096, 256, 256, 512, False),   # tier-1, whatever the shape
-    ("gpu", 4096, 256, 256, 512, False),
-    ("tpu", 64, 16, 16, 8, False),         # the toy token cell's widths
-    ("tpu", 4096, 192, 128, 512, True),    # Xing4's cell: q/k of 192 behind zero columns, v of 128
-    ("tpu", 4096, 192, 64, 512, False),    # value heads that are no whole lane rows
-    ("tpu", 4096, 96, 128, 512, False),    # query/key heads that are no half lane rows
-    ("tpu", 4096, 256, 256, 64, False),    # a query block the kernel does not tile
-    ("tpu", 4096, 320, 320, 512, False),   # a head that is no multiple of a lane row
+@pytest.mark.parametrize("backend,t,qk,vd,block,window,dblock,fused", [
+    # GLM's and Xing4's latent attention
+    ("tpu", 4096, 256, 256, 512, None, None, True),    # the token cell
+    ("tpu", 256, 128, 128, 128, None, None, True),
+    ("cpu", 4096, 256, 256, 512, None, None, False),   # tier-1, whatever the shape
+    ("gpu", 4096, 256, 256, 512, None, None, False),
+    ("tpu", 64, 16, 16, 8, None, None, False),         # the toy token cell's widths
+    ("tpu", 4096, 192, 128, 512, None, None, True),    # Xing4's cell: q/k of 192 behind zero columns
+    ("tpu", 4096, 192, 64, 512, None, None, False),    # value heads that are no whole lane rows
+    ("tpu", 4096, 96, 128, 512, None, None, False),    # query/key heads that are no half lane rows
+    ("tpu", 4096, 256, 256, 64, None, None, False),    # a query block the kernel does not tile
+    ("tpu", 4096, 320, 320, 512, None, None, False),   # a head that is no multiple of a lane row
+    # Trinity's and SmallThinker's window and full layers
+    ("tpu", 8192, 128, 128, 512, 2048, None, True),    # the Trinity cell's window layers
+    ("tpu", 8192, 128, 128, 512, None, None, True),    # and its full layer
+    ("cpu", 8192, 128, 128, 512, 2048, None, False),   # tier-1, whatever the shape
+    ("tpu", 8192, 128, 128, 512, 2000, None, False),   # a window the tiles do not divide
+    ("tpu", 1024, 128, 128, 512, 2048, None, True),    # a window longer than the record: full causal
+    ("tpu", 32, 16, 16, 8, 16, None, False),           # the toy cell's widths
+    ("tpu", 8192, 128, 128, 64, 2048, None, False),    # a query block the kernel does not tile
+    # SDAR's block-diffusion mask
+    ("tpu", 16384, 128, 128, 512, None, 4, True),      # the SDAR cell: 8,192 tokens twice
+    ("cpu", 16384, 128, 128, 512, None, 4, False),     # tier-1, whatever the shape
+    ("tpu", 12288, 128, 128, 512, None, 4, True),      # 6,144 tokens twice
+    ("tpu", 8192 + 512, 128, 128, 512, None, 4, False),  # a half that is no whole number of tiles
+    ("tpu", 16384, 128, 128, 512, None, 96, False),    # blocks the tile does not hold whole
+    ("tpu", 64, 16, 16, 8, None, 4, False),            # the toy cell's widths
 ])
-def test_the_path_is_chosen_from_backend_and_shapes(backend, t, qk, vd, block, fused):
-    assert glm.fused_scores(backend, t, qk, vd, block) is fused
+def test_the_path_is_chosen_from_backend_and_shapes(backend, t, qk, vd, block, window, dblock, fused):
+    """One rule for every call site of the kernel, whatever the model."""
+    assert attention.fused(backend, t, qk, vd, block, window, dblock) is fused
 
 
 def _mla_call(cfg: GlmMoeLiteConfig):
     p = GlmMoeLite(cfg)._attn_init(jax.random.PRNGKey(0))
     x = jnp.zeros((1, cfg.seq_len, cfg.hidden_size))
-    rope = glm.rope_tables(cfg.seq_len, cfg.qk_rope_head_dim, cfg.rope_theta)
+    rope = lm_layers.rope_tables(cfg.seq_len, cfg.qk_rope_head_dim, cfg.rope_theta)
     return lambda: jax.make_jaxpr(
         lambda p, x: glm.mla(p, x, jnp.ones((cfg.hidden_size,)), cfg, rope, "model"))(p, x)
 
@@ -143,13 +164,12 @@ def test_mla_through_the_kernel_agrees_with_mla_through_the_blocks(monkeypatch):
     p = GlmMoeLite(cfg)._attn_init(jax.random.PRNGKey(1))
     p = jax.tree.map(lambda a: a * 20 if a.ndim == 2 else a, p)  # scores of order 1, not 1e-3
     x = jax.random.normal(jax.random.PRNGKey(2), (2, cfg.seq_len, cfg.hidden_size))
-    rope = glm.rope_tables(cfg.seq_len, cfg.qk_rope_head_dim, cfg.rope_theta)
+    rope = lm_layers.rope_tables(cfg.seq_len, cfg.qk_rope_head_dim, cfg.rope_theta)
     run = lambda: jax.value_and_grad(lambda x: jnp.sum(  # noqa: E731
         glm.mla(p, x, jnp.ones((cfg.hidden_size,)), cfg, rope, "model") ** 2))(x)
     want, dwant = run()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(glm, "causal_attention",
-                        lambda q, k, v, s, block: causal_attention(q, k, v, s, block, True))
+    monkeypatch.setattr(attention, "causal_attention", _interpreted)
     got, dgot = run()
     assert float(got) == pytest.approx(float(want), rel=1e-3)
     assert _rel(dgot, dwant) < 1e-2
@@ -172,7 +192,8 @@ def _two_widths(dqk: int, dv: int, group: int):
 
 def _blocked_two_widths(q, k, v, group: int):
     k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
-    return jnp.concatenate([glm._attend_block(q, k, v, i, 128, q.shape[-1] ** -0.5)
+    scale = q.shape[-1] ** -0.5
+    return jnp.concatenate([attention._attend_block(q, k, v, i, 128, scale, 1, None, None)
                             for i in range(0, T, 128)], axis=1)
 
 
@@ -220,7 +241,7 @@ def test_xing4s_attention_through_the_kernel_agrees_with_the_blocks(monkeypatch)
     p = GlmMoeLite(cfg)._attn_init(jax.random.PRNGKey(1))
     p = jax.tree.map(lambda a: a * 20 if a.ndim == 2 else a, p)  # scores of order 1, not 1e-3
     x = jax.random.normal(jax.random.PRNGKey(2), (2, cfg.seq_len, cfg.hidden_size))
-    rope = glm.yarn_rope_tables(cfg.seq_len, cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+    rope = lm_layers.yarn_rope_tables(cfg.seq_len, cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
                                 64, cfg.beta_fast, cfg.beta_slow)
     stats = lambda: (STAT_GET("model.mla.fused_scores"), STAT_GET("model.mla.blocked_scores"))  # noqa: E731
     run = lambda: jax.value_and_grad(lambda x: jnp.sum(glm.mla_branch(  # noqa: E731
@@ -229,8 +250,7 @@ def test_xing4s_attention_through_the_kernel_agrees_with_the_blocks(monkeypatch)
     want, dwant = run()
     assert stats() == (f0, b0 + 1)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(glm, "causal_attention",
-                        lambda q, k, v, s, block: causal_attention(q, k, v, s, block, True))
+    monkeypatch.setattr(attention, "causal_attention", _interpreted)
     got, dgot = run()
     assert stats() == (f0 + 1, b0 + 1)
     assert float(got) == pytest.approx(float(want), rel=1e-3)
@@ -250,7 +270,7 @@ def _grouped(T: int, group: int = GROUP, kv_heads: int = 1):
 
 
 def _blocked_grouped(q, k, v, window, group: int = GROUP):
-    return jnp.concatenate([afmoe._attend_block(q, k, v, i, 128, SCALE, group, window)
+    return jnp.concatenate([attention._attend_block(q, k, v, i, 128, SCALE, group, window, None)
                             for i in range(0, q.shape[1], 128)], axis=1)
 
 
@@ -356,44 +376,41 @@ def test_no_group_and_no_window_are_the_call_that_names_neither(qkvg):
         assert all(np.array_equal(a, b) for a, b in zip(jax.tree.leaves(run(*args)), want))
 
 
-@pytest.mark.parametrize("backend,t,d,block,window,fused", [
-    ("tpu", 8192, 128, 512, 2048, True),    # the Trinity cell's window layers
-    ("tpu", 8192, 128, 512, None, True),    # and its full layer
-    ("cpu", 8192, 128, 512, 2048, False),   # tier-1, whatever the shape
-    ("tpu", 8192, 128, 512, 2000, False),   # a window the tiles do not divide
-    ("tpu", 1024, 128, 512, 2048, True),    # a window longer than the record: full causal
-    ("tpu", 32, 16, 8, 16, False),          # the toy cell's widths
-    ("tpu", 8192, 128, 64, 2048, False),    # a query block the kernel does not tile
-])
-def test_afmoes_path_is_chosen_from_backend_and_shapes(backend, t, d, block, window, fused):
-    assert afmoe.fused_scores(backend, t, d, block, window) is fused
-
-
 @pytest.mark.parametrize("backend,window,fused", [
     ("tpu", 4096, True),    # the SmallThinker cell's window layers: 32 tiles a side, a band of 8 + 1
     ("tpu", None, True),    # and its full layer
     ("cpu", 4096, False),   # tier-1, whatever the shape
 ])
 def test_smallthinkers_path_is_chosen_from_backend_and_shapes(backend, window, fused, monkeypatch):
-    """The model's scores part is ``afmoe._scores`` itself, with its rule and
-    its counters: at the cell's shapes a TPU takes one kernel a kind."""
+    """The model's scores and Trinity's reach ``attention.scores`` alike, with
+    its rule and its counters: at the cell's shapes a TPU takes one kernel a kind."""
     from paddlebox_tpu.models import SmallThinkerConfig, smallthinker
 
     c = SmallThinkerConfig()  # the published widths, the cell's record and blocks
     assert (c.group, c.seq_len, c.head_dim, c.attn_block, c.sliding_window) == (7, 16384, 128, 512, 4096)
-    assert afmoe.fused_scores(backend, c.seq_len, c.head_dim, c.attn_block, window) is fused
-    assert not afmoe.fused_scores("tpu", 32, 16, 8, 16)  # the toy cell's widths stay blocked
-    assert smallthinker._scores is afmoe._scores
+    assert attention.fused(backend, c.seq_len, c.head_dim, c.head_dim, c.attn_block, window) is fused
+    assert not attention.fused("tpu", 32, 16, 16, 8, 16)  # the toy cell's widths stay blocked
+    reached, scores = [], attention.scores
+    monkeypatch.setattr(attention, "scores",
+                        lambda *a, **kw: reached.append(kw["kind"]) or scores(*a, **kw))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     small = SmallThinkerConfig(hidden_size=64, num_attention_heads=7, num_key_value_heads=1,
                                seq_len=1024, sliding_window=512, attn_block=128)
     x = jax.ShapeDtypeStruct((1, small.seq_len, small.hidden_size), jnp.float32)
     p = {"q": jnp.zeros((64, 7 * 128)), "k": jnp.zeros((64, 128)), "v": jnp.zeros((64, 128)),
          "o": jnp.zeros((7 * 128, 64))}
-    rope = glm.rope_tables(small.seq_len, small.head_dim, small.rope_theta)
+    rope = lm_layers.rope_tables(small.seq_len, small.head_dim, small.rope_theta)
     text = str(jax.make_jaxpr(lambda x: smallthinker.attention(
         p, x, jnp.ones((64,)), small, rope, window is not None))(x))
     assert text.count("pallas_call") == (1 if fused else 0)
+    trinity = AfmoeConfig(hidden_size=64, num_attention_heads=7, num_key_value_heads=1,
+                          seq_len=1024, sliding_window=512, attn_block=128)
+    ln = jnp.ones((64,))
+    p = Afmoe(trinity)._attn_init(jax.random.PRNGKey(0))
+    text = str(jax.make_jaxpr(lambda x: afmoe.attention(
+        p, x, ln, ln, trinity, rope, window is not None))(x))
+    assert text.count("pallas_call") == (1 if fused else 0)
+    assert reached == ["window" if window else "full"] * 2
 
 
 AF_TILED = AfmoeConfig(hidden_size=64, num_attention_heads=2, num_key_value_heads=1, head_dim=128,
@@ -407,7 +424,7 @@ def test_afmoes_call_sites_are_counted_by_kind_under_the_path_they_were_lowered_
     c = AF_TILED
     p = Afmoe(c)._attn_init(jax.random.PRNGKey(0))
     x, ln = jnp.zeros((1, c.seq_len, c.hidden_size)), jnp.ones((c.hidden_size,))
-    rope = glm.rope_tables(c.seq_len, c.head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(c.seq_len, c.head_dim, c.rope_theta)
     trace = lambda s: str(jax.make_jaxpr(  # noqa: E731
         lambda p, x, s=s: afmoe.attention(p, x, ln, ln, c, rope, s))(p, x))
     w0, f0, b0 = stats()
@@ -432,14 +449,12 @@ def test_afmoe_attention_through_the_kernel_agrees_with_it_through_the_blocks(mo
     p = jax.tree.map(lambda a: a * 20 if a.ndim == 2 else a, p)  # scores of order 1, not 1e-3
     x = jax.random.normal(jax.random.PRNGKey(2), (1, c.seq_len, c.hidden_size))
     ln = jnp.ones((c.hidden_size,))
-    rope = glm.rope_tables(c.seq_len, c.head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(c.seq_len, c.head_dim, c.rope_theta)
     run = lambda: jax.value_and_grad(lambda x: jnp.sum(  # noqa: E731
         afmoe.attention(p, x, ln, ln, c, rope, sliding) ** 2))(x)
     want, dwant = run()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(afmoe, "causal_attention",
-                        lambda q, k, v, s, block, interpret, group, window: causal_attention(
-                            q, k, v, s, block, True, group, window))
+    monkeypatch.setattr(attention, "causal_attention", _interpreted)
     got, dgot = run()
     assert float(got) == pytest.approx(float(want), rel=1e-3)
     assert _rel(dgot, dwant) < 1e-2
@@ -448,7 +463,7 @@ def test_afmoe_attention_through_the_kernel_agrees_with_it_through_the_blocks(mo
 # ---- the block-diffusion mask (PR 39) ------------------------------------------------
 
 def _blocked_diffusion(q, k, v, block: int, group: int = GROUP):
-    return jnp.concatenate([sdar._attend_block(q, k, v, i, 128, SCALE, group, block)
+    return jnp.concatenate([attention._attend_block(q, k, v, i, 128, SCALE, group, None, block)
                             for i in range(0, q.shape[1], 128)], axis=1)
 
 
@@ -460,7 +475,7 @@ def _blocked_diffusion(q, k, v, block: int, group: int = GROUP):
 def test_the_diffusion_mask_matches_the_blocked_form_output_and_gradients(T, block, dblock, group, kv_heads):
     """The record twice, clean then noised, in blocks of ``dblock``: a clean
     query sees its own and the earlier clean blocks, a noisy one the earlier
-    clean blocks and its own noisy block. Against ``sdar._attend_block`` (held
+    clean blocks and its own noisy block. Against ``attention._attend_block`` (held
     to a brute-force table in ``tests/test_sdar.py``)."""
     q, k, v, g = _grouped(T, group, kv_heads)
     fused = lambda q, k, v: causal_attention(q, k, v, SCALE, block, True, group, None, dblock)  # noqa: E731
@@ -493,18 +508,11 @@ def test_under_the_diffusion_mask_a_change_moves_the_rows_that_see_it_and_no_oth
         causal_attention(q, k, v, SCALE, 256, True, GROUP, None, n)  # 768 is not two halves of tiles of 256
 
 
-@pytest.mark.parametrize("backend,t,d,block,dblock,fused", [
-    ("tpu", 16384, 128, 512, 4, True),    # the SDAR cell: 8,192 tokens twice, 16 tiles a half
-    ("cpu", 16384, 128, 512, 4, False),   # tier-1, whatever the shape
-    ("tpu", 12288, 128, 512, 4, True),    # the record the issue falls back to
-    ("tpu", 8192 + 512, 128, 512, 4, False),  # a half that is no whole number of tiles
-    ("tpu", 16384, 128, 512, 96, False),  # blocks the tile does not hold whole
-    ("tpu", 64, 16, 8, 4, False),         # the toy cell's widths
-])
-def test_sdars_path_is_chosen_from_backend_and_shapes(backend, t, d, block, dblock, fused, monkeypatch):
-    assert sdar.fused_scores(backend, t, d, block, dblock) is fused
-    if t != 16384 or dblock != 4:
-        return
+@pytest.mark.parametrize("backend,fused", [("tpu", True), ("cpu", False)])
+def test_sdars_call_site_is_counted_under_the_path_it_was_lowered_to(backend, fused, monkeypatch):
+    """At the SDAR cell's record (8,192 tokens twice, tiles of 512, blocks of 4) a
+    TPU takes the kernel (``test_the_path_is_chosen_from_backend_and_shapes``)."""
+    assert attention.fused(backend, 16384, 128, 128, 512, None, 4) is fused
     names = ("model.attn.fused_diffusion_scores", "model.attn.blocked_scores")
     before = [STAT_GET(n) for n in names]
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -512,7 +520,8 @@ def test_sdars_path_is_chosen_from_backend_and_shapes(backend, t, d, block, dblo
                         seq_len=512, attn_block=128)
     p = {"q": jnp.zeros((64, 8 * 128)), "k": jnp.zeros((64, 128)), "v": jnp.zeros((64, 128)),
          "o": jnp.zeros((8 * 128, 64)), "q_norm": jnp.ones((128,)), "k_norm": jnp.ones((128,))}
-    rope = tuple(jnp.tile(a, (2, 1)) for a in glm.rope_tables(c.data_len, c.head_dim, c.rope_theta))
+    rope = tuple(jnp.tile(a, (2, 1))
+                 for a in lm_layers.rope_tables(c.data_len, c.head_dim, c.rope_theta))
     text = str(jax.make_jaxpr(lambda x: sdar.attention(p, x, jnp.ones((64,)), c, rope))(
         jax.ShapeDtypeStruct((1, c.seq_len, 64), jnp.float32)))
     assert text.count("pallas_call") == (1 if fused else 0)
@@ -753,7 +762,7 @@ def test_smallthinkers_loss_and_gradient_compile_for_a_v5e_with_each_forward_ker
     compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
         params, emb, ids).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == (4 if kept else 6)
-    # since PR 44 the token sum is cut in two parts of 1,280 columns (``glm.combine_parts``): the
+    # the token sum is cut in two parts of 1,280 columns (``moe.combine_parts``): the
     # backward keeps dhg and dhu, bfloat16 [M, 768] each with M = 32 blocks of 4,096 rows (0.40 GB),
     # where the checkpoint recomputes the forward it also keeps h (0.20 GB), and a part's 84 MB
     # stands beside the joined sum once (6.19 / 4.52 GB; 5.99 / 3.84 before)
@@ -889,8 +898,8 @@ def test_xing4s_piece_joins_the_token_sum_as_written_and_its_whole_block_sorted(
         upd = jax.ShapeDtypeStruct((4 * R, C), jnp.float32, sharding=one_chip)
         return jax.jit(f).lower(tok, upd).compile().as_text()
 
-    assert glm.combine_piece_rows(N) == 512
-    cut = joined(glm._add_rows)
+    assert moe.combine_piece_rows(N) == 512
+    cut = joined(moe._add_rows)
     assert cut.count(" scatter(") == 2 and " sort(" not in cut and "indices_are_sorted=true" not in cut
     whole = joined(lambda acc, tb, rows: acc.at[tb].add(rows, mode="drop"))
     assert whole.count(" scatter(") == 1 and " sort(" in whole and " gather(" in whole
@@ -919,9 +928,10 @@ def test_the_combines_token_sum_stays_in_fast_memory_cut_where_it_is_over_96_mib
     p = {"gate": on(G, H, I), "up": on(G, H, I), "down": on(G, I, H)}
 
     def loss(p, x, g, idx, dy):
-        return jnp.sum(glm.routed_experts(p, x, idx, g, c, "model")[0] * dy)
+        y = moe.routed_experts(p, x, idx, g, c.experts_held, c.experts_offset, c.expert_block, "model")[0]
+        return jnp.sum(y * dy)
 
-    assert glm.combine_parts(N, H) == parts
+    assert moe.combine_parts(N, H) == parts
     text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
         p, on(N, H), on(N, k), on(N, k, dt=jnp.int32), on(N, H)).compile().as_text()
     assert STAT_GET("model.moe.combine_parts") == len(parts)

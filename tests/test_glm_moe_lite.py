@@ -22,6 +22,7 @@ from benchmark.reference import token_step  # noqa: E402
 from paddlebox_tpu import BoxWrapper  # noqa: E402
 from paddlebox_tpu.data import SlotInfo, SlotSchema  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
+from paddlebox_tpu.models import lm_layers, moe  # noqa: E402
 from paddlebox_tpu.models import GlmMoeLite, GlmMoeLiteConfig  # noqa: E402
 from paddlebox_tpu.table import SparseOptimizerConfig  # noqa: E402
 from paddlebox_tpu.train import CTRTrainer, TrainStepConfig  # noqa: E402
@@ -99,7 +100,7 @@ def test_latent_attention_against_a_per_head_per_position_loop(seeded):
     p = jax.tree.map(lambda a: np.asarray(a, np.float64), params["dense"][0]["attn"])
     ln = np.asarray(params["dense"][0]["ln1"], np.float64)
     x = np.asarray(emb, np.float64)
-    rope = glm.rope_tables(T, c.qk_rope_head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(T, c.qk_rope_head_dim, c.rope_theta)
     got = np.asarray(glm.mla(params["dense"][0]["attn"], emb, params["dense"][0]["ln1"], c, rope,
                              "model"), np.float64) - x
 
@@ -152,9 +153,10 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(seeded
     for off in range(0, 8, 2):  # four chips of two experts each
         c = program_config(experts_offset=off)
         part = {**layer, "experts": jax.tree.map(lambda a: a[off:off + 2], layer["experts"])}
-        idx, g = glm.route(part["router"], x, c)
+        idx, g = moe.route(part["router"], x, c.num_experts_per_tok, scale=c.routed_scaling_factor)
         assert np.array_equal(np.sort(idx, -1), np.sort(chosen, -1))  # every chip routes alike
-        routed, counts = glm.routed_experts(part["experts"], x, idx, g, c, "model")
+        routed, counts = moe.routed_experts(part["experts"], x, idx, g, c.experts_held,
+                                            c.experts_offset, c.expert_block, "model")
         with jax.default_matmul_precision("highest"):  # and the reference is given the same share
             share_cfg = {**TINY, "experts_offset": off}
             ref_share = ref.experts_part(part, x, share_cfg, m)[0] - shared
@@ -174,7 +176,7 @@ def test_routing_picks_by_score_plus_bias_weighs_by_score_and_drops_no_token():
     x = jnp.asarray(rng.normal(size=(40, H)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(H, 8)) * 0.3, jnp.float32)
     bias = jnp.asarray([3.0, 0, 0, 0, 0, 0, 0, -3.0])  # expert 0 always chosen, 7 never
-    idx, g = glm.route({"w": w, "bias": bias}, x, c)
+    idx, g = moe.route({"w": w, "bias": bias}, x, c.num_experts_per_tok, scale=c.routed_scaling_factor)
     s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64) @ np.asarray(w, np.float64))))
     want = np.argsort(-(s + np.asarray(bias)), axis=1)[:, :2]
     assert np.array_equal(np.sort(idx, -1), np.sort(want, -1))
@@ -184,15 +186,17 @@ def test_routing_picks_by_score_plus_bias_weighs_by_score_and_drops_no_token():
     assert np.asarray(g).sum(1) == pytest.approx(1.8, rel=1e-5)
     # a skewed router: every token on held expert 0, 40 rows in blocks of 8, none dropped
     experts = GlmMoeLite(c)._mlp_init(jax.random.PRNGKey(0), 48, lead=(2,))
-    y, counts = glm.routed_experts(experts, x, idx, g, c, "model")
+    y, counts = moe.routed_experts(experts, x, idx, g, c.experts_held, c.experts_offset,
+                                   c.expert_block, "model")
     assert counts[0] == 40 and counts.sum() == 40 + int(np.sum(np.asarray(idx) == 1))
     one = jax.tree.map(lambda a: a[0], experts)
     g0 = jnp.sum(jnp.where(idx == 0, g, 0.0), axis=1, keepdims=True)
     g1 = jnp.sum(jnp.where(idx == 1, g, 0.0), axis=1, keepdims=True)
-    want_y = glm.swiglu(one, x) * g0 + glm.swiglu(jax.tree.map(lambda a: a[1], experts), x) * g1
+    two = jax.tree.map(lambda a: a[1], experts)
+    want_y = lm_layers.swiglu(one, x) * g0 + lm_layers.swiglu(two, x) * g1
     assert _rel(y, want_y) < 1e-5
     # the layout: every block one expert's, rows by the blocks in use
-    src, blk, n_blocks, cnt = glm.group_layout(jnp.asarray([1, 2, 0, 0, 2, 1, 0, 2, 2]), 2, 4)
+    src, blk, n_blocks, cnt = moe.group_layout(jnp.asarray([1, 2, 0, 0, 2, 1, 0, 2, 2]), 2, 4)
     assert cnt.tolist() == [3, 2] and int(n_blocks) == 2 and blk[:2].tolist() == [0, 1]
     assert src[:8].tolist() == [2, 3, 6, 9, 0, 5, 9, 9] and np.all(np.asarray(src[8:]) == 9)
 

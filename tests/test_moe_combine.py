@@ -1,5 +1,5 @@
-"""How a block's rows join the token sum (``models/glm_moe_lite.py``:
-``group_layout``, ``grouped_experts``, ``_add_rows``), both token models'
+"""How a block's rows join the token sum (``models/moe.py``:
+``group_layout``, ``grouped_experts``, ``_add_rows``), every token model's
 expert layer.
 
 (a) ``routed_experts`` against a dense per-token sum, value and gradients,
@@ -20,18 +20,23 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
+from paddlebox_tpu.models import lm_layers, moe  # noqa: E402
 from paddlebox_tpu.models import GlmMoeLiteConfig  # noqa: E402
 from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
 
 H, I = 16, 8
-P = glm.COMBINE_ROWS
+P = moe.COMBINE_ROWS
 
 
 def _config(R: int, E: int, G: int, k: int, offset: int) -> GlmMoeLiteConfig:
     return GlmMoeLiteConfig(hidden_size=H, moe_intermediate_size=I, n_routed_experts=E,
                             num_experts_per_tok=k, experts_held=G, experts_offset=offset,
                             expert_block=R)
+
+
+def _routed(p, x, idx, g, c):
+    """``moe.routed_experts`` as a layer calls it, its share read off the config."""
+    return moe.routed_experts(p, x, idx, g, c.experts_held, c.experts_offset, c.expert_block, "model")
 
 
 def _inputs(seed: int, N: int, E: int, G: int, k: int, never=None):
@@ -53,7 +58,7 @@ def _dense(p, x, idx, g, c):
     y = jnp.zeros_like(x)
     for e in range(c.experts_held):
         weight = jnp.sum(jnp.where(idx == e + c.experts_offset, g, 0.0), axis=1, keepdims=True)
-        y = y + glm.swiglu(jax.tree.map(lambda a: a[e], p), x) * weight  # noqa: B023
+        y = y + lm_layers.swiglu(jax.tree.map(lambda a: a[e], p), x) * weight  # noqa: B023
     return y
 
 
@@ -88,9 +93,9 @@ def test_routed_experts_give_the_dense_sum_and_its_gradients(case):
         return lambda p, x, g: jnp.sum(fn(p, x, g) * dy)
 
     def routed(p, x, g):
-        return glm.routed_experts(p, x, idx, g, c, "model")[0]
+        return _routed(p, x, idx, g, c)[0]
 
-    y, counts = jax.jit(lambda p, x, g: glm.routed_experts(p, x, idx, g, c, "model"))(p, x, g)
+    y, counts = jax.jit(lambda p, x, g: _routed(p, x, idx, g, c))(p, x, g)
     held = np.bincount(np.asarray(idx).ravel() - offset + E, minlength=2 * E)[E:E + G]
     assert np.array_equal(np.asarray(counts), held)
     want = _dense(p, x, idx, g, c)
@@ -107,7 +112,7 @@ def test_routed_experts_give_the_dense_sum_and_its_gradients(case):
     if case == "three_pieces_the_last_all_padding":
         assert P < held.max() <= 2 * P  # real rows in two thirds of a block, none in the last
     if case == "xing4s_block_into_4096_tokens":
-        assert glm.combine_piece_rows(N) == 512 < R < held.min()  # cut blocks, full and part full
+        assert moe.combine_piece_rows(N) == 512 < R < held.min()  # cut blocks, full and part full
     assert _rel(y, want) < 1e-5
     for name, a, b in [("x", got_grads[1], want_grads[1]), ("g", got_grads[2], want_grads[2])] + [
             (n, got_grads[0][n], want_grads[0][n]) for n in ("gate", "up", "down")]:
@@ -125,16 +130,16 @@ def _whole_block(acc, tb, rows):
 def test_y_and_dx_are_bit_for_bit_what_one_scatter_add_a_block_gave(R, monkeypatch):
     """896: Xing4's block into 4,096 tokens, pieces of 512 + 384 under ``COMBINE_ROWS``."""
     N, E, G, k = {8: (40, 6, 3, 2), 3 * P: (3 * P, 2, 2, 1), 896: (4096, 2, 2, 1)}[R]
-    assert glm.combine_piece_rows(N) < R or R == 8
+    assert moe.combine_piece_rows(N) < R or R == 8
     c = _config(R, E, G, k, 0)
     p, x, idx, g, dy = _inputs(R, N, E, G, k)
 
     def y_and_dx():
-        y, vjp = jax.vjp(lambda x: glm.routed_experts(p, x, idx, g, c, "model")[0], x)
+        y, vjp = jax.vjp(lambda x: _routed(p, x, idx, g, c)[0], x)
         return np.asarray(y), np.asarray(vjp(dy)[0])
 
     got = y_and_dx()
-    monkeypatch.setattr(glm, "_add_rows", _whole_block)
+    monkeypatch.setattr(moe, "_add_rows", _whole_block)
     want = y_and_dx()
     assert np.any(want[0]) and np.any(want[1])
     assert np.array_equal(got[0], want[0]) and np.array_equal(got[1], want[1])
@@ -153,7 +158,7 @@ def test_a_blocks_real_rows_come_first_their_tokens_ascending_and_distinct(seed)
     local = idx.reshape(-1) - offset
     expert_of = np.where((local >= 0) & (local < G), local, G)
     A = N * k
-    src, blk, n_blocks, counts = (np.asarray(a) for a in glm.group_layout(jnp.asarray(expert_of), G, R))
+    src, blk, n_blocks, counts = (np.asarray(a) for a in moe.group_layout(jnp.asarray(expert_of), G, R))
     n_blocks = int(n_blocks)
     assert src.shape[0] == (-(-A // R) + G) * R  # rows for the worst case
     assert np.array_equal(counts, np.bincount(expert_of, minlength=G + 1)[:G])
@@ -185,12 +190,12 @@ def test_the_counters_say_how_many_pieces_a_call_sites_block_took(R, N, pieces, 
     piece = lambda: [STAT_GET("model.moe.combine_piece_rows"),  # noqa: E731
                      STAT_GET("model.moe.combine_piece_bytes")]
     before = stats()
-    text = str(jax.make_jaxpr(lambda x: glm.routed_experts(p, x, idx, g, c, "model")[0])(x))
+    text = str(jax.make_jaxpr(lambda x: _routed(p, x, idx, g, c)[0])(x))
     assert (stats() - before).tolist() == [1, pieces]  # the forward's y
     assert text.count("scatter-add") == pieces
     assert piece() == [piece_rows, piece_rows * H * 4]  # float32 rows of H columns
     before = stats()
-    jax.make_jaxpr(jax.grad(lambda x: jnp.sum(glm.routed_experts(p, x, idx, g, c, "model")[0] * dy)))(x)
+    jax.make_jaxpr(jax.grad(lambda x: jnp.sum(_routed(p, x, idx, g, c)[0] * dy)))(x)
     assert (stats() - before).tolist() == [2, 2 * pieces]  # the forward's y and the backward's dx
     assert piece() == [piece_rows, piece_rows * H * 4]
 
@@ -215,7 +220,7 @@ def test_a_cells_block_is_cut_an_eighth_of_its_tokens_tall_and_never_over_1024(c
     R, N, C, want = CELLS[cell]
     acc = jax.ShapeDtypeStruct((N, C), jnp.float32)
     tb, rows = jax.ShapeDtypeStruct((R,), jnp.int32), jax.ShapeDtypeStruct((R, C), jnp.float32)
-    eqns = jax.make_jaxpr(glm._add_rows)(acc, tb, rows).jaxpr.eqns
+    eqns = jax.make_jaxpr(moe._add_rows)(acc, tb, rows).jaxpr.eqns
     got = [e.invars[2].aval.shape[0] for e in eqns if e.primitive.name == "scatter-add"]
     assert got == want and all(8 * h <= N for h in got)
     assert STAT_GET("model.moe.combine_piece_rows") == want[0]
@@ -225,7 +230,7 @@ def test_a_cells_block_is_cut_an_eighth_of_its_tokens_tall_and_never_over_1024(c
 @pytest.mark.parametrize("acc_rows", [1, 8, 24, 63, 64, 72, 1000, 4095, 4096, 4104, 8191, 8192, 8200,
                                       16384, 1 << 20])
 def test_a_piece_is_whole_sublanes_never_none_never_over_1024_never_over_an_eighth(acc_rows):
-    h = glm.combine_piece_rows(acc_rows)
+    h = moe.combine_piece_rows(acc_rows)
     assert h % 8 == 0 and 8 <= h <= P
     assert 8 * h <= acc_rows or h == 8  # the as-written form's side of the line, where 8 rows can be
     assert h == P or 8 * (h + 8) > acc_rows  # and the tallest such
@@ -259,14 +264,14 @@ def test_a_sum_cut_by_columns_gives_the_one_part_result_bit_for_bit(parts, width
 
     def run():
         def loss(p, x, g):
-            return jnp.sum(glm.routed_experts(p, x, idx, g, c, "model")[0] * dy)
-        y = jax.jit(lambda p, x, g: glm.routed_experts(p, x, idx, g, c, "model")[0])(p, x, g)
+            return jnp.sum(_routed(p, x, idx, g, c)[0] * dy)
+        y = jax.jit(lambda p, x, g: _routed(p, x, idx, g, c)[0])(p, x, g)
         return [np.asarray(a) for a in [y] + jax.tree.leaves(jax.jit(jax.grad(loss, (0, 1, 2)))(p, x, g))]
 
-    assert glm.combine_parts(N, WIDE) == (WIDE,)
+    assert moe.combine_parts(N, WIDE) == (WIDE,)
     want = run()
-    monkeypatch.setattr(glm, "COMBINE_BYTES", N * WIDE * 4 // parts)
-    assert glm.combine_parts(N, WIDE) == widths
+    monkeypatch.setattr(moe, "COMBINE_BYTES", N * WIDE * 4 // parts)
+    assert moe.combine_parts(N, WIDE) == widths
     got = run()
     assert STAT_GET("model.moe.combine_parts") == parts
     assert STAT_GET("model.moe.combine_part_bytes") == N * widths[0] * 4
@@ -287,7 +292,7 @@ def test_a_sum_cut_by_columns_gives_the_one_part_result_bit_for_bit(parts, width
     (65536, 2560, (384,) * 6 + (256,)),
 ])
 def test_a_token_sum_is_cut_in_parts_of_whole_lane_tiles_under_the_budget(rows, cols, widths):
-    got = glm.combine_parts(rows, cols)
+    got = moe.combine_parts(rows, cols)
     assert got == widths and sum(got) == cols
     assert all(w % 128 == 0 for w in got[:-1]) and 0 < got[-1] <= got[0]
-    assert len(got) <= -(-rows * cols * 4 // glm.COMBINE_BYTES)  # never more parts than the bytes ask
+    assert len(got) <= -(-rows * cols * 4 // moe.COMBINE_BYTES)  # never more parts than the bytes ask

@@ -29,7 +29,7 @@ from benchmark.tests import toy_sdar  # noqa: E402
 from paddlebox_tpu import BoxWrapper  # noqa: E402
 from paddlebox_tpu.data import SlotInfo, SlotSchema  # noqa: E402
 from paddlebox_tpu.models import Sdar, SdarConfig, SmallThinker  # noqa: E402
-from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
+from paddlebox_tpu.models import attention, lm_layers, moe  # noqa: E402
 from paddlebox_tpu.models import sdar  # noqa: E402
 from paddlebox_tpu.ops.pallas_kernels import diffusion_visible  # noqa: E402
 from paddlebox_tpu.table import SparseOptimizerConfig  # noqa: E402
@@ -166,7 +166,8 @@ def test_the_blocked_form_computes_the_table(seeded):
     ks = jax.random.split(jax.random.PRNGKey(39), 3)
     q = jax.random.normal(ks[0], (1, T, 6, 16))
     k, v = (jax.random.normal(a, (1, T, 2, 16)) for a in ks[1:])
-    got = jnp.concatenate([sdar._attend_block(q, k, v, i, 8, 0.25, 3, N) for i in range(0, T, 8)], 1)
+    got = jnp.concatenate([attention._attend_block(q, k, v, i, 8, 0.25, 3, None, N)
+                           for i in range(0, T, 8)], 1)
     kk, vv = (jnp.repeat(a, 3, axis=2) for a in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.25
     p = jax.nn.softmax(jnp.where(_brute_table(L, N), s, -jnp.inf), axis=-1)
@@ -221,8 +222,8 @@ def test_the_loss_weighs_a_masked_position_by_the_blocks_count_and_no_other():
     assert np.asarray(out["parts"]) == pytest.approx(
         [4.0 * ce[1] / 4.0, 4.0 / 3.0 * (ce[4] + ce[5] + ce[7]) / 4.0], rel=1e-6)
     # the target is the clean token at the same position (no shift), read from the noisy half's row
-    h = np.asarray(glm.rms_norm(x[:, 8:], params["final_norm"], c.rms_norm_eps))[0]
-    logits = np.asarray(glm._mm(jnp.asarray(h), params["head"]), np.float64)
+    h = np.asarray(lm_layers.rms_norm(x[:, 8:], params["final_norm"], c.rms_norm_eps))[0]
+    logits = np.asarray(lm_layers._mm(jnp.asarray(h), params["head"]), np.float64)
     assert tl == pytest.approx(logits[np.arange(8), clean], rel=1e-5)
     # an unmasked position carries no loss: its clean id may be anything
     other = sdar.diffusion_loss(params, x, ids.at[0, 0].set(7), c)
@@ -250,9 +251,10 @@ def test_eight_shares_feed_forward_parts_add_up_to_the_uncut_layer(seeded):
     for off in range(8):  # eight chips of one expert each
         c = program_config(experts_offset=off, num_experts=1)
         experts = jax.tree.map(lambda a: a[off:off + 1], layer["experts"])
-        idx, g = glm.route(layer["router"], x, c, "softmax_of_chosen")
+        idx, g = moe.route(layer["router"], x, c.num_experts_per_tok, form="softmax_of_chosen")
         assert np.array_equal(np.sort(idx, -1), np.sort(chosen, -1))  # every chip routes alike
-        routed, counts = glm.routed_experts(experts, x, idx, g, c, "model")
+        routed, counts = moe.routed_experts(experts, x, idx, g, c.experts_held, c.experts_offset,
+                                            c.expert_block, "model")
         here = np.any(np.asarray(idx) == off, axis=1)
         assert 0 < (~here).sum() < B * T  # no shared expert: those rows add exactly zero here
         assert not np.any(np.asarray(routed)[~here])

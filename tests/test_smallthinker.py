@@ -29,7 +29,7 @@ from benchmark.reference import smallthinker as ref  # noqa: E402
 from benchmark.reference import token_step  # noqa: E402
 from paddlebox_tpu import BoxWrapper  # noqa: E402
 from paddlebox_tpu.data import SlotInfo, SlotSchema  # noqa: E402
-from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
+from paddlebox_tpu.models import lm_layers, moe  # noqa: E402
 from paddlebox_tpu.models import smallthinker as st  # noqa: E402
 from paddlebox_tpu.models import (  # noqa: E402
     Afmoe, AfmoeConfig, GlmMoeLiteConfig, SmallThinker, SmallThinkerConfig)
@@ -64,6 +64,12 @@ def seeded():
 
 def _rel(a, b) -> float:
     return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+def _routed(experts, x, idx, g, c, act="silu"):
+    """``moe.routed_experts`` as a layer calls it, its share read off the config."""
+    return moe.routed_experts(experts, x, idx, g, c.experts_held, c.experts_offset, c.expert_block,
+                              "model", act)
 
 
 def _one_layer(params, i=0):
@@ -121,7 +127,7 @@ def test_program_model_agrees_with_the_plain_reference(seeded):
 def test_a_scan_step_told_its_kind_is_the_layer_of_that_kind(seeded):
     params, emb, _ = seeded
     c = program_config()
-    rope = glm.rope_tables(T, c.head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(T, c.head_dim, c.rope_theta)
     p = _one_layer(params)
     outs = {}
     for sliding in (True, False):
@@ -146,7 +152,7 @@ def test_the_layers_kinds_are_the_files_full_first_then_three_sliding():
 def test_the_routers_choice_reads_the_layers_input_and_not_what_attention_made_of_it(seeded):
     params, emb, _ = seeded
     c = program_config()
-    rope = glm.rope_tables(T, c.head_dim, c.rope_theta)
+    rope = lm_layers.rope_tables(T, c.head_dim, c.rope_theta)
     p = _one_layer(params, 1)
     loud = {**p, "attn": jax.tree.map(lambda a: a * 40.0, p["attn"])}  # another attention block
     run = jax.jit(lambda p, s: st.layer(p, emb, c, rope, s))
@@ -155,7 +161,8 @@ def test_the_routers_choice_reads_the_layers_input_and_not_what_attention_made_o
         x2, idx2, _ = run(loud, jnp.asarray(sliding))
         assert _rel(x2, x1) > 0.1  # the stream did change, and the experts' input with it
         assert np.array_equal(idx1, idx2)  # the choice did not
-    want, _ = glm.route(p["router"], emb.reshape(B * T, H), c, "softmax_of_chosen")
+    want, _ = moe.route(p["router"], emb.reshape(B * T, H), c.num_experts_per_tok,
+                        form="softmax_of_chosen")
     assert np.array_equal(idx1.reshape(B * T, K), want)  # the bare input: no norm before it
     # the reference with the planted fault (the router after attention) chooses otherwise
     m = ref._Math(jnp.float32, jnp.bfloat16)
@@ -181,9 +188,9 @@ def test_four_shares_add_up_to_the_uncut_layer_and_nothing_is_counted_twice(seed
     for off in range(0, 8, 2):  # four chips of two experts each
         c = program_config(experts_offset=off)
         experts = jax.tree.map(lambda a: a[off:off + 2], layer["experts"])
-        idx, g = glm.route(layer["router"], x, c, "softmax_of_chosen")
+        idx, g = moe.route(layer["router"], x, c.num_experts_per_tok, form="softmax_of_chosen")
         assert np.array_equal(np.sort(idx, -1), np.sort(chosen, -1))  # every chip routes alike
-        routed, counts = glm.routed_experts(experts, x, idx, g, c, "model", "relu")
+        routed, counts = _routed(experts, x, idx, g, c, "relu")
         with jax.default_matmul_precision("highest"):  # and the reference is given the same share
             ref_share = ref.experts_part({"experts": experts}, x, chosen, w,
                                          {**TINY, "experts_offset": off}, m)
@@ -207,8 +214,8 @@ def _loop_experts(experts, x, idx, g, offset, act):
     y = jnp.zeros_like(x)
     for e in range(experts["gate"].shape[0]):
         w_e = jnp.sum(jnp.where(idx == offset + e, g, 0.0), axis=1, keepdims=True)
-        h = fn(glm._mm(x, experts["gate"][e])) * glm._mm(x, experts["up"][e])
-        y = y + glm._mm(h, experts["down"][e]) * w_e
+        h = fn(lm_layers._mm(x, experts["gate"][e])) * lm_layers._mm(x, experts["up"][e])
+        y = y + lm_layers._mm(h, experts["down"][e]) * w_e
     return y
 
 
@@ -221,14 +228,14 @@ def test_the_gates_activation_through_the_grouped_product_against_a_loop(act):
     experts = {n: jax.random.normal(k, s) * 0.2 for n, k, s in (
         ("gate", ks[1], (4, H, 48)), ("up", ks[2], (4, H, 48)), ("down", ks[3], (4, 48, H)))}
     router = {"w": jax.random.normal(ks[4], (H, 16)) * 0.3}
-    idx, _ = glm.route(router, x, c, "softmax_of_chosen")
+    idx, _ = moe.route(router, x, c.num_experts_per_tok, form="softmax_of_chosen")
 
     def grouped(experts, x, router):
-        g = glm.route(router, x, c, "softmax_of_chosen")[1]
-        return jnp.sum(glm.routed_experts(experts, x, idx, g, c, "model", act)[0] ** 2)
+        g = moe.route(router, x, c.num_experts_per_tok, form="softmax_of_chosen")[1]
+        return jnp.sum(_routed(experts, x, idx, g, c, act)[0] ** 2)
 
     def loop(experts, x, router):
-        g = glm.route(router, x, c, "softmax_of_chosen")[1]
+        g = moe.route(router, x, c.num_experts_per_tok, form="softmax_of_chosen")[1]
         return jnp.sum(_loop_experts(experts, x, idx, g, 4, act) ** 2)
 
     got, dgot = jax.jit(jax.value_and_grad(grouped, argnums=(0, 1, 2)))(experts, x, router)
@@ -238,10 +245,11 @@ def test_the_gates_activation_through_the_grouped_product_against_a_loop(act):
         assert float(jnp.linalg.norm(b)) > 0 and _rel(a, b) < 0.02  # where a bfloat16 cotangent rounds
     other = {"relu": "silu", "silu": "relu"}[act]  # and the other activation is another function
     assert abs(float(grouped(experts, x, router)) - float(jnp.sum(
-        _loop_experts(experts, x, idx, glm.route(router, x, c, "softmax_of_chosen")[1], 4, other) ** 2))
+        _loop_experts(experts, x, idx, moe.route(router, x, 4, form="softmax_of_chosen")[1], 4,
+                      other) ** 2))
                ) > 0.05 * float(want)
     with pytest.raises(ValueError, match="gate activation"):
-        glm.routed_experts(experts, x, idx, jnp.ones(idx.shape), c, "model", "gelu")
+        _routed(experts, x, idx, jnp.ones(idx.shape), c, "gelu")
 
 
 def test_the_softmax_router_picks_6_by_logit_weighs_by_a_softmax_over_them_and_reads_no_bias():
@@ -249,7 +257,7 @@ def test_the_softmax_router_picks_6_by_logit_weighs_by_a_softmax_over_them_and_r
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.normal(size=(40, H)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(H, 64)) * 0.3, jnp.float32)
-    idx, g = glm.route({"w": w}, x, c, "softmax_of_chosen")  # no bias leaf, no scale on the config
+    idx, g = moe.route({"w": w}, x, c.num_experts_per_tok, form="softmax_of_chosen")  # no bias, no scale
     r = np.asarray(x, np.float64) @ np.asarray(w, np.float64)
     assert idx.shape == g.shape == (40, 6) and idx.dtype == jnp.int32
     assert np.array_equal(np.sort(idx, -1), np.sort(np.argsort(-r, axis=1)[:, :6], -1))
@@ -262,7 +270,7 @@ def test_the_softmax_router_picks_6_by_logit_weighs_by_a_softmax_over_them_and_r
                                           rel=1e-5)
     assert np.asarray(g).sum(1) == pytest.approx(1.0, rel=1e-6)
     with pytest.raises(ValueError, match="router form"):
-        glm.route({"w": w}, x, c, "softmax")
+        moe.route({"w": w}, x, c.num_experts_per_tok, form="softmax")
 
 
 # GLM's and Trinity's calls name neither the router's form nor the gate's activation
@@ -291,18 +299,19 @@ def test_glms_and_trinitys_calls_trace_to_the_jaxprs_they_had_before_form_and_ac
     c = SETTINGS[model]
     N, Hc, I = 24, c.hidden_size, c.moe_intermediate_size
     E = getattr(c, "n_routed_experts", None) or c.num_experts
+    scale = getattr(c, "routed_scaling_factor", None) or c.route_scale
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     router = {"w": f32(Hc, E), "bias": f32(E)}
     G = c.experts_held
     experts = {"gate": f32(G, Hc, I), "up": f32(G, Hc, I), "down": f32(G, I, Hc)}
 
     def routed(p, x):
-        idx, g = glm.route(p["router"], x, c)
-        y, counts = glm.routed_experts(p["experts"], x, idx, g, c, "model")
+        idx, g = moe.route(p["router"], x, c.num_experts_per_tok, scale=scale)
+        y, counts = _routed(p["experts"], x, idx, g, c)
         return jnp.sum(y * y), (idx, counts)
 
     def routef(p, x):
-        idx, g = glm.route(p, x, c)
+        idx, g = moe.route(p, x, c.num_experts_per_tok, scale=scale)
         return jnp.sum(g * g), idx
 
     f, p = {"route": (routef, router),

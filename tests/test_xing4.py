@@ -29,6 +29,7 @@ from benchmark.reference import xing4 as ref  # noqa: E402
 from paddlebox_tpu import BoxWrapper  # noqa: E402
 from paddlebox_tpu.data import SlotInfo, SlotSchema  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
+from paddlebox_tpu.models import lm_layers  # noqa: E402
 from paddlebox_tpu.models import xing4  # noqa: E402
 from paddlebox_tpu.models import Xing4  # noqa: E402
 from paddlebox_tpu.obs.program_scopes import scope_map  # noqa: E402
@@ -251,15 +252,15 @@ def test_yarn_tables_and_scale_at_the_published_numbers():
     want = f / factor * ramp + f * (1 - ramp)
     assert np.array_equal(want[:11], f[:11]) and np.allclose(want[23:], f[23:] / 64)
     assert np.allclose(ref.yarn_inv_freq(cfg), want, rtol=1e-12)
-    cos, sin = glm.yarn_rope_tables(256, d, c.rope_theta, c.rope_factor, c.rope_original,
+    cos, sin = lm_layers.yarn_rope_tables(256, d, c.rope_theta, c.rope_factor, c.rope_original,
                                     c.beta_fast, c.beta_slow)
     ang = np.arange(256, dtype=np.float32)[:, None] * want.astype(np.float32)[None, :]  # float32, as there
     assert np.allclose(cos, np.cos(ang), atol=1e-5) and np.allclose(sin, np.sin(ang), atol=1e-5)
     # without scaling the tables are the plain ones
-    plain = glm.yarn_rope_tables(256, d, 1e4, 1.0, 4096, 32, 1)
-    assert all(np.allclose(a, b, atol=1e-6) for a, b in zip(plain, glm.rope_tables(256, d, 1e4)))
+    plain = lm_layers.yarn_rope_tables(256, d, 1e4, 1.0, 4096, 32, 1)
+    assert all(np.allclose(a, b, atol=1e-6) for a, b in zip(plain, lm_layers.rope_tables(256, d, 1e4)))
     m = 0.1 * np.log(64) + 1
-    assert glm.yarn_mscale(64, 1) == pytest.approx(m) and glm.yarn_mscale(1, 1) == 1.0
+    assert lm_layers.yarn_mscale(64, 1) == pytest.approx(m) and lm_layers.yarn_mscale(1, 1) == 1.0
     assert c.softmax_scale == pytest.approx(192 ** -0.5 * m * m) == pytest.approx(0.14468, rel=1e-4)
     assert ref.softmax_scale(cfg) == pytest.approx(c.softmax_scale, rel=1e-12)
     assert ref.softmax_scale({**cfg, "yarn_scale_left_out": True}) == pytest.approx(192 ** -0.5)
