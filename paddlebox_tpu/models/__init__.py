@@ -9,6 +9,7 @@ from paddlebox_tpu.models.afmoe import Afmoe, AfmoeConfig
 from paddlebox_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 from paddlebox_tpu.models.sdar import Sdar, SdarConfig
 from paddlebox_tpu.models.xing4 import Xing4, Xing4Config
+from paddlebox_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 
 __all__ = [
     "mlp_init",
@@ -32,4 +33,6 @@ __all__ = [
     "SdarConfig",
     "Xing4",
     "Xing4Config",
+    "OlmoHybrid",
+    "OlmoHybridConfig",
 ]

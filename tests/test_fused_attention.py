@@ -8,7 +8,9 @@ grouped-query heads and a window (``models/afmoe.py``), at a group that is no
 power of two (7, ``models/smallthinker.py``) and that model's 16k shapes, under
 the block-diffusion mask (``diffusion_block``, ``models/sdar.py``), and at
 query/key heads of another width than the value heads (192 and 128,
-``models/xing4.py``); every older call held to the kernel it had."""
+``models/xing4.py``), and the full layers of a stack that is three parts
+linear attention (``models/olmo_hybrid.py``); every older call held to the
+kernel it had."""
 
 from __future__ import annotations
 
@@ -29,8 +31,8 @@ from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 from paddlebox_tpu.models import afmoe, attention, lm_layers, moe, sdar  # noqa: E402
 from paddlebox_tpu.models import glm_moe_lite as glm  # noqa: E402
 from paddlebox_tpu.models import (  # noqa: E402
-    Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, SmallThinker, SmallThinkerConfig, Xing4,
-    Xing4Config)
+    Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, OlmoHybrid, OlmoHybridConfig, SmallThinker,
+    SmallThinkerConfig, Xing4, Xing4Config)
 from paddlebox_tpu.ops.pallas_kernels import (  # noqa: E402
     KEEP_SCORES, SCORES_LSE, SCORES_OUT, causal_attention)
 from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
@@ -626,12 +628,18 @@ KEEPERS = {
         **_SMALL, num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=128,
         qk_rope_head_dim=64, v_head_dim=128, num_hidden_layers=3, first_k_dense_replace=1,
         intermediate_size=32, n_routed_experts=8)), 2, 2, "model.mla.keep_scores_sites"),
+    "olmo_hybrid": (lambda: OlmoHybrid(OlmoHybridConfig.from_dict(dict(
+        _SMALL, num_attention_heads=1, num_key_value_heads=1, head_dim=128, linear_num_key_heads=2,
+        linear_num_value_heads=2, linear_key_head_dim=16, linear_value_head_dim=32,
+        intermediate_size=32))), 1, 1, "model.attn.keep_scores_sites"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(KEEPERS))
 def test_every_token_models_checkpoints_keep_the_scores_and_count_themselves(monkeypatch, name):
-    """All five models took the policy (Xing4's with its model, PR 42: a dense layer and a scan body, 192 / 128 wide). The four before it took the policy (PR 36; SDAR's with its model, PR 39: each cell's superstep still
+    """All six models take the policy (Olmo-Hybrid's in the full layer of a
+    period's scan body, Xing4's in a dense layer and a scan body at 192 / 128
+    wide; with it each cell's superstep still
     fits the chip and its step is shorter, ``PERF.md`` section 6): on a TPU the gradient of
     ``apply`` holds each call site's forward kernel once, every checkpoint of
     a layer carries ``KEEP_SCORES``, and the trace-time counter says how many."""
@@ -940,3 +948,35 @@ def test_the_combines_token_sum_stays_in_fast_memory_cut_where_it_is_over_96_mib
                if " fusion(" in line and "moe/combine/scatter-add" in line]
     assert len(in_fast) == scatters and all(in_fast), in_fast
     assert not re.findall(r" sort\(.*moe/combine", text)
+
+
+def test_olmo_hybrids_loss_and_gradient_compile_for_a_v5e_inside_the_memory_4096_tokens_leave(
+        one_chip, monkeypatch):
+    """The cell's model (``benchmark/configs/olmo_hybrid_7b_hp3.json``: one
+    record of 4,096 tokens, one period of three linear layers and a full one,
+    10 of 30 heads), loss and gradient of every leaf and of the rows, the
+    fused path forced: the full layer's forward and backward kernel, and 9.28
+    GB at the peak today (3.04 of parameters, 3.04 of gradients, 3.47 of
+    temporaries). The superstep adds Adam's two moments (5.95 GB) and the
+    table (0.51) to that; with more than 10.2 GB here it would not fit the
+    chip's 17.18 at this seq_len."""
+    from benchmark.models import olmo_hybrid as build
+    from paddlebox_tpu.obs.program_scopes import scope_map
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "olmo_hybrid_7b_hp3.json")) as f:
+        cfg = json.load(f)
+    model = build.build(cfg, 3 + cfg["embedx_dim"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, T, H = cfg["batch_size"], cfg["seq_len"], cfg["hidden_size"]
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(on, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    emb, ids = (jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in ((B, T, H), (B, T)))
+    compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
+        params, emb, ids).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert compiled.memory_analysis().peak_memory_in_bytes < 9.6e9  # 9.28 today
+    kernels = {n: s for n, s in scope_map(text).items() if "causal_attention" in n}
+    assert set(kernels.values()) == {"model/attn/scores_full"}, kernels
+    assert "model/linear_attn/delta_rule" in set(scope_map(text).values())

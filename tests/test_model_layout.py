@@ -1,7 +1,8 @@
 """The layering of ``paddlebox_tpu/models/``, read off the source with ``ast``:
-the models stand on shared modules (``attention``, ``moe``, ``lm_layers``,
-``layers``, ``base``) and not on each other, no module reaches into another's
-private names, and the fused attention kernel has one way in."""
+the models stand on shared modules (``attention``, ``linear_attention``,
+``moe``, ``lm_layers``, ``layers``, ``base``) and not on each other, no
+module reaches into another's private names, and the fused attention kernel
+has one way in."""
 
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ import pathlib
 import pytest
 
 MODELS = pathlib.Path(__file__).resolve().parents[1] / "paddlebox_tpu" / "models"
-SHARED = {"__init__", "attention", "base", "layers", "lm_layers", "moe"}
+SHARED = {"__init__", "attention", "base", "layers", "linear_attention", "lm_layers", "moe"}
 # the one import of a model module by another: Xing4 subclasses GLM's model and config
 ALLOWED = {("xing4", "glm_moe_lite")}
 # the shared modules' own bfloat16 products, which every model's equations are built of
@@ -46,7 +47,7 @@ def _model_modules_imported(tree, models):
 def test_no_model_module_imports_another_but_xing4_glms():
     trees = _trees()
     models = set(trees) - SHARED
-    assert {"glm_moe_lite", "afmoe", "smallthinker", "sdar", "xing4"} <= models
+    assert {"glm_moe_lite", "afmoe", "smallthinker", "sdar", "xing4", "olmo_hybrid"} <= models
     edges = {(name, other) for name, tree in trees.items() if name != "__init__"  # the exports
              for other in _model_modules_imported(tree, models) if other != name}
     assert edges == ALLOWED
