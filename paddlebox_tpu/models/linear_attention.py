@@ -14,12 +14,15 @@ u_t = beta_t (v_t - alpha_t S_{t-1} k_t) solve a unit lower-triangular system
 beta_t e^{gamma_t - gamma_s} (k_t . k_s) for s < t; its inverse is the
 doubling product (I - A)(I + A^2)(I + A^4)... on the MXU over blocks of 16
 rows, joined by forward substitution a block row at a time. Everything that
-does not read S_0 is batched over every chunk and head; only S passes from
-chunk to chunk, in a ``lax.scan`` of T / C steps. Decays enter as
-differences of cumulative log-decays masked to t >= s before ``exp``, never
-as e^gamma and e^-gamma apart. The rule is float32 throughout, its products
-at ``highest``; the backward is jax's through the chunk scan (a state a
-chunk kept).
+does not read S_0 is batched over every chunk and head (``chunk_operands``);
+only S passes from chunk to chunk: on a TPU in one Pallas kernel each way,
+the state in VMEM for the whole record
+(``ops/pallas_kernels.py::delta_rule_recurrence``, chosen by
+``fused_recurrence``), elsewhere in a ``lax.scan`` of T / C steps, the
+kernel's oracle (``chunk_scan``; its backward is jax's, a state a chunk
+kept). Decays enter as differences of cumulative log-decays masked to t >= s
+before ``exp``, never as e^gamma and e^-gamma apart. The rule is float32
+throughout, its products at ``highest``, in the kernel as in the scan.
 """
 
 from __future__ import annotations
@@ -29,6 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddlebox_tpu.models.lm_layers import F32
+from paddlebox_tpu.ops.pallas_kernels import (
+    RECURRENCE_VMEM_BYTES, SUBLANE, delta_rule_recurrence, recurrence_vmem_bytes)
 from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
 HI = lax.Precision.HIGHEST
@@ -92,18 +97,26 @@ def unit_lower_inverse(A):
     return inv
 
 
-def delta_rule(q, k, v, beta, g, chunk: int, scope: str):
-    """o_t = S_t q_t. q, k [B, T, H, dk] (unit rows), v [B, T, H, dv], beta and
-    the log-decay g = log alpha [B, T, H], float32 -> o [B, T, H, dv] float32,
-    in chunks of ``chunk`` tokens (a divisor of T). ``scope`` names the chunk
-    scan's body too: a scan's body is traced under a name stack of its own."""
-    B, T, H, dk = q.shape
-    dv, C = v.shape[-1], min(chunk, T)
-    if T % C:
-        raise ValueError(f"seq_len {T} is not a multiple of chunk {C}")
+def fused_recurrence(backend: str, chunk: int, dk: int, dv: int, heads: int) -> bool:
+    """Whether ``delta_rule``'s chunk-to-chunk recurrence takes the kernel
+    (``ops/pallas_kernels.py::delta_rule_recurrence``): on a TPU, at chunks
+    and key widths of whole sublane rows, where a grid step's blocks of a
+    record's heads take at most half the kernel's VMEM (the rest is its
+    products' temporaries). Everything else runs the chunk scan."""
+    if backend != "tpu" or chunk % SUBLANE or dk % SUBLANE:
+        return False
+    return 2 * recurrence_vmem_bytes(heads, chunk, dk, dv) <= RECURRENCE_VMEM_BYTES
+
+
+def chunk_operands(q, k, v, beta, g, C: int):
+    """What the chunk-to-chunk recurrence reads, batched over every chunk and
+    head (``delta_rule``'s arguments, chunks of C tokens) -> W, Qd, Kd [n, B,
+    H, C, dk], U0 [n, B, H, C, dv], P [n, B, H, C, C] and last [n, B, H]: the
+    incoming state's part of a chunk's new values, its queries and keys
+    decayed from the chunk's start and to its end, the new values from the
+    chunk alone, the chunk's decayed scores and its whole decay."""
+    B, T, H = q.shape[:3]
     n = T // C
-    STAT_ADD("model.linear_attn.chunked_sites")  # at trace time
-    STAT_SET("model.linear_attn.chunk", C)
 
     def chunks(a):  # [B, T, H, ...] -> [n, B, H, C, ...]
         a = a.reshape(B, n, C, H, *a.shape[3:])
@@ -123,7 +136,37 @@ def delta_rule(q, k, v, beta, g, chunk: int, scope: str):
     P = _product32("...td,...sd->...ts", q, k) * decay  # t >= s, the diagonal too
     Qd = first * q
     Kd = jnp.exp(gam[..., -1:] - gam)[..., None] * k  # e^{gamma_C - gamma_s} k_s
-    last = jnp.exp(gam[..., -1])[..., None, None]  # [n, B, H, 1, 1]
+    return W, U0, P, Qd, Kd, jnp.exp(gam[..., -1])
+
+
+def delta_rule(q, k, v, beta, g, chunk: int, scope: str):
+    """o_t = S_t q_t. q, k [B, T, H, dk] (unit rows), v [B, T, H, dv], beta and
+    the log-decay g = log alpha [B, T, H], float32 -> o [B, T, H, dv] float32,
+    in chunks of ``chunk`` tokens (a divisor of T): the chunks' operands
+    batched (``chunk_operands``), then the state from chunk to chunk by the
+    kernel where ``fused_recurrence`` says so, else by the chunk scan.
+    ``scope`` names the chunk scan's body too: a scan's body is traced under
+    a name stack of its own."""
+    B, T, H, dk = q.shape
+    dv, C = v.shape[-1], min(chunk, T)
+    if T % C:
+        raise ValueError(f"seq_len {T} is not a multiple of chunk {C}")
+    STAT_SET("model.linear_attn.chunk", C)
+    operands = chunk_operands(q, k, v, beta, g, C)
+    if fused_recurrence(jax.default_backend(), C, dk, dv, H):
+        STAT_ADD("model.linear_attn.fused_sites")  # at trace time
+        o = delta_rule_recurrence(*operands)
+    else:
+        STAT_ADD("model.linear_attn.chunked_sites")  # at trace time
+        o = chunk_scan(*operands, scope)
+    return jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, T, H, dv)
+
+
+def chunk_scan(W, U0, P, Qd, Kd, last, scope: str):
+    """The recurrence as a ``lax.scan`` over the chunks, every backend's but
+    a TPU's and the kernel's oracle: ``chunk_operands`` -> o [n, B, H, C, dv]."""
+    B, H, dk, dv = *W.shape[1:3], W.shape[-1], U0.shape[-1]
+    last = last[..., None, None]
 
     def step(M, xs):  # M = S^T [B, H, dk, dv]
         with jax.named_scope(scope):
@@ -133,5 +176,4 @@ def delta_rule(q, k, v, beta, g, chunk: int, scope: str):
                  + _product32("...ts,...se->...te", P, U))
             return last * M + _product32("...sd,...se->...de", Kd, U), o
 
-    _, o = lax.scan(step, jnp.zeros((B, H, dk, dv), F32), (W, U0, P, Qd, Kd, last))
-    return jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, T, H, dv)
+    return lax.scan(step, jnp.zeros((B, H, dk, dv), F32), (W, U0, P, Qd, Kd, last))[1]

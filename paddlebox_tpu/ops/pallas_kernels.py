@@ -3,12 +3,14 @@
 ``models/afmoe.py`` (grouped-query heads, window and full layers, since PR 33),
 under the block-diffusion training mask of ``models/sdar.py`` (PR 39), and at
 query/key heads of another width than the value heads (192 and 128,
-``models/xing4.py``, PR 42).
+``models/xing4.py``, PR 42); the chunk-to-chunk recurrence of the gated delta
+rule of ``models/olmo_hybrid.py``'s linear layers.
 
 A kernel lives here when a call site chooses it from what it can observe
-(backend and shapes: ``models/attention.py::fused``), its XLA form
-stays as every other backend's path and as its oracle
-(``tests/test_fused_attention.py``), a counter says which form was lowered,
+(backend and shapes: ``models/attention.py::fused``,
+``models/linear_attention.py::fused_recurrence``), its XLA form stays as
+every other backend's path and as its oracle (``tests/test_fused_attention.py``,
+``tests/test_delta_rule_kernel.py``), a counter says which form was lowered,
 and a benchmark cell runs it. The table gather and scatter of
 ``ops/pull_push.py`` are XLA's: per-row DMA kernels lost to them by 3.3x at
 the one lane-aligned shape ever measured (docs/SCATTER_NOTES.md).
@@ -26,6 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128  # Mosaic lane width
+SUBLANE = 8  # float32 rows of a Mosaic tile
 
 # ---- fused causal attention ---------------------------------------------------
 #
@@ -472,3 +475,142 @@ def _causal_attention_bwd(scale, block, interpret, group, window, diffusion_bloc
 
 
 causal_attention.defvjp(_causal_attention_fwd, _causal_attention_bwd)
+
+
+# ---- the gated delta rule's chunk-to-chunk recurrence ---------------------------
+#
+# What passes from chunk to chunk in ``models/linear_attention.py::delta_rule``:
+# the state M = S^T [H, dk, dv] of a record's heads, held in a VMEM scratch
+# across a grid that walks the chunks in order (backward: in reverse, the
+# state's cotangent dM held instead). A grid step is one chunk of all heads of
+# a record, its products batched over the heads, float32 operands at
+# ``highest`` with float32 accumulation, as the rule's XLA form has them:
+#
+#   forward    U = U0 - W M,   o = Qd M + P U,   M <- last M + Kd^T U
+#   backward   (M the state that entered the chunk, U recomputed from it)
+#              dU = P^T do + Kd dM,   dP = do U^T,   dQd = do M^T,   dKd = U dM^T,
+#              dlast = sum(M * dM),   dU0 = dU,   dW = -dU M^T,
+#              dM <- last dM + Qd^T do - W^T dU
+#
+# The forward rule writes the state that enters each chunk (``Ms``, the
+# backward's residual); the plain call does not. Blocks are whole chunks and
+# whole widths, so any dk, dv and chunk is a legal block (dk 96 rides in lanes
+# of 128 in VMEM); ``models/linear_attention.py::fused_recurrence`` says which
+# shapes take the kernel.
+
+_HI = jax.lax.Precision.HIGHEST
+_NN_H = (((2,), (1,)), ((0,), (0,)))  # [H, a, b] x [H, b, c] -> [H, a, c]
+_TN_H = (((1,), (1,)), ((0,), (0,)))  # [H, b, a] x [H, b, c] -> [H, a, c]
+_NT_H = (((2,), (2,)), ((0,), (0,)))  # [H, a, b] x [H, c, b] -> [H, a, c]
+RECURRENCE_VMEM_BYTES = 64 << 20
+# grid (record, chunk): the state passes along the chunk axis
+_RECURRENCE_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=RECURRENCE_VMEM_BYTES)
+
+
+def recurrence_vmem_bytes(heads: int, chunk: int, dk: int, dv: int) -> int:
+    """VMEM of the backward's grid step (the larger of the two): its 14 blocks
+    twice (the pipeline's two buffers) and dM, float32 tiles of (8, 128)."""
+    def tile(rows, cols):
+        return -(-rows // SUBLANE) * SUBLANE * -(-cols // LANE) * LANE
+
+    blocks = (6 * tile(chunk, dk) + 3 * tile(chunk, dv) + 2 * tile(chunk, chunk) + tile(dk, dv)
+              + 2 * tile(1, 1))
+    return 4 * heads * (2 * blocks + tile(dk, dv))
+
+
+def _dot32(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _recurrence_fwd_kernel(w_ref, u0_ref, p_ref, qd_ref, kd_ref, last_ref, o_ref, *rest):
+    *ms_ref, m_sc = rest  # the entering states' block where the forward rule keeps them
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        m_sc[...] = jnp.zeros(m_sc.shape, jnp.float32)
+
+    M = m_sc[...]
+    if ms_ref:
+        ms_ref[0][...] = M
+    U = u0_ref[...] - _dot32(w_ref[...], M, _NN_H)
+    o_ref[...] = _dot32(qd_ref[...], M, _NN_H) + _dot32(p_ref[...], U, _NN_H)
+    m_sc[...] = last_ref[...] * M + _dot32(kd_ref[...], U, _TN_H)
+
+
+def _recurrence_bwd_kernel(w_ref, u0_ref, p_ref, qd_ref, kd_ref, last_ref, ms_ref, do_ref,
+                           dw_ref, du0_ref, dp_ref, dqd_ref, dkd_ref, dlast_ref, dm_sc):
+    @pl.when(pl.program_id(1) == 0)  # the last chunk: nothing flows back into its state
+    def _():
+        dm_sc[...] = jnp.zeros(dm_sc.shape, jnp.float32)
+
+    W, M, dM, do = w_ref[...], ms_ref[...], dm_sc[...], do_ref[...]
+    U = u0_ref[...] - _dot32(W, M, _NN_H)
+    dU = _dot32(p_ref[...], do, _TN_H) + _dot32(kd_ref[...], dM, _NN_H)
+    dp_ref[...] = _dot32(do, U, _NT_H)
+    dqd_ref[...] = _dot32(do, M, _NT_H)
+    dkd_ref[...] = _dot32(U, dM, _NT_H)
+    dlast_ref[...] = jnp.sum(jnp.sum(M * dM, axis=2, keepdims=True), axis=1, keepdims=True)
+    du0_ref[...] = dU
+    dw_ref[...] = -_dot32(dU, M, _NT_H)
+    dm_sc[...] = last_ref[...] * dM + _dot32(qd_ref[...], do, _TN_H) - _dot32(W, dU, _TN_H)
+
+
+def _chunk_spec(shape, reverse: bool):
+    """One chunk of one record, all heads: a block of [n, B, H, rows, cols]."""
+    n = shape[0]
+    at = (lambda b, i: (n - 1 - i, b, 0, 0, 0)) if reverse else (lambda b, i: (i, b, 0, 0, 0))
+    return pl.BlockSpec((None, None) + tuple(shape[2:]), at)
+
+
+def _over_chunks(kernel, ins, outs, reverse: bool, interpret: bool, name: str):
+    """``kernel`` over the grid (record, chunk), the chunks in order or in
+    reverse, a state's [H, dk, dv] in VMEM; ins[0] is W, ins[1] U0, ins[5]
+    last [n, B, H], handed to the kernel as [n, B, H, 1, 1]."""
+    n, B, H, _, dk = ins[0].shape
+    ins = (*ins[:5], ins[5].reshape(n, B, H, 1, 1), *ins[6:])
+    return pl.pallas_call(
+        kernel, grid=(B, n),
+        in_specs=[_chunk_spec(a.shape, reverse) for a in ins],
+        out_specs=[_chunk_spec(o.shape, reverse) for o in outs],
+        out_shape=outs, scratch_shapes=[pltpu.VMEM((H, dk, ins[1].shape[-1]), jnp.float32)],
+        compiler_params=_RECURRENCE_PARAMS, interpret=interpret, name=name)(*ins)
+
+
+def _recurrence_fwd(W, U0, P, Qd, Kd, last, interpret: bool, keep_states: bool):
+    """-> [o], and the state entering each chunk where ``keep_states``."""
+    n, B, H, C, dk = W.shape
+    f32 = lambda *s: jax.ShapeDtypeStruct((n, B, H, *s, U0.shape[-1]), jnp.float32)  # noqa: E731
+    outs = [f32(C)] + ([f32(dk)] if keep_states else [])
+    return _over_chunks(_recurrence_fwd_kernel, (W, U0, P, Qd, Kd, last), outs, False, interpret,
+                        "delta_rule_recurrence_fwd")
+
+
+def _recurrence_bwd(W, U0, P, Qd, Kd, last, Ms, do, interpret: bool):
+    ins = (W, U0, P, Qd, Kd, last, Ms, do)
+    outs = [jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in ins[:5]]
+    outs.append(jax.ShapeDtypeStruct(last.shape + (1, 1), jnp.float32))
+    grads = _over_chunks(_recurrence_bwd_kernel, ins, outs, True, interpret,
+                         "delta_rule_recurrence_bwd")
+    return (*grads[:5], grads[5].reshape(last.shape))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def delta_rule_recurrence(W, U0, P, Qd, Kd, last, interpret: bool = False):
+    """The gated delta rule's chunk-to-chunk recurrence, float32: W [n, B, H,
+    C, dk], U0 [n, B, H, C, dv], P [n, B, H, C, C], Qd and Kd [n, B, H, C,
+    dk], last [n, B, H] -> o [n, B, H, C, dv], chunk by chunk from a zero
+    state (the equations above). The cotangents of all six operands come back."""
+    return _recurrence_fwd(W, U0, P, Qd, Kd, last, interpret, keep_states=False)[0]
+
+
+def _delta_rule_recurrence_fwd(W, U0, P, Qd, Kd, last, interpret):
+    o, Ms = _recurrence_fwd(W, U0, P, Qd, Kd, last, interpret, keep_states=True)
+    return o, (W, U0, P, Qd, Kd, last, Ms)
+
+
+def _delta_rule_recurrence_bwd(interpret, res, do):
+    return _recurrence_bwd(*res, do, interpret)
+
+
+delta_rule_recurrence.defvjp(_delta_rule_recurrence_fwd, _delta_rule_recurrence_bwd)

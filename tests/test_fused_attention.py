@@ -34,7 +34,7 @@ from paddlebox_tpu.models import (  # noqa: E402
     Afmoe, AfmoeConfig, GlmMoeLite, GlmMoeLiteConfig, OlmoHybrid, OlmoHybridConfig, SmallThinker,
     SmallThinkerConfig, Xing4, Xing4Config)
 from paddlebox_tpu.ops.pallas_kernels import (  # noqa: E402
-    KEEP_SCORES, SCORES_LSE, SCORES_OUT, causal_attention)
+    KEEP_SCORES, SCORES_LSE, SCORES_OUT, causal_attention, delta_rule_recurrence)
 from paddlebox_tpu.utils.monitor import STAT_GET  # noqa: E402
 
 B, T, H, D = 1, 256, 2, 128
@@ -855,6 +855,31 @@ def test_two_width_kernels_compile_for_a_v5e_at_the_xing4_cells_shapes(one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 4096 * 32 * 256 * 4
 
 
+def test_delta_rule_recurrence_kernels_compile_for_a_v5e_at_the_olmo_hybrid_cells_shapes(one_chip):
+    """64 chunks of 64 tokens, 10 heads of 96 / 192 (``models/linear_attention.py``):
+    the forward that keeps the state entering each chunk [64, 1, 10, 96, 192]
+    and the backward, one chunk of all heads a grid step; nothing but that
+    state between them."""
+    from paddlebox_tpu.obs.program_scopes import scope_map
+
+    on = lambda *s: jax.ShapeDtypeStruct((64, 1, 10) + s, jnp.float32, sharding=one_chip)  # noqa: E731
+
+    def step(W, U0, P, Qd, Kd, last, do):
+        with jax.named_scope("model/linear_attn/delta_rule"):
+            return jax.grad(lambda *a: jnp.sum(delta_rule_recurrence(*a) * do), argnums=range(6))(
+                W, U0, P, Qd, Kd, last)
+
+    compiled = jax.jit(step).lower(on(64, 96), on(64, 192), on(64, 64), on(64, 96), on(64, 96),
+                                   on(), on(64, 192)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    kernels = {n: s for n, s in scope_map(text).items() if "delta_rule_recurrence" in n}
+    assert len(kernels) == 2 and set(kernels.values()) == {"model/linear_attn/delta_rule"}, kernels
+    assert re.search(r"delta_rule_recurrence_fwd\S* = \(f32\[64,1,10,64,192\]\S*, "
+                     r"f32\[64,1,10,96,192\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 64 * 10 * 96 * 192 * 4
+
+
 def test_xing4s_loss_and_gradient_compile_for_a_v5e_inside_the_memory_4096_tokens_leave(
         one_chip, monkeypatch):
     """The cell's model (``benchmark/configs/xing4_29b_a4b_ep8.json``: one
@@ -955,11 +980,14 @@ def test_olmo_hybrids_loss_and_gradient_compile_for_a_v5e_inside_the_memory_4096
     """The cell's model (``benchmark/configs/olmo_hybrid_7b_hp3.json``: one
     record of 4,096 tokens, one period of three linear layers and a full one,
     10 of 30 heads), loss and gradient of every leaf and of the rows, the
-    fused path forced: the full layer's forward and backward kernel, and 9.28
-    GB at the peak today (3.04 of parameters, 3.04 of gradients, 3.47 of
-    temporaries). The superstep adds Adam's two moments (5.95 GB) and the
-    table (0.51) to that; with more than 10.2 GB here it would not fit the
-    chip's 17.18 at this seq_len."""
+    fused path forced: the full layer's forward and backward kernel, the
+    linear layers' recurrence kernels (the forward in the forward scan, the
+    forward again and the backward in the backward scan, all three under the
+    delta rule's scope), and 9.26 GB at the peak today (3.04 of parameters,
+    3.04 of gradients, 3.46 of temporaries; 9.28 with the chunk scan). The
+    superstep adds Adam's two moments (5.95 GB) and the table (0.51) to that;
+    with more than 10.2 GB here it would not fit the chip's 17.18 at this
+    seq_len."""
     from benchmark.models import olmo_hybrid as build
     from paddlebox_tpu.obs.program_scopes import scope_map
 
@@ -975,8 +1003,11 @@ def test_olmo_hybrids_loss_and_gradient_compile_for_a_v5e_inside_the_memory_4096
     compiled = jax.jit(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True)).lower(
         params, emb, ids).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert compiled.memory_analysis().peak_memory_in_bytes < 9.6e9  # 9.28 today
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert compiled.memory_analysis().peak_memory_in_bytes < 9.6e9  # 9.26 today
     kernels = {n: s for n, s in scope_map(text).items() if "causal_attention" in n}
     assert set(kernels.values()) == {"model/attn/scores_full"}, kernels
-    assert "model/linear_attn/delta_rule" in set(scope_map(text).values())
+    rule = {n: s for n, s in scope_map(text).items() if "delta_rule_recurrence" in n}
+    assert sorted(re.sub(r"\.\d+$", "", n) for n in rule) == [
+        "delta_rule_recurrence_bwd", "delta_rule_recurrence_fwd", "delta_rule_recurrence_fwd"]
+    assert set(rule.values()) == {"model/linear_attn/delta_rule"}, rule

@@ -1,8 +1,8 @@
 """The layering of ``paddlebox_tpu/models/``, read off the source with ``ast``:
 the models stand on shared modules (``attention``, ``linear_attention``,
 ``moe``, ``lm_layers``, ``layers``, ``base``) and not on each other, no
-module reaches into another's private names, and the fused attention kernel
-has one way in."""
+module reaches into another's private names, and each kernel (the fused
+attention's, the delta rule's recurrence) has one way in."""
 
 from __future__ import annotations
 
@@ -68,6 +68,9 @@ def test_no_module_imports_a_private_name_from_another():
     ("causal_attention", ("attention", "scores")),  # the one way into the fused kernel
     ("_attend_block", ("attention", "scores")),     # and its one oracle beside it
     ("fused", ("attention", "scores")),             # chosen by one rule
+    ("delta_rule_recurrence", ("linear_attention", "delta_rule")),  # the recurrence's kernel,
+    ("chunk_scan", ("linear_attention", "delta_rule")),             # its oracle
+    ("fused_recurrence", ("linear_attention", "delta_rule")),       # and its rule
 ])
 def test_the_kernel_its_oracle_and_its_rule_are_called_from_one_function(callee, where):
     calls = []
@@ -82,4 +85,5 @@ def test_the_kernel_its_oracle_and_its_rule_are_called_from_one_function(callee,
 def test_one_blocked_oracle_and_one_rule_are_defined():
     defs = [(name, fn.name) for name, tree in _trees().items() for fn in ast.walk(tree)
             if isinstance(fn, ast.FunctionDef) and (fn.name == "_attend_block" or "fused" in fn.name)]
-    assert defs == [("attention", "_attend_block"), ("attention", "fused")]
+    assert defs == [("attention", "_attend_block"), ("attention", "fused"),
+                    ("linear_attention", "fused_recurrence")]
