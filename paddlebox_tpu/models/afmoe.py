@@ -23,7 +23,10 @@ sqrt(hidden) (``mup_enabled``).
 Precision: float32 but for the bfloat16 operands of the matrix products.
 Memory: every layer recomputed in the backward from its input, but for the
 fused scores' float32 output and logsumexp (``models/attention.py``; 0.67 GB
-over the cell's five layers of one 8k record). The expert layers are one stacked body under
+over the cell's five layers of one 8k record) and, in the expert layers, the
+grouped product's output, which the norm after the experts reads
+(``models/moe.py::KEEP_EXPERTS``: the backward runs the blocks' pass once;
+0.27 GB over the cell's four). The expert layers are one stacked body under
 ``lax.scan`` **whose step is told its kind** (a traced flag: ``lax.cond``
 picks rope and window or neither, so both kinds compile once whatever their
 order); the scores take the fused kernel with ``group`` and ``window`` on a
@@ -46,7 +49,7 @@ from paddlebox_tpu.models.attention import window_or_full
 from paddlebox_tpu.models.lm_layers import (
     F32, WINDOW_COUNTERS, GroupedQueryConfig, TokenModel, _mm, feed_ids, record_window_counters,
     rms_norm, rope_tables, step_counters, swiglu, window_loss)
-from paddlebox_tpu.models.moe import route, routed_experts
+from paddlebox_tpu.models.moe import KEEP_EXPERTS, route, routed_experts
 from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
 from paddlebox_tpu.utils.monitor import STAT_ADD
 
@@ -208,8 +211,9 @@ class Afmoe(TokenModel):
                                policy=KEEP_SCORES)(p, x)
         # checkpoints that keep the scores' output and logsumexp, at trace time
         STAT_ADD("model.attn.keep_scores_sites", c.num_dense_layers + 1)
+        STAT_ADD("model.moe.keep_output_sites")  # and the expert output: the norm after it reads it
 
-        @partial(jax.checkpoint, policy=KEEP_SCORES)
+        @partial(jax.checkpoint, policy=KEEP_EXPERTS)
         def body(x, layer):
             p, sliding = layer  # one compiled body: the step is told its kind
             x, idx, counts = moe_layer(p, x, c, rope, sliding)

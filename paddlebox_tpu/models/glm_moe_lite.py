@@ -46,7 +46,7 @@ from paddlebox_tpu.models.attention import scores
 from paddlebox_tpu.models.lm_layers import (
     BF16, F32, TokenConfig, TokenModel, _mm, apply_rope, feed_ids, head_logits,
     record_load_counters, rms_norm, rope_tables, step_counters, swiglu)
-from paddlebox_tpu.models.moe import route, routed_experts
+from paddlebox_tpu.models.moe import KEEP_EXPERTS, route, routed_experts
 from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
 from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
@@ -246,17 +246,18 @@ class GlmMoeLite(TokenModel):
         x = emb.astype(F32)
         # checkpoints that keep the scores' output and logsumexp, at trace time
         STAT_ADD("model.mla.keep_scores_sites", len(params["dense"]) + 2)
+        STAT_ADD("model.moe.keep_output_sites", 2)  # and the expert output, where a backward reads it
         for p in params["dense"]:
             x = jax.checkpoint(lambda p, x: dense_layer(p, x, c, rope), policy=KEEP_SCORES)(p, x)
 
-        @partial(jax.checkpoint, policy=KEEP_SCORES)
+        @partial(jax.checkpoint, policy=KEEP_EXPERTS)
         def body(x, p):
             x, idx, counts = moe_layer(p, x, c, rope)
             return x, (idx, counts)
 
         x, (choices, loads) = lax.scan(body, x, params["moe"])
 
-        @partial(jax.checkpoint, policy=KEEP_SCORES)
+        @partial(jax.checkpoint, policy=KEEP_EXPERTS)
         def mtp(m, x, emb):
             with jax.named_scope("model/mtp/eh_proj"):
                 nxt = jnp.concatenate([emb[:, 1:], jnp.zeros_like(emb[:, :1])], axis=1)

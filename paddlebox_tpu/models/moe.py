@@ -14,13 +14,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from paddlebox_tpu.models.lm_layers import BF16, F32, _mm
-from paddlebox_tpu.ops.pallas_kernels import LANE
+from paddlebox_tpu.ops.pallas_kernels import LANE, SCORES_LSE, SCORES_OUT
 from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
 # the counters of a share with no shared expert, after a model's first five
 SHARE_COUNTERS = ("unrouted_tokens", "block_rows")
+
+# The grouped product's output, by name, and the one policy of every expert layer's checkpoint:
+# ``KEEP_SCORES``' two names and this one. Where the layer's backward reads the output (a norm
+# or a mixing map after the expert sum) it is kept and the recomputation's blocks' pass is dead
+# code; where only an add reads it, no backward does, and nothing is stored.
+EXPERTS_OUT = "grouped_experts_out"
+KEEP_EXPERTS = jax.checkpoint_policies.save_only_these_names(SCORES_OUT, SCORES_LSE, EXPERTS_OUT)
 
 
 def route(p, x, top_k: int, *, scale: float = 1.0, form: str = "sigmoid_bias_norm"):
@@ -296,7 +304,8 @@ def routed_experts(p, x, idx, g, held: int, offset: int, block: int, scope: str,
                    act: str = "silu"):
     """The part of the layer of the ``held`` experts from ``offset`` on, for x
     [N, H] routed as (idx, g), in blocks of ``block`` rows, the gate's
-    activation ``act``. Returns it with the held experts' loads [held]."""
+    activation ``act``. Returns it, named ``EXPERTS_OUT``, with the held
+    experts' loads [held]."""
     N, k = idx.shape
     with jax.named_scope(f"{scope}/moe/dispatch"):
         local = idx.reshape(-1) - offset
@@ -306,7 +315,7 @@ def routed_experts(p, x, idx, g, held: int, offset: int, block: int, scope: str,
         gate = jnp.concatenate([g.reshape(-1), jnp.zeros((1,), F32)])[src]
     y = grouped_experts(x, p["gate"], p["up"], p["down"], gate, tok, blk_expert, n_blocks,
                         block, scope, act)
-    return y, counts
+    return checkpoint_name(y, EXPERTS_OUT), counts
 
 
 def share_counters(choices, loads, held: int, offset: int, block: int) -> list:

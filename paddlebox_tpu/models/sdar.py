@@ -53,8 +53,7 @@ from paddlebox_tpu.models.lm_layers import (
     BF16, F32, WINDOW_COUNTERS, GroupedQueryConfig, TokenModel, _mm, apply_rope, feed_ids,
     head_logits, record_load_counters, rms_norm, rope_tables, step_counters)
 from paddlebox_tpu.models.moe import (
-    SHARE_COUNTERS, record_share_counters, route, routed_experts, share_counters)
-from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
+    KEEP_EXPERTS, SHARE_COUNTERS, record_share_counters, route, routed_experts, share_counters)
 from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
 COUNTERS = (("loss_first_half", "loss_second_half") + WINDOW_COUNTERS[2:] + SHARE_COUNTERS
@@ -197,8 +196,9 @@ class Sdar(TokenModel):
 
         # checkpoints that keep the scores' output and logsumexp, at trace time
         STAT_ADD("model.attn.keep_scores_sites")
+        STAT_ADD("model.moe.keep_output_sites")  # and the expert output, where a backward reads it
 
-        @partial(jax.checkpoint, policy=KEEP_SCORES)
+        @partial(jax.checkpoint, policy=KEEP_EXPERTS)
         def body(x, p):
             x, idx, counts = layer(p, x, c, rope)
             return x, (idx, counts)

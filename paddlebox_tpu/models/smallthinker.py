@@ -48,8 +48,7 @@ from paddlebox_tpu.models.lm_layers import (
     F32, WINDOW_COUNTERS, GroupedQueryConfig, TokenModel, _mm, feed_ids, record_window_counters,
     rms_norm, rope_tables, step_counters, window_loss)
 from paddlebox_tpu.models.moe import (
-    SHARE_COUNTERS, record_share_counters, route, routed_experts, share_counters)
-from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
+    KEEP_EXPERTS, SHARE_COUNTERS, record_share_counters, route, routed_experts, share_counters)
 from paddlebox_tpu.utils.monitor import STAT_ADD
 
 COUNTERS = WINDOW_COUNTERS + SHARE_COUNTERS
@@ -157,8 +156,9 @@ class SmallThinker(TokenModel):
 
         # checkpoints that keep the scores' output and logsumexp, at trace time
         STAT_ADD("model.attn.keep_scores_sites")
+        STAT_ADD("model.moe.keep_output_sites")  # and the expert output, where a backward reads it
 
-        @partial(jax.checkpoint, policy=KEEP_SCORES)
+        @partial(jax.checkpoint, policy=KEEP_EXPERTS)
         def body(x, step):
             p, sliding = step  # one compiled body: the step is told its kind
             x, idx, counts = layer(p, x, c, rope, sliding)

@@ -34,7 +34,9 @@ of the branches' matrix products; the maps' product is float32 at ``highest``
 (as the router's), Sinkhorn float32. Memory: every layer is recomputed in the
 backward from its input X (``jax.checkpoint`` with ``KEEP_SCORES``: the fused
 scores' output and logsumexp are kept), the expert layers one ``lax.scan``
-body.
+body whose checkpoint keeps the grouped product's output as well
+(``models/moe.py::KEEP_EXPERTS``: post_res's ``H_post`` gradient reads it, so
+the backward runs the blocks' pass once).
 """
 
 from __future__ import annotations
@@ -52,6 +54,7 @@ from paddlebox_tpu.models.glm_moe_lite import (
 from paddlebox_tpu.models.lm_layers import (
     F32, feed_ids, load_counters, next_token_logits, record_load_counters, yarn_mscale,
     yarn_rope_tables)
+from paddlebox_tpu.models.moe import KEEP_EXPERTS
 from paddlebox_tpu.ops.pallas_kernels import KEEP_SCORES
 from paddlebox_tpu.utils.monitor import STAT_ADD, STAT_SET
 
@@ -245,12 +248,13 @@ class Xing4(GlmMoeLite):
         X = (emb.astype(F32),) * c.hc_mult
         # checkpoints that keep the scores' output and logsumexp, at trace time
         STAT_ADD("model.mla.keep_scores_sites", len(params["dense"]) + 1)
+        STAT_ADD("model.moe.keep_output_sites")  # and the expert output: post_res's map reads it
         readings = []
         for p in params["dense"]:
             X, s = jax.checkpoint(lambda p, X: dense_layer(p, X, c, rope), policy=KEEP_SCORES)(p, X)
             readings.append(s)
 
-        @partial(jax.checkpoint, policy=KEEP_SCORES)
+        @partial(jax.checkpoint, policy=KEEP_EXPERTS)
         def body(X, p):
             X, idx, counts, s = moe_layer(p, X, c, rope)
             return X, (idx, counts, s)

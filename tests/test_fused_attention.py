@@ -633,6 +633,10 @@ KEEPERS = {
         linear_num_value_heads=2, linear_key_head_dim=16, linear_value_head_dim=32,
         intermediate_size=32))), 1, 1, "model.attn.keep_scores_sites"),
 }
+# name -> its expert layers' checkpoint sites, which keep the grouped product's output as well
+# (``models/moe.py::KEEP_EXPERTS``): GLM's scanned layer and MTP module, every other expert
+# model's scan body; Olmo-Hybrid runs no expert layer
+KEEP_OUTPUT_SITES = {"glm": 2, "trinity": 1, "smallthinker": 1, "sdar": 1, "xing4": 1, "olmo_hybrid": 0}
 
 
 @pytest.mark.parametrize("name", sorted(KEEPERS))
@@ -642,18 +646,21 @@ def test_every_token_models_checkpoints_keep_the_scores_and_count_themselves(mon
     wide; with it each cell's superstep still
     fits the chip and its step is shorter, ``PERF.md`` section 6): on a TPU the gradient of
     ``apply`` holds each call site's forward kernel once, every checkpoint of
-    a layer carries ``KEEP_SCORES``, and the trace-time counter says how many."""
+    a layer carries ``KEEP_SCORES`` (an expert layer's ``KEEP_EXPERTS``, which
+    keeps the scores' two names too), and the trace-time counters say how
+    many (``model.moe.keep_output_sites`` the expert layers')."""
     make, calls, sites, stat = KEEPERS[name]
     model = make()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    before = STAT_GET(stat)
+    before = STAT_GET(stat), STAT_GET("model.moe.keep_output_sites")
     text = str(jax.make_jaxpr(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True))(
         params, f32(1, 256, 64), f32(1, 256)))
     assert _kernel_calls(text) == (calls, calls)
     assert len(re.findall(r"policy=<function save_only_these_names", text)) == sites
-    assert STAT_GET(stat) - before == sites  # apply was traced once
+    assert STAT_GET(stat) - before[0] == sites  # apply was traced once
+    assert STAT_GET("model.moe.keep_output_sites") - before[1] == KEEP_OUTPUT_SITES[name]
 
 
 # ---- compiled for the chip, without the chip ---------------------------------------
@@ -762,7 +769,7 @@ def test_smallthinkers_loss_and_gradient_compile_for_a_v5e_with_each_forward_ker
     model = build.build(cfg, 3 + cfg["embedx_dim"])
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if not kept:
-        monkeypatch.setattr(smallthinker, "KEEP_SCORES", None)
+        monkeypatch.setattr(smallthinker, "KEEP_EXPERTS", None)
     B, T, H = cfg["batch_size"], cfg["seq_len"], cfg["hidden_size"]
     on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
     params = jax.tree.map(on, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
@@ -905,8 +912,9 @@ def test_xing4s_loss_and_gradient_compile_for_a_v5e_inside_the_memory_4096_token
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert compiled.memory_analysis().peak_memory_in_bytes < 9.8e9  # 9.54 today
-    # no block's rows join the token sum in the sorted form (PR 43): y, dx and the recomputation's y
-    assert len(re.findall(r" scatter\(.*moe/combine", text)) == 3 * 2  # a block of 896 in two pieces
+    # no block's rows join the token sum in the sorted form: y and dx; the checkpoint keeps
+    # y (``moe.KEEP_EXPERTS``: post_res reads it), so the backward recomputes no y
+    assert len(re.findall(r" scatter\(.*moe/combine", text)) == 2 * 2  # a block of 896 in two pieces
     assert not re.findall(r" sort\(.*moe/combine", text)
 
 

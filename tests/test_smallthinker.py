@@ -289,13 +289,16 @@ DIGESTS = {
 
 
 @pytest.mark.parametrize("model,piece", sorted(DIGESTS))
-def test_glms_and_trinitys_calls_trace_to_the_jaxprs_they_had_before_form_and_activation(model, piece):
+def test_glms_and_trinitys_calls_trace_to_the_jaxprs_they_had_before_form_and_activation(
+        monkeypatch, model, piece):
     """``route`` and ``routed_experts`` (forward and gradient) as GLM's and
     Trinity's layers call them trace to the jaxprs of the parent commit
     (f7c5868, before the router's form and the gate's activation became
-    arguments), source locations aside. The digests were taken from that
-    commit with these lines; a change to what those two cells run has to
-    change them."""
+    arguments), source locations aside, and but for the name
+    ``routed_experts`` gives its output (``moe.EXPERTS_OUT``, which a layer
+    checkpoint keeps): once, and with it read back as the identity the digest
+    holds. The digests were taken from that commit with these lines; a change
+    to what those two cells run has to change them."""
     c = SETTINGS[model]
     N, Hc, I = 24, c.hidden_size, c.moe_intermediate_size
     E = getattr(c, "n_routed_experts", None) or c.num_experts
@@ -316,22 +319,29 @@ def test_glms_and_trinitys_calls_trace_to_the_jaxprs_they_had_before_form_and_ac
 
     f, p = {"route": (routef, router),
             "routed_experts": (routed, {"router": router, "experts": experts})}[piece]
-    text = str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(p, f32(N, Hc)))
+    trace = lambda: str(jax.make_jaxpr(  # noqa: E731
+        jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(p, f32(N, Hc)))
+    assert trace().count(f"name[name={moe.EXPERTS_OUT}]") == (piece == "routed_experts")
+    monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
+    text = trace()
     digest = hashlib.sha256(re.sub(r"\S+\.py:\d+", "", text).encode()).hexdigest()
     assert digest == DIGESTS[model, piece]
 
 
-def test_trinitys_step_traces_to_the_jaxpr_it_had_but_for_what_its_two_checkpoints_keep():
+def test_trinitys_step_traces_to_the_jaxpr_it_had_but_for_what_its_two_checkpoints_keep(monkeypatch):
     """``Afmoe.apply`` (loss, counters and the gradient of every leaf and of
     the rows) traces to the jaxpr of commit f7c5868, source locations aside,
-    but for one thing since PR 36: the dense layer's checkpoint and the
-    scanned body's carry ``ops/pallas_kernels.py::KEEP_SCORES`` where they
-    carried no policy (a policy prints as a function with an address, so
-    those two are read back as ``policy=None`` before hashing; the digest is
-    the one taken from f7c5868, when ``feed_ids``, ``window_loss``,
-    ``window_counters`` and ``record_window_counters`` moved out of the class
-    for ``smallthinker`` to call). On the CPU the scores take the blocked form,
-    which gives no names: the policy keeps nothing here."""
+    but for what its two checkpoints keep: the dense layer's checkpoint
+    carries ``ops/pallas_kernels.py::KEEP_SCORES`` and the scanned
+    body's ``models/moe.py::KEEP_EXPERTS`` where they carried no policy (a
+    policy prints as a function with an address, so those two are read back
+    as ``policy=None`` before hashing), and the grouped product's output
+    carries the name that the latter keeps (``moe.EXPERTS_OUT``), read back
+    as the identity (the digest is the one taken from f7c5868, when
+    ``feed_ids``, ``window_loss``, ``window_counters`` and
+    ``record_window_counters`` moved out of the class for ``smallthinker`` to
+    call). On the CPU the scores take the blocked form, which gives no names:
+    there the policy keeps the expert output alone."""
     c = AfmoeConfig(
         hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=8, sliding_window=16,
         layer_types=("sliding_attention", "full_attention", "sliding_attention"), num_dense_layers=1,
@@ -341,8 +351,11 @@ def test_trinitys_step_traces_to_the_jaxpr_it_had_but_for_what_its_two_checkpoin
     model = Afmoe(c)
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     p = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    text = str(jax.make_jaxpr(jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True))(
-        p, f32(2, 32, 32), f32(2, 32)))
+    trace = lambda: str(jax.make_jaxpr(  # noqa: E731
+        jax.value_and_grad(model.apply, argnums=(0, 1), has_aux=True))(p, f32(2, 32, 32), f32(2, 32)))
+    assert f"name[name={moe.EXPERTS_OUT}]" in trace()
+    monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
+    text = trace()
     text, kept = re.subn(r"policy=<function save_only_these_names\S* at 0x[0-9a-f]+>", "policy=None", text)
     assert kept == 2 and "name[name=" not in text
     digest = hashlib.sha256(re.sub(r"\S+\.py:\d+", "", text).encode()).hexdigest()
